@@ -26,16 +26,19 @@ _FASTPATH = proto_fastpath_enabled()
 
 def _resolve_device_mode(device_mode: Optional[bool]) -> bool:
     """Device (TPU kernel) protocol path: explicit arg > ACCORD_TPU_DEVICE
-    env > on iff 64-bit JAX is enabled (the kernels' precondition — the test
-    conftest, bench, burn CLI and graft entries all enable it at startup)."""
-    if device_mode is not None:
-        return device_mode
-    import os
-    env = os.environ.get("ACCORD_TPU_DEVICE")
-    if env is not None:
-        return env.lower() not in ("0", "false", "off", "")
-    import jax
-    return bool(jax.config.jax_enable_x64)
+    env > on.  The kernels' precondition is 64-bit JAX, which every entry
+    point enables through ``ops.packing.startup()`` (the test conftest sets
+    the flag itself); a process that turns the device path on without it
+    fails HERE, at node construction, not at the first flush."""
+    if device_mode is None:
+        import os
+        env = os.environ.get("ACCORD_TPU_DEVICE")
+        device_mode = env is None or \
+            env.lower() not in ("0", "false", "off", "")
+    if device_mode:
+        from ..ops.packing import ensure_x64
+        ensure_x64()
+    return device_mode
 
 
 class Node:

@@ -82,6 +82,8 @@ class WallClockScheduler(api.Scheduler):
 
 
 def main() -> None:
+    from ..ops.packing import startup
+    startup()
     start = time.monotonic_ns()
 
     def now_micros() -> int:

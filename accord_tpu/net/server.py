@@ -878,6 +878,8 @@ def main(argv=None) -> int:
                         "EXCEPT this node: boot as a non-member observer "
                         "awaiting the epoch that admits it")
     args = p.parse_args(argv)
+    from ..ops.packing import startup
+    startup()
 
     host, port = parse_addr(args.listen)
     device_mode = {"auto": None, "on": True, "off": False}[args.device_mode]

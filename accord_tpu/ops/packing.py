@@ -26,6 +26,23 @@ def enable_x64() -> None:
     jax.config.update("jax_enable_x64", True)
 
 
+def startup() -> str:
+    """Process start-up for every entry point (burn, maelstrom, net.server,
+    bench, tools/*, chip_smoke): 64-bit JAX plus a persistent compile cache
+    the caller can place.  ``JAX_COMPILATION_CACHE_DIR`` set -> jax reads it
+    and nothing is set here; unset -> ``<checkout>/.jax_cache``, a fixed
+    path (the path is part of the cache key, so a directory that moves
+    never hits).  Returns the cache directory in effect."""
+    import os
+    enable_x64()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update(
+            "jax_compilation_cache_dir",
+            os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)))), ".jax_cache"))
+    return jax.config.jax_compilation_cache_dir
+
+
 def ensure_x64() -> None:
     """The protocol's ids are 128-bit (2 x int64 words); the device data
     plane requires 64-bit integer support.  On TPU, int64 compares/bitwise
@@ -35,7 +52,7 @@ def ensure_x64() -> None:
     x64 is a PRECONDITION, not a side effect: flipping the process-global
     flag lazily mid-run would silently change dtype-promotion semantics for
     unrelated JAX code in the host application.  Callers must opt in via
-    enable_x64() (or jax.config / JAX_ENABLE_X64) at startup.
+    startup() / enable_x64() (or jax.config / JAX_ENABLE_X64) at startup.
     """
     if not jax.config.jax_enable_x64:
         raise RuntimeError(

@@ -36,15 +36,8 @@ STORE_AXIS = "store"
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: the public ``jax.shard_map``
-    (``check_vma``) when present, else the experimental spelling
-    (``check_rep``) older jaxes ship."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(n_devices: int = None) -> Mesh:

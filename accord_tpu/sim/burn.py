@@ -661,6 +661,8 @@ def main(argv=None):
                         "fork appended last; composes with "
                         "--recovery-nemesis and --device-faults)")
     args = p.parse_args(argv)
+    from ..ops.packing import startup
+    startup()
 
     if args.loop_seed is not None:
         seed = args.loop_seed

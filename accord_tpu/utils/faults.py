@@ -38,6 +38,8 @@ import contextlib
 import sys
 from typing import Dict, Iterator, Optional, Tuple
 
+from jax.errors import JaxRuntimeError
+
 from .random_source import RandomSource
 
 # Skip ensuring stability (deps durable at a quorum) before execution
@@ -82,13 +84,8 @@ DEVICE_FAULT_KINDS: Dict[str, type] = {
 # exception types the device layer treats as a device-boundary failure (and
 # therefore quarantines + fails over on) — injected faults plus the real
 # runtime's launch/transfer/OOM errors
-_dev_exc = [DeviceFaultError, MemoryError]
-try:  # pragma: no cover - depends on the installed jaxlib
-    from jaxlib.xla_extension import XlaRuntimeError as _XlaRuntimeError
-    _dev_exc.append(_XlaRuntimeError)
-except Exception:  # pragma: no cover
-    pass
-DEVICE_EXCEPTIONS: Tuple[type, ...] = tuple(_dev_exc)
+DEVICE_EXCEPTIONS: Tuple[type, ...] = (DeviceFaultError, MemoryError,
+                                       JaxRuntimeError)
 
 # kind -> (probability, RandomSource); empty means no draws anywhere
 _armed: Dict[str, Tuple[float, RandomSource]] = {}

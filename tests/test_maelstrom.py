@@ -195,11 +195,12 @@ def test_token_mapping():
 def test_stdin_stdout_node():
     env = dict(os.environ)
     env["ACCORD_TPU_DEVICE"] = "0"   # host path: fast cold start
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"     # a test child never takes the chip
     p = subprocess.Popen([sys.executable, "-m", "accord_tpu.maelstrom"],
                          stdin=subprocess.PIPE, stdout=subprocess.PIPE,
                          stderr=subprocess.DEVNULL,
-                         text=True, env=env, cwd="/root/repo")
+                         text=True, env=env, cwd=os.path.dirname(
+                             os.path.dirname(os.path.abspath(__file__))))
     try:
         def send(obj):
             p.stdin.write(json.dumps(obj) + "\n")

@@ -54,8 +54,8 @@ def _run_node(lines):
     p = subprocess.run(
         [sys.executable, "-m", "accord_tpu.maelstrom"],
         input="\n".join(json.dumps(m) for m in lines) + "\n",
-        capture_output=True, text=True, timeout=240, cwd="/root/repo",
-        env=env)
+        capture_output=True, text=True, timeout=240, env=env,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     assert p.returncode == 0, p.stderr[-800:]
     return [json.loads(l) for l in p.stdout.splitlines() if l.strip()]
 

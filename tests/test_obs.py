@@ -289,8 +289,10 @@ def test_devprof_16store_fused_run_trace(tmp_path, monkeypatch):
     from accord_tpu.local.dispatch import DeviceDispatcher, fusion_enabled
     if not fusion_enabled():
         pytest.skip("ACCORD_TPU_FUSION=off canary run")
+    import os
     import sys
-    sys.path.insert(0, "/root/repo")
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     from bench import bench_launch_amortized_harness
     monkeypatch.setattr(DeviceDispatcher, "_fused_flush_pays",
                         lambda self, hints: True)

@@ -1,8 +1,8 @@
-import sys, time
-sys.path.insert(0, "/root/repo")
+import os, sys, time
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np
-from accord_tpu.ops.packing import enable_x64
-enable_x64()
+from accord_tpu.ops.packing import startup
+startup()
 import jax, jax.numpy as jnp
 from functools import partial
 

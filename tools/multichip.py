@@ -36,10 +36,8 @@ def _force_cpu_mesh(n_devices):
         os.environ["XLA_FLAGS"] = (
             flags + f" --xla_force_host_platform_device_count={n_devices}"
         ).strip()
-    os.environ["JAX_ENABLE_X64"] = "true"
     import jax
     jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_enable_x64", True)
     if len(jax.devices()) < n_devices:
         raise RuntimeError(
             f"need {n_devices} devices but jax initialized with "
@@ -133,7 +131,9 @@ def leg_store_shard(n_devices):
     import numpy as np
     from accord_tpu.local.device_index import DeviceState
 
-    N, BUDGET, B, KEYS = 1 << 18, 1 << 15, 64, 1 << 20
+    # the per-device budget that makes the table fit ONLY as d slices
+    N, B, KEYS = 1 << 18, 64, 1 << 20
+    BUDGET = N // n_devices
     store, safe = _store_and_safe()
     dev = DeviceState(store)
     assert dev.mesh is not None, "store_shard leg needs the mesh"
@@ -198,7 +198,8 @@ def leg_slice_fault(n_devices):
     from accord_tpu.utils import faults
     from accord_tpu.utils.random_source import RandomSource
 
-    N, BUDGET, B, KEYS = 1 << 16, 1 << 13, 32, 1 << 18
+    N, B, KEYS = 1 << 16, 32, 1 << 18
+    BUDGET = N // n_devices
     store, safe = _store_and_safe()
     dev = DeviceState(store)
     assert dev.mesh is not None
@@ -251,9 +252,12 @@ def main(argv=None):
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "MULTICHIP_r11.json"))
     args = p.parse_args(argv)
-    _force_cpu_mesh(args.devices)
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
+    from accord_tpu.ops.packing import startup
+    startup()
+    _force_cpu_mesh(args.devices)
+    import jax
 
     legs = {}
     rc = 0
@@ -275,7 +279,8 @@ def main(argv=None):
         "rc": rc,
         "ok": rc == 0,
         "skipped": False,
-        "platform": "cpu-mesh (virtual; real multi-chip not reachable)",
+        "platform": jax.devices()[0].platform,
+        "device_kind": jax.devices()[0].device_kind,
         "legs": legs,
     }
     with open(args.out, "w") as f:

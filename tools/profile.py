@@ -61,9 +61,9 @@ import time              # noqa: E402
 
 import numpy as np  # noqa: E402
 
-from accord_tpu.ops.packing import enable_x64  # noqa: E402
+from accord_tpu.ops.packing import startup  # noqa: E402
 
-enable_x64()
+startup()
 
 from accord_tpu.obs import devprof  # noqa: E402
 from accord_tpu.obs.metrics import index_counters  # noqa: E402
