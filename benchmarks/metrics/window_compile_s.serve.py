@@ -1,0 +1,14 @@
+"""Seconds jax spent tracing, lowering and compiling INSIDE the measured
+window (jax.monitoring /jax/core/compile/* durations).  Should be 0: every
+shape is warmed in set-up."""
+
+LAYER = "start-up"
+UNIT = "s"
+SOURCE = "program_span"
+MOVES = "commit_p95"
+
+
+def read(record):
+    if record.get("driver") != "served":
+        return None
+    return record["compile"]["seconds"]
