@@ -777,15 +777,21 @@ class NodeServer:
               file=sys.stderr, flush=True)
 
     async def close(self) -> None:
+        # nothing in and nothing out (cancelling a link waits on the loop,
+        # not on its peer), then the final flush, and only then the wait on
+        # sockets: a graceful exit is durable whatever peers and clients do,
+        # and what a timer does after the flush cannot leave the node
+        if self.frame_server is not None:
+            self.frame_server.stop()
         for link in self.links.values():
             await link.close()
-        if self.frame_server is not None:
-            await self.frame_server.close()
         if self.journal is not None:
             try:
                 self.journal.close()   # final flush (graceful exit only —
             except OSError:            # kill -9 relies on recovery)
                 pass
+        if self.frame_server is not None:
+            await self.frame_server.close()
 
 
 def _weighted_median(census: Dict[int, int]) -> int:

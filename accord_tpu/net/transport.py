@@ -67,6 +67,9 @@ COALESCE_MAX_BYTES = 256 * 1024
 COALESCE_FACTOR = 8
 COALESCE_MIN_MICROS = 0
 COALESCE_MAX_MICROS = 1_000
+# how long a closing FrameServer lets its accepted connections drain what
+# is already written to them before it aborts them
+CLOSE_GRACE_S = 1.0
 
 _write_probe_cache: Optional[int] = None
 
@@ -327,6 +330,8 @@ class FrameServer:
         self.on_payload = on_payload
         self.on_close = on_close
         self._server: Optional[asyncio.AbstractServer] = None
+        self._writers: set = set()   # accepted and not yet gone
+        self._stopped = False
         self.n_accepted = 0
         self.n_frame_errors = 0
         self.bytes_rx = 0
@@ -335,19 +340,39 @@ class FrameServer:
         self._server = await asyncio.start_server(
             self._handle, self.host, self.port)
 
-    async def close(self) -> None:
+    def stop(self) -> None:
+        """Stop accepting and delivering, without waiting: no frame reaches
+        the callbacks after this.  What was accepted is closed here, since
+        ``wait_closed()`` waits (Python 3.12) for every such connection and
+        a peer or client may hold its end open for ever."""
+        self._stopped = True
         if self._server is not None:
             self._server.close()
+        for writer in self._writers:
+            writer.close()
+
+    async def close(self) -> None:
+        self.stop()
+        if self._server is None:
+            return
+        try:
+            await asyncio.wait_for(self._server.wait_closed(), CLOSE_GRACE_S)
+        except asyncio.TimeoutError:
+            # a peer that stopped reading holds unsent replies in a
+            # closing transport's buffer: give them up
+            for writer in self._writers:
+                writer.transport.abort()
             await self._server.wait_closed()
 
     async def _handle(self, reader: asyncio.StreamReader,
                       writer: asyncio.StreamWriter) -> None:
         self.n_accepted += 1
+        self._writers.add(writer)
         decoder = FrameDecoder()
         try:
-            while True:
+            while not self._stopped:   # accepted as stop() ran: not served
                 chunk = await reader.read(65536)
-                if not chunk:
+                if not chunk or self._stopped:
                     return
                 self.bytes_rx += len(chunk)
                 if self.on_payload is not None:
@@ -364,6 +389,7 @@ class FrameServer:
         except (ConnectionError, OSError, asyncio.IncompleteReadError):
             pass
         finally:
+            self._writers.discard(writer)
             if self.on_close is not None:
                 try:
                     self.on_close(writer)

@@ -730,15 +730,9 @@ async def _serve(seed, sz, out_dir):
             "n_host_ticks": int(sum(d.n_host_ticks for d in devs)),
             **rep}
     finally:
-        # every outbound link first: a FrameServer's close waits for its
-        # inbound connections, which in one process are the OTHER servers'
-        # links (separate processes just exit)
         await client.close()
         for s in servers:
-            for link in s.links.values():
-                await link.close()
-        for s in servers:
-            await asyncio.wait_for(s.close(), 30.0)
+            await s.close()
 
 
 def phase_serve(seed, sz, out_dir=None):

@@ -1,7 +1,13 @@
 """Test config: force JAX onto a virtual 8-device CPU mesh so sharding tests
 run anywhere (the driver separately dry-runs the multi-chip path)."""
 
+import contextlib
+import faulthandler
 import os
+import signal
+import threading
+
+import pytest
 
 # Hard override: the ambient environment may point JAX at a real accelerator;
 # unit tests always run on the virtual CPU mesh.  The env var alone is not
@@ -86,6 +92,54 @@ def pytest_configure(config):
         from accord_tpu import obs
         assert not obs.enabled(), \
             "ACCORD_TPU_OBS=off set but obs.enabled() is True"
+
+
+# -- one time limit for every test ----------------------------------------
+# A hang costs one failed test, with every thread's stack in its captured
+# stderr — not a dead xdist worker and the files queued behind it.  Raise
+# it only for an honest test above 60 s (the slowest when it was set:
+# test_drain_routes_vs_host_oracle, 44 s under the driver's six workers).
+
+TEST_LIMIT_S = 120.0
+
+
+@contextlib.contextmanager
+def time_limit(seconds):
+    """Fail the calling test once ``seconds`` have passed: SIGALRM dumps
+    every thread's stack and raises pytest's Failed — a BaseException, so
+    no ``except Exception`` of the program swallows it — in the main
+    thread, out of ``asyncio.run`` too.  It fires again every second
+    after, in case something did absorb it (a task other than the one
+    ``run_until_complete`` waits for).  A main thread stuck outside the
+    interpreter never runs the handler: for that case alone faulthandler's
+    own thread dumps the stacks a little later."""
+    if (not hasattr(signal, "SIGALRM")
+            or threading.current_thread() is not threading.main_thread()):
+        yield
+        return
+
+    def expired(_signum, _frame):
+        faulthandler.dump_traceback()
+        pytest.fail(f"test exceeded {seconds:g} s")
+
+    previous = signal.signal(signal.SIGALRM, expired)
+    signal.setitimer(signal.ITIMER_REAL, seconds, 1.0)
+    faulthandler.dump_traceback_later(seconds + 5.0, exit=False)
+    try:
+        yield
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+
+
+@pytest.fixture(autouse=True)
+def _test_time_limit(request):
+    if request.node.get_closest_marker("slow"):   # tier-2: long by design
+        yield
+        return
+    with time_limit(TEST_LIMIT_S):
+        yield
 
 
 # -- shared DeviceState test fixture --------------------------------------

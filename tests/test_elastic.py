@@ -329,11 +329,13 @@ def test_client_pending_fail_over_on_close_and_remove():
 
     async def scenario():
         served = []
+        over = asyncio.Event()
 
         async def handler(reader, writer):
             # read one frame's worth and never reply
             served.append(await reader.read(64))
-            await asyncio.sleep(30)
+            await over.wait()
+            writer.close()   # or wait_closed() below waits for ever (3.12)
 
         server = await asyncio.start_server(handler, "127.0.0.1", 0)
         port = server.sockets[0].getsockname()[1]
@@ -354,6 +356,7 @@ def test_client_pending_fail_over_on_close_and_remove():
         assert client.duplicate_replies() == 3, \
             "departed node's duplicate census was dropped"
         assert client.addrs == []
+        over.set()
         server.close()
         await server.wait_closed()
         return True

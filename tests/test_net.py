@@ -840,6 +840,7 @@ def test_peer_link_coalesces_queued_frames_into_one_write():
                 reads.append(chunk)
                 if sum(len(c) for c in reads) >= want:
                     got.set()
+            writer.close()   # or wait_closed() below waits for ever (3.12)
 
         server = await asyncio.start_server(handle, "127.0.0.1", 0)
         port = server.sockets[0].getsockname()[1]
@@ -884,6 +885,140 @@ def test_coalesce_window_priced_not_thresholded():
         assert coalesce_window_micros() == 123
     finally:
         del os.environ["ACCORD_TPU_COALESCE_US"]
+
+
+@pytest.mark.parametrize("unread_reply_bytes", [0, 64 << 20])
+def test_frame_server_close_does_not_wait_for_its_peers(unread_reply_bytes):
+    """A client that sent a frame and never closes — and, in the second
+    case, never reads the reply either, so the closing transport keeps
+    unsent bytes — cannot hold FrameServer.close()."""
+    from accord_tpu.net.transport import FrameServer
+
+    async def run():
+        seen = []
+        got = asyncio.Event()
+
+        def on_payload(payload, writer):
+            seen.append(payload)
+            if unread_reply_bytes:
+                writer.write(b"\0" * unread_reply_bytes)
+            got.set()
+
+        server = FrameServer("127.0.0.1", 0, on_payload=on_payload)
+        await server.start()
+        port = server._server.sockets[0].getsockname()[1]
+        _reader, writer = await asyncio.open_connection("127.0.0.1", port)
+        frame = encode_frame(PACKETS[1], "binary")
+        writer.write(frame)
+        await writer.drain()
+        await asyncio.wait_for(got.wait(), 10)
+        t0 = asyncio.get_event_loop().time()
+        await asyncio.wait_for(server.close(), 5.0)
+        took = asyncio.get_event_loop().time() - t0
+        writer.close()
+        return seen, took, server
+
+    seen, took, server = asyncio.run(run())
+    assert len(seen) == 1
+    assert took < 2.0
+    assert not server._writers
+
+
+@pytest.mark.parametrize("handler_is_reading", [True, False])
+def test_frame_server_delivers_nothing_after_stop(handler_is_reading):
+    """Bytes that reached a connection's buffer before stop() — with its
+    handler already waiting in read(), or accepted and not yet started —
+    are dropped: no frame reaches the callbacks after stop() returned."""
+    from accord_tpu.net.transport import FrameServer
+
+    class Writer:
+        closed = False
+
+        def close(self):
+            self.closed = True
+
+    async def run():
+        seen = []
+        server = FrameServer("127.0.0.1", 0,
+                             on_payload=lambda p, w: seen.append(p))
+        reader, writer = asyncio.StreamReader(), Writer()
+        task = asyncio.get_event_loop().create_task(
+            server._handle(reader, writer))
+        if handler_is_reading:
+            await asyncio.sleep(0)
+        reader.feed_data(encode_frame(PACKETS[1], "binary"))
+        server.stop()
+        await asyncio.wait_for(task, 5.0)
+        return seen, writer, server
+
+    seen, writer, server = asyncio.run(run())
+    assert seen == []
+    assert writer.closed and not server._writers
+
+
+def test_node_close_flushes_journal_before_it_waits_on_a_socket(tmp_path):
+    """A NodeServer closed while a client still holds its connection has
+    every appended record on disk BEFORE it waits for any transport, its
+    outbound links are down before that flush (what a timer does after it
+    cannot leave the node), and its close returns."""
+    import gc
+    from accord_tpu.journal import segment
+    from accord_tpu.net.server import NodeServer
+
+    def on_disk():
+        n = nbytes = 0
+        for path in tmp_path.glob("wal-*.seg"):
+            for payload in segment.scan(str(path))[1]:
+                n += 1
+                nbytes += len(payload)
+        return n, nbytes
+
+    async def run():
+        server = NodeServer("n1", "127.0.0.1", 0, {"n2": ("127.0.0.1", 1)},
+                            members=["n1"], durability=False,
+                            journal_dir=str(tmp_path))
+        await server.start()
+        port = server.frame_server._server.sockets[0].getsockname()[1]
+        reader, writer = await asyncio.open_connection("127.0.0.1", port)
+        writer.write(encode_frame(
+            {"src": "c1", "dest": "n1",
+             "body": {"type": "txn", "msg_id": 1,
+                      "txn": [["append", 7, 1]]}}, "binary"))
+        await writer.drain()
+        dec = FrameDecoder()
+        replies = []
+        while not replies:
+            replies = dec.feed(await asyncio.wait_for(reader.read(65536), 20))
+        at_wait, links_down_at_flush = [], []
+        wait_on_sockets = server.frame_server.close
+        final_flush = server.journal.close
+
+        async def spy_wait():
+            at_wait.append(on_disk())
+            await wait_on_sockets()
+
+        def spy_flush():
+            links_down_at_flush.append(
+                all(link._task.done() for link in server.links.values()))
+            final_flush()
+        server.frame_server.close = spy_wait
+        server.journal.close = spy_flush
+        await asyncio.wait_for(server.close(), 5.0)
+        writer.close()
+        return (replies[0], at_wait, links_down_at_flush,
+                server.journal.wal.stats())
+
+    thresholds = gc.get_threshold()
+    try:
+        reply, at_wait, links_down_at_flush, wal = asyncio.run(run())
+    finally:
+        gc.unfreeze()   # NodeServer.start() retunes the collector
+        gc.set_threshold(*thresholds)
+    assert reply["body"]["type"] == "txn_ok"
+    assert links_down_at_flush == [True]
+    assert wal["appended"] > 0 and wal["durable_seq"] == wal["tail_seq"]
+    assert at_wait == [(wal["appended"], wal["bytes"])]
+    assert on_disk() == (wal["appended"], wal["bytes"])
 
 
 # ---------------------------------------------------------------------------
