@@ -223,10 +223,26 @@ def _tri_pairs(tb: np.ndarray, tj: np.ndarray):
     return tb[first], tj[first], p_i
 
 
+# one bucket-index entry as the host keeps it: the BucketTable's eight
+# per-entry columns, in its field order, as ONE record — an add is one store,
+# and the eight [g_cap, BUCKET_K] host arrays are field views of one buffer
+_BUCKET_REC = np.dtype([("lo", np.int64), ("hi", np.int64),
+                        ("slot", np.int32), ("col", np.int32),
+                        ("msb", np.int64), ("lsb", np.int64),
+                        ("node", np.int32), ("kind", np.int32)])
+_BUCKET_PAD = np.array((dk.PAD_LO, dk.PAD_HI, -1, 0, 0, 0, 0, 0),
+                       _BUCKET_REC)
+# a pending cell on its way to the device: the record + its (row, pos) pair
+_CELL_BYTES = _BUCKET_REC.itemsize + 8
+# floor of the padded pending-cell count: a served store's few-cell syncs
+# and a 64-txn flush's ~1k cells share ONE compiled scatter shape
+_MIN_CELLS = 2048
+
+
 @jax.jit
-def _scatter_bucket_rows(dev, idx, rows):
-    """Fused dirty-bucket update for the seven bucket-entry arrays."""
-    return tuple(a.at[idx].set(r) for a, r in zip(dev, rows))
+def _scatter_bucket_cells(dev, rows, pos, vals):
+    """Fused pending-cell update for the eight bucket-entry arrays."""
+    return tuple(a.at[rows, pos].set(v) for a, v in zip(dev, vals))
 
 
 def _host_index_of(status, lo_a, hi_a, msb, lsb, node, fkey):
@@ -259,9 +275,10 @@ def _host_index_of(status, lo_a, hi_a, msb, lsb, node, fkey):
 class _DepsMirror:
     """Host mirror of one store's DepsTable, with dirty-row tracking, plus
     the host half of the bucketed interval index (the CINTIA-analogue in
-    ops.deps_kernel.bucketed_flat): per-bucket (lo, hi, slot) entry lists
-    kept incrementally, wide/overflow entries in a straggler set, dirty
-    buckets scatter-updated to the device alongside the slot table."""
+    ops.deps_kernel.bucketed_flat): per-bucket rows of entry records kept
+    IN PLACE, entry by entry, wide/overflow entries in a straggler set, and
+    the cells each mutation wrote scatter-updated to the device alongside
+    the slot table."""
 
     # bucket width = 2^BSHIFT tokens; intervals (and query probes) touching
     # more than SPAN buckets go to the wide/straggler path
@@ -320,22 +337,24 @@ class _DepsMirror:
         # invalidate, footprint growth) bumps ``version``
         self._device_sh: Optional[dk.DepsTable] = None
         self._device_sh_key = None
-        # -- bucket index (host truth); entries are (lo, hi, slot, col)
-        # where col is the interval's column in its slot row — the third
-        # leg of the exact overlap triple the kernels emit --
+        # -- bucket index (host truth): ``_brec[row, :_blen[row]]`` are the
+        # bucket's entries, in no order (the kernels sort the candidate
+        # codes), every cell beyond is _BUCKET_PAD; wide entries are
+        # (lo, hi, slot, col).  col is the interval's column in its slot
+        # row — the third leg of the exact overlap triple the kernels emit
         self.bucket_row: Dict[int, int] = {}     # bucket id -> dense row
-        self.bucket_entries: List[List[Tuple[int, int, int, int]]] = []
-        self.bucket_dirty: Set[int] = set()
+        self._blen: List[int] = []               # entries per dense row
+        # slot -> the flat cells (row * BUCKET_K + pos) its entries sit in
+        self._bcells: Dict[int, List[int]] = {}
         self.wide_entries: Set[Tuple[int, int, int, int]] = set()
         # live-occupancy high-water across bucket rows (monotonic, like
         # capacity): the kernels slice the entry axis to its pow2 — the
         # [G, BUCKET_K] rows are ~95% padding on spread keyspaces and the
         # candidate matrix (and kernel wall) shrinks proportionally
         self.bucket_max_len = 0
-        self._bhost = None                        # 8 host row arrays
         self._bdev = None                         # jnp 8-tuple
-        self._bdev_pending: Set[int] = set()      # rows _bdev hasn't seen
-        self._g_cap = 0
+        self._bpend: Set[int] = set()             # flat cells _bdev lacks
+        self._alloc_bucket_rows(_MIN_CAPACITY)
         # wide/straggler host arrays cached PER PADDED WIDTH (r08): the
         # single-device and mesh consumers may ask for different pow2
         # floors, and alternating routes between flushes must not rebuild
@@ -391,6 +410,18 @@ class _DepsMirror:
         return min(self.BUCKET_K,
                    _pow2_at_least(max(self.bucket_max_len, 1), 8))
 
+    def _alloc_bucket_rows(self, g_cap: int, old=None) -> None:
+        """Host rows for ``g_cap`` buckets: ``old``'s, then _BUCKET_PAD.  The
+        device copy is absent or of another shape: full upload next."""
+        rec = np.zeros((g_cap, self.BUCKET_K), _BUCKET_REC)
+        rec["lo"], rec["hi"], rec["slot"] = dk.PAD_LO, dk.PAD_HI, -1
+        if old is not None:
+            rec[: len(old)] = old
+        self._brec, self._bflat, self._g_cap = rec, rec.reshape(-1), g_cap
+        self._bhost = tuple(rec[f] for f in _BUCKET_REC.names)
+        self._bdev = None
+        self._bpend.clear()
+
     def _bucket_add(self, slot: int, lo: int, hi: int, col: int) -> None:
         if self.status[slot] == dk.SLOT_INVALIDATED:
             return   # structurally excluded (de-indexed on invalidation)
@@ -400,54 +431,67 @@ class _DepsMirror:
             self.wide_entries.add((lo, hi, slot, col))
             self.wide_version += 1
             return
+        # the slot is live, so its immutable id/kind columns are current
+        ent = (lo, hi, slot, col, self.msb[slot], self.lsb[slot],
+               self.node[slot], self.kind[slot])
+        blen, k = self._blen, self.BUCKET_K
         for bid in range(blo, bhi + 1):
             row = self.bucket_row.get(bid)
             if row is None:
-                row = len(self.bucket_entries)
+                row = len(blen)
+                if row == self._g_cap:
+                    self._alloc_bucket_rows(2 * row, self._brec)
                 self.bucket_row[bid] = row
-                self.bucket_entries.append([])
+                blen.append(0)
                 self._bids_stale = True
-            ents = self.bucket_entries[row]
-            if len(ents) >= self.BUCKET_K:
+            n = blen[row]
+            if n >= k:
                 # overflow spill: the straggler list absorbs hot buckets
                 self.wide_entries.add((lo, hi, slot, col))
                 self.wide_version += 1
             else:
-                ents.append((lo, hi, slot, col))
-                self.bucket_dirty.add(row)
-                if len(ents) > self.bucket_max_len:
-                    self.bucket_max_len = len(ents)
+                cell = row * k + n
+                self._bflat[cell] = ent
+                blen[row] = n + 1
+                self._bcells.setdefault(slot, []).append(cell)
+                if self._bdev is not None:
+                    self._bpend.add(cell)
+                if n >= self.bucket_max_len:
+                    self.bucket_max_len = n + 1
 
     def _bucket_remove(self, slot: int) -> None:
         """De-index every interval of ``slot`` (called before the row's
-        lo/hi are cleared on free)."""
+        lo/hi are cleared on free): each of its cells takes its row's last
+        entry, and the vacated last cell is cleared."""
         self.bucket_version += 1
-        row_lo, row_hi = self.lo[slot], self.hi[slot]
-        for m in range(self.max_intervals):
-            lo, hi = int(row_lo[m]), int(row_hi[m])
-            if lo > hi:
-                continue
-            ent = (lo, hi, slot, m)
-            blo, bhi = lo >> self.BSHIFT, hi >> self.BSHIFT
-            if bhi - blo + 1 > self.SPAN:
+        if self.wide_entries:
+            row_lo, row_hi = self.lo[slot], self.hi[slot]
+            for m in range(self.max_intervals):
+                ent = (int(row_lo[m]), int(row_hi[m]), slot, m)
                 if ent in self.wide_entries:
                     self.wide_entries.discard(ent)
                     self.wide_version += 1
-                continue
-            spilled = False
-            for bid in range(blo, bhi + 1):
-                r = self.bucket_row.get(bid)
-                if r is not None:
-                    try:
-                        self.bucket_entries[r].remove(ent)
-                        self.bucket_dirty.add(r)
-                        continue
-                    except ValueError:
-                        pass
-                spilled = True
-            if spilled and ent in self.wide_entries:
-                self.wide_entries.discard(ent)
-                self.wide_version += 1
+        cells = self._bcells.pop(slot, None)
+        if cells is None:
+            return
+        flat, blen, k = self._bflat, self._blen, self.BUCKET_K
+        wrote = []
+        for i, cell in enumerate(cells):
+            cells[i] = -1      # done: a later same-slot move must not match
+            row = cell // k
+            blen[row] -= 1
+            last = row * k + blen[row]
+            if cell != last:
+                moved = flat[last]
+                flat[cell] = moved
+                owner = int(moved["slot"])
+                own = cells if owner == slot else self._bcells[owner]
+                own[own.index(last)] = cell
+                wrote.append(cell)
+            flat[last] = _BUCKET_PAD
+            wrote.append(last)
+        if self._bdev is not None:
+            self._bpend.update(wrote)
 
     def bid_rows(self) -> Tuple[np.ndarray, np.ndarray]:
         """(sorted bucket ids, dense row per id) for vectorized query->row
@@ -461,57 +505,6 @@ class _DepsMirror:
             self._row_of_sorted = rows[order]
             self._bids_stale = False
         return self._sorted_bids, self._row_of_sorted
-
-    def _fill_bucket_row(self, arrs, r, ents) -> None:
-        """Write one bucket's entries into the 8 host row arrays, with the
-        immutable id/kind columns read from the mirror (entries are live,
-        so the mirror columns are current for their slots)."""
-        blo, bhi, bslot, bcol, bmsb, blsb, bnode, bkind = arrs
-        blo[r] = dk.PAD_LO
-        bhi[r] = dk.PAD_HI
-        bslot[r] = -1
-        bcol[r] = 0
-        for i, (lo, hi, s, col) in enumerate(ents):
-            blo[r, i] = lo
-            bhi[r, i] = hi
-            bslot[r, i] = s
-            bcol[r, i] = col
-            bmsb[r, i] = self.msb[s]
-            blsb[r, i] = self.lsb[s]
-            bnode[r, i] = self.node[s]
-            bkind[r, i] = self.kind[s]
-
-    def _sync_bucket_host(self) -> None:
-        """Bring the 7 host bucket-row arrays (``_bhost``) up to date with
-        ``bucket_entries`` — the single source both device consumers (the
-        single-device jnp copy and the mesh-sharded upload) build from, so
-        alternating consumers (the router switches routes between flushes)
-        never see each other's dirty-set consumption."""
-        k = self.BUCKET_K
-        g_cap = _pow2_at_least(max(len(self.bucket_entries), 1), 64)
-        if self._bhost is None or g_cap != self._g_cap:
-            blo = np.full((g_cap, k), dk.PAD_LO, np.int64)
-            bhi = np.full((g_cap, k), dk.PAD_HI, np.int64)
-            bslot = np.full((g_cap, k), -1, np.int32)
-            bcol = np.zeros((g_cap, k), np.int32)
-            bmsb = np.zeros((g_cap, k), np.int64)
-            blsb = np.zeros((g_cap, k), np.int64)
-            bnode = np.zeros((g_cap, k), np.int32)
-            bkind = np.zeros((g_cap, k), np.int32)
-            self._bhost = (blo, bhi, bslot, bcol, bmsb, blsb, bnode, bkind)
-            for r, ents in enumerate(self.bucket_entries):
-                if ents:
-                    self._fill_bucket_row(self._bhost, r, ents)
-            self._g_cap = g_cap
-            self.bucket_dirty.clear()
-            self._bdev = None          # shape changed: full re-upload
-            self._bdev_pending.clear()
-        elif self.bucket_dirty:
-            rows = sorted(self.bucket_dirty)
-            for r in rows:
-                self._fill_bucket_row(self._bhost, r, self.bucket_entries[r])
-            self._bdev_pending.update(rows)
-            self.bucket_dirty.clear()
 
     def _sync_wide_host(self, floor: int):
         """Host arrays for the wide/straggler entries, padded to a pow2 of
@@ -549,24 +542,38 @@ class _DepsMirror:
         return hit[1]
 
     def bucket_device(self) -> "dk.BucketTable":
-        """Sync the bucket index to the (single) device — dirty-row scatter,
-        like the slot table — and return the BucketTable."""
-        self._sync_bucket_host()
-        if self._bdev is None or self._bdev_pending:
+        """Sync the bucket index to the (single) device — the cells written
+        since the last sync, or the whole table when that is no more bytes
+        (the rule of device_table's "mostly dirty") — and return the
+        BucketTable."""
+        n = len(self._bpend)
+        if self._bdev is None or n:
             faults.check("transfer", "bucket upload")
-        if self._bdev is None:
-            self._bdev = tuple(jnp.asarray(a) for a in self._bhost)
-            self._bdev_pending.clear()
-        elif self._bdev_pending:
-            rows = sorted(self._bdev_pending)
-            padded = _pow2_at_least(len(rows), 8)
-            idx = np.concatenate([np.array(rows, np.int32),
-                                  np.full(padded - len(rows), rows[-1],
-                                          np.int32)])
-            self._bdev = _scatter_bucket_rows(
-                self._bdev, jnp.asarray(idx),
-                tuple(a[idx] for a in self._bhost))
-            self._bdev_pending.clear()
+            import time as _time
+            t0 = _time.perf_counter()
+            padded = _pow2_at_least(n, _MIN_CELLS)
+            nbytes = padded * _CELL_BYTES
+            if self._bdev is None or nbytes >= self._brec.nbytes:
+                kind, n, nbytes = "sync_bucket_full", 0, self._brec.nbytes
+                self._bdev = tuple(jnp.asarray(a) for a in self._bhost)
+            else:
+                # padded with the last cell (an idempotent scatter): one
+                # compilation per pow2
+                kind = "sync_bucket_cells"
+                cells = np.fromiter(self._bpend, np.int32, n)
+                cells = np.concatenate(
+                    [cells, np.full(padded - n, cells[-1], np.int32)])
+                vals = self._bflat[cells]
+                rows, pos = np.divmod(cells, np.int32(self.BUCKET_K))
+                self._bdev = _scatter_bucket_cells(
+                    self._bdev, rows, pos,
+                    tuple(np.ascontiguousarray(vals[f])
+                          for f in _BUCKET_REC.names))
+            self._bpend.clear()
+            if self.owner is not None:
+                self.owner._ktime(kind, t0)
+                self.owner.n_bucket_cells_uploaded += n
+                self.owner.bucket_upload_bytes += nbytes
         whost = self._sync_wide_host(16)
         wkey = (self.wide_version, whost[0].shape[0])
         if self._wdev is None or self._wdev_key != wkey:
@@ -581,7 +588,6 @@ class _DepsMirror:
         parallel.sharded.sharded_bucketed_flat).  Any mutation triggers a
         full sharded re-upload, keyed on the bucket/wide version counters —
         same policy as device_table_sharded."""
-        self._sync_bucket_host()
         d = int(np.prod(list(mesh.shape.values())))
         whost = self._sync_wide_host(max(16, d))
         key = (self.bucket_version, self.wide_version, self._g_cap,
@@ -1755,6 +1761,11 @@ class DeviceState:
         # kind -> [calls, seconds]; dispatch_* covers host pack + upload +
         # enqueue, wait_* the download join, host_* the host-side passes
         self.kernel_times: Dict[str, List[float]] = {}
+        # _DepsMirror.bucket_device: kernel_times' sync_bucket_cells /
+        # sync_bucket_full count its syncs by path; these the pending cells
+        # the cells path carried and the bytes either path sent
+        self.n_bucket_cells_uploaded = 0
+        self.bucket_upload_bytes = 0
         # -- device-fault tolerance (module docstring: degradation ladder) --
         # shadow-verify every device flush against the host route when True
         # (or when utils.faults.PARANOIA is set process-wide)

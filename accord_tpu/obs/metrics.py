@@ -279,6 +279,10 @@ INDEX_COUNTERS: List[Tuple[str, str]] = [
     ("slice_restores", "n_slice_restores"),
     ("shard_merge_bytes", "n_shard_merge_bytes"),
     ("oom_recovered", "n_oom_recovered"),
+    # the bucket index's way to the device: pending cells the cell scatter
+    # carried, and the bytes the cell and whole-table uploads sent
+    ("bucket_cells_uploaded", "n_bucket_cells_uploaded"),
+    ("bucket_upload_bytes", "bucket_upload_bytes"),
 ]
 
 
@@ -288,7 +292,7 @@ def index_counters(dev) -> Dict[str, int]:
     the oom flag the line always carried)."""
     out = {k: getattr(dev, attr) for k, attr in INDEX_COUNTERS[:9]}
     out["wide_entries"] = len(dev.deps.wide_entries)
-    out["buckets"] = len(dev.deps.bucket_entries)
+    out["buckets"] = len(dev.deps.bucket_row)
     for k, attr in INDEX_COUNTERS[9:]:
         out[k] = getattr(dev, attr)
     out["oom_degraded"] = int(dev.host_pinned)

@@ -133,6 +133,22 @@ def test_bucketed_attributed_scan_compiles(topo, no_persistent_cache):
     _fits(compiled)
 
 
+@pytest.mark.parametrize("cells", [2048, 32768])
+def test_bucket_cell_scatter_compiles(topo, no_persistent_cache, cells):
+    """The pending-cell sync of the bucket index at the store cells' sizes:
+    15,625 buckets in 16,384 rows; a 64-txn flush pads to the 2,048-cell
+    floor, a 2,048-txn flush to 32,768 cells."""
+    from accord_tpu.local.device_index import (_BUCKET_REC,
+                                               _scatter_bucket_cells)
+    one = SingleDeviceSharding(topo.devices[0])
+    dtypes = [_BUCKET_REC[f] for f in _BUCKET_REC.names]
+    compiled = _scatter_bucket_cells.lower(
+        tuple(_sds((16384, 128), dt, one) for dt in dtypes),
+        _sds((cells,), jnp.int32, one), _sds((cells,), jnp.int32, one),
+        tuple(_sds((cells,), dt, one) for dt in dtypes)).compile()
+    _fits(compiled)
+
+
 def _ell_state(n, d, sh):
     i64, i32 = jnp.int64, jnp.int32
     return drk.EllDrainState(_sds((n, d), i32, sh), _sds((n,), i32, sh),

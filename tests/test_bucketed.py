@@ -120,6 +120,175 @@ def _raw_deps(dev, qs):
     return out
 
 
+def _check_rows(deps):
+    """The in-place bucket rows against a from-scratch rebuild from the
+    mirror's lo/hi/status columns: per bucket row the same multiset of
+    entry records (an entry a full bucket refused sits in the wide set
+    instead), lengths that match, padding beyond each length, and a
+    per-slot cell record that points at the slot's own entries."""
+    from collections import Counter
+    from accord_tpu.local.device_index import _BUCKET_PAD
+    from accord_tpu.ops import deps_kernel as dk
+    k = deps.BUCKET_K
+    want, want_wide = {}, set()
+    for slot in np.nonzero((deps.status >= 0)
+                           & (deps.status != dk.SLOT_INVALIDATED))[0]:
+        slot = int(slot)
+        for m in range(deps.max_intervals):
+            lo, hi = int(deps.lo[slot, m]), int(deps.hi[slot, m])
+            if lo > hi:
+                continue
+            blo, bhi = lo >> deps.BSHIFT, hi >> deps.BSHIFT
+            if bhi - blo + 1 > deps.SPAN:
+                want_wide.add((lo, hi, slot, m))
+                continue
+            rec = (lo, hi, slot, m, int(deps.msb[slot]),
+                   int(deps.lsb[slot]), int(deps.node[slot]),
+                   int(deps.kind[slot]))
+            for bid in range(blo, bhi + 1):
+                want.setdefault(bid, Counter())[rec] += 1
+    assert set(want) <= set(deps.bucket_row)
+    assert sorted(deps.bucket_row.values()) == list(range(len(deps._blen)))
+    assert len(deps._blen) <= deps._g_cap == deps._brec.shape[0]
+    spilled = set()
+    for bid, row in deps.bucket_row.items():
+        n = deps._blen[row]
+        got = Counter(tuple(int(v) for v in rec)
+                      for rec in deps._brec[row, :n])
+        missing = want.get(bid, Counter()) - got
+        assert not got - want.get(bid, Counter()), (bid, got)
+        spilled.update(rec[:4] for rec in missing)
+        assert (deps._brec[row, n:] == _BUCKET_PAD).all(), (bid, n)
+    assert (deps._brec[len(deps._blen):] == _BUCKET_PAD).all()
+    assert deps.wide_entries == want_wide | spilled
+    assert deps.bucket_max_len >= max(deps._blen, default=0)
+    cells = [c for cs in deps._bcells.values() for c in cs]
+    assert len(cells) == len(set(cells)) == sum(deps._blen)
+    for slot, cs in deps._bcells.items():
+        assert cs and all(deps._bflat[c]["slot"] == slot for c in cs)
+        assert all(c % k < deps._blen[c // k] for c in cs)
+
+
+def _check_device(deps):
+    """bucket_device() leaves the device arrays equal to the host rows."""
+    btable = deps.bucket_device()
+    assert not deps._bpend
+    for dev_a, host_a in zip(btable[:8], deps._bhost):
+        assert dev_a.dtype == host_a.dtype
+        assert np.array_equal(np.asarray(dev_a), host_a)
+
+
+@pytest.mark.parametrize("seed", [41, 42])
+def test_bucket_rows_kept_in_place_property(seed):
+    """A random register / invalidate / free sequence with a hot bucket
+    driven past BUCKET_K into the wide spill and back, and enough distinct
+    buckets to grow the row arrays twice: the in-place rows always equal a
+    from-scratch rebuild, on the host and (after a sync) on the device."""
+    rng = np.random.default_rng(seed)
+    keyspace = 20_000                   # 313 buckets: g_cap 64 -> 256
+    store, dev, safe = _mk_state()
+    deps = dev.deps
+    live, hlc, high_water, hot_lens = [], 1, 0, []
+    g_caps = {deps._g_cap}
+    for step in range(900):
+        filling = (step // 300) % 2 == 0        # fill, drain, fill
+        if rng.random() < (0.85 if filling else 0.15) or not live:
+            (tid, toks, rngs), = _workload(
+                rng, 1, keyspace, wide_frac=0.1,
+                hot_frac=0.6 if filling else 0.0)
+            tid = TxnId.create(1, hlc, tid.kind(), tid.domain(),
+                               1 + int(rng.integers(0, 5)))
+            hlc += 1
+            keys = Ranges.of(*rngs) if rngs else \
+                Keys([IntKey(t) for t in toks])
+            dev.register(tid, int(InternalStatus.PREACCEPTED), keys)
+            live.append(tid)
+        else:
+            tid = live.pop(int(rng.integers(0, len(live))))
+            if rng.random() < 0.3:
+                dev.update_status(tid, int(InternalStatus.INVALIDATED))
+            if rng.random() < 0.8:
+                dev.free(tid)       # an invalidated slot may stay
+        assert deps.bucket_max_len >= high_water
+        high_water = deps.bucket_max_len
+        g_caps.add(deps._g_cap)
+        hot_row = deps.bucket_row.get(0)
+        if hot_row is not None:
+            hot_lens.append(deps._blen[hot_row])
+        if step % 25 == 24:
+            _check_rows(deps)
+        if step % 75 == 74:
+            _check_device(deps)
+    _check_rows(deps)
+    _check_device(deps)
+    assert g_caps >= {64, 128, 256}
+    full = hot_lens.index(deps.BUCKET_K)        # into the spill ...
+    assert min(hot_lens[full:]) < deps.BUCKET_K // 2        # ... and back
+    assert deps.bucket_max_len == deps.BUCKET_K
+    kt = dev.kernel_times
+    assert kt["sync_bucket_cells"][0] + kt["sync_bucket_full"][0] == 12
+    assert kt["sync_bucket_full"][0] >= 3       # first sync + two grows
+    # same answers as the dense kernel on the state all of that left
+    qs = _queries(rng, 16, keyspace, 10_000)
+    got = _raw_deps(dev, qs)
+    dev.BUCKETED = False
+    assert got == _raw_deps(dev, qs)
+
+
+@pytest.mark.parametrize("path", ["cells", "full"])
+def test_bucket_device_uploads_cells_or_the_whole_table(path):
+    """After the first (whole-table) upload a sync carries the pending
+    cells alone — or the table again once the cells would cost as many
+    bytes; either way device == host, and the counters say which."""
+    from accord_tpu.local.device_index import _CELL_BYTES, _MIN_CELLS
+    rng = np.random.default_rng(5)
+    store, dev, safe = _mk_state()
+    deps = dev.deps
+    hlc = iter(range(1, 1 << 20))
+
+    def register(n_txns, n_keys):
+        # 64 buckets only: the row arrays never grow
+        for _ in range(n_txns):
+            tid = TxnId.create(1, next(hlc), TxnKind.Write, Domain.Key, 1)
+            toks = rng.choice(64 << deps.BSHIFT, n_keys, replace=False)
+            dev.register(tid, int(InternalStatus.PREACCEPTED),
+                         Keys([IntKey(int(t)) for t in toks]))
+            yield tid
+
+    first = list(register(20, 4))
+    _check_device(deps)
+    table_bytes = deps._brec.nbytes
+    assert table_bytes == 64 * deps.BUCKET_K * 48
+    assert {k: c for k, (c, _s) in dev.kernel_times.items()} == \
+        {"sync_bucket_full": 1}
+    assert (dev.n_bucket_cells_uploaded, dev.bucket_upload_bytes) == \
+        (0, table_bytes)
+    _check_device(deps)                 # nothing pending: no sync at all
+    assert dev.bucket_upload_bytes == table_bytes
+    if path == "cells":
+        for tid in first[:5]:
+            dev.free(tid)
+        list(register(3, 4))
+        n = len(deps._bpend)
+        assert 12 <= n <= 12 + 2 * 20
+        _check_device(deps)
+        assert dev.kernel_times["sync_bucket_cells"][0] == 1
+        assert dev.kernel_times["sync_bucket_full"][0] == 1
+        assert dev.n_bucket_cells_uploaded == n
+        assert dev.bucket_upload_bytes == \
+            table_bytes + _MIN_CELLS * _CELL_BYTES
+    else:
+        list(register(1100, 4))
+        assert deps._g_cap == 64 and len(deps._bpend) == 4400
+        assert 8192 * _CELL_BYTES >= table_bytes > 4096 * _CELL_BYTES
+        _check_device(deps)
+        assert "sync_bucket_cells" not in dev.kernel_times
+        assert dev.kernel_times["sync_bucket_full"][0] == 2
+        assert (dev.n_bucket_cells_uploaded, dev.bucket_upload_bytes) == \
+            (0, 2 * table_bytes)
+    _check_rows(deps)
+
+
 @pytest.mark.parametrize("shape", ["spread", "hot", "wide", "mixed"])
 def test_bucketed_matches_bruteforce_and_dense(shape):
     rng = np.random.default_rng({"spread": 1, "hot": 2, "wide": 3,
@@ -162,11 +331,11 @@ def test_bucketed_survives_frees_and_requery():
     for q, g in zip(qs, got):
         assert g == _brute(kept, q)
     # the freed slots must be fully de-indexed: no stale bucket entries
-    live = set()
-    for ents in dev.deps.bucket_entries:
-        live.update(s for (_l, _h, s, _c) in ents)
+    bslot = dev.deps._bhost[2]
+    live = set(bslot[bslot >= 0].tolist())
     live.update(s for (_l, _h, s, _c) in dev.deps.wide_entries)
-    assert all(dev.deps.id_of.get(s) is not None for s in live)
+    assert live and all(dev.deps.id_of.get(s) is not None for s in live)
+    _check_rows(dev.deps)
 
 
 def test_bucketed_attributed_matches_dense_attributed():
