@@ -1224,6 +1224,14 @@ class _DrainMirror:
     # adjacency instead of the dense matrix: dense [n, n] at 100k in-flight
     # is 10GB of bools; ELL is n x max_degree
     DENSE_MAX = 8192
+    # the smallest compacted state, and the smallest dirty-row scatter into
+    # a state that large.  Every (state size) and (state size, scatter size)
+    # is a separately compiled program, and so is every tuple of state sizes
+    # that a fused tick stacks.  A served store's live set sits below 64
+    # slots, so with this floor a node meets ONE program of each kind, in
+    # its first ticks; with 16 it met 16, then now and then 32 and the
+    # mixed pairs, and compiled each on the serving loop when it first did
+    MIN_STATE_SLOTS = 64
 
     def state(self):
         """Compacted drain state over LIVE slots only (padded to a power-of-
@@ -1251,7 +1259,8 @@ class _DrainMirror:
             rows, li = rows[ok], li[ok].astype(np.int32)
             st = c["state"]
             if len(li):
-                padded = _pow2_at_least(len(li), 8)
+                padded = _pow2_at_least(
+                    len(li), min(len(st.status), self.MIN_STATE_SLOTS))
                 idx = np.concatenate(
                     [li, np.full(padded - len(li), li[-1], np.int32)])
                 rws = np.concatenate(
@@ -1268,7 +1277,7 @@ class _DrainMirror:
             self._dirty_scalars.clear()
             return st, c["live"]
         live = np.nonzero(self.status != dk.SLOT_FREE)[0]
-        n = _pow2_at_least(len(live), 16)
+        n = _pow2_at_least(len(live), self.MIN_STATE_SLOTS)
         local = np.full(self.capacity, -1, np.int32)
         local[live] = np.arange(len(live), dtype=np.int32)
         status = np.full(n, dk.SLOT_FREE, np.int32)

@@ -33,6 +33,12 @@ class RunResult:
         # the run's obs.Observability (set by the runner that produced
         # this result) — obs_row_fields reads phase latencies from it
         self.obs = None
+        # what the clients saw, as sim/serial_kv.replay takes it: one
+        # (start, end, reads, appends) per txn_ok, one (start, appends) per
+        # txn with appends that was not answered ok, and the final lists
+        self.answered: List[tuple] = []
+        self.unanswered: List[tuple] = []
+        self.finals: Dict[int, tuple] = {}
 
     def p99_micros(self) -> Optional[int]:
         if not self.latencies_micros:
@@ -144,13 +150,16 @@ class MaelstromRunner:
                      keys_per_txn: Optional[int] = None,
                      zipf_skew: Optional[float] = None,
                      spread_ring: bool = False,
-                     value_kinds: Optional[tuple] = None) -> RunResult:
+                     value_kinds: Optional[tuple] = None,
+                     key_table: Optional[List[int]] = None) -> RunResult:
         """``keys_per_txn`` pins the txn width (default 1..3 random);
         ``zipf_skew`` draws keys Zipf-distributed over [0, n_keys) —
         configs[1]'s 4-key multi-partition Zipf-0.9 shape.
         ``spread_ring`` strides key values across the whole token ring so
         an N-key space actually lands on every shard (small ints all hash
         into shard 0 otherwise — a 'multi-partition' workload must be).
+        ``key_table`` maps the drawn index (under zipf: the rank) to its
+        key instead, so hot ranks need not be neighbours on the ring.
         ``value_kinds`` cycles appended values through the reference's
         datum kinds (subset of ("long", "string", "double", "hash");
         default None keeps plain unique ints) — values cross the client
@@ -166,7 +175,7 @@ class MaelstromRunner:
         def pick_key() -> int:
             k = (wl.next_zipf(n_keys, zipf_skew) if zipf_skew is not None
                  else wl.next_int(n_keys))
-            return k * stride
+            return key_table[k] if key_table is not None else k * stride
 
         def make_value(i: int):
             """(client-JSON form, canonical form) for unique value #i —
@@ -214,13 +223,15 @@ class MaelstromRunner:
                     reads.append(token_of(k))
             op_id = verifier.begin()
             start = self.queue.now
-            pending[i] = True
+            pending[i] = (start, writes)
             msg_id = 10_000 + i
 
             def on_reply(body: dict):
                 pending.pop(i, None)
                 if body.get("type") != "txn_ok":
                     self.result.ops_failed += 1
+                    if writes:
+                        self.result.unanswered.append((start, writes))
                     return
                 self.result.ops_ok += 1
                 self.result.latencies_micros.append(self.queue.now - start)
@@ -239,6 +250,8 @@ class MaelstromRunner:
                         observed[t] = vals
                 verifier.on_result(op_id, start, self.queue.now,
                                    observed, writes)
+                self.result.answered.append((start, self.queue.now,
+                                             observed, writes))
 
             self.client_handlers[msg_id] = on_reply
             self._deliver(node, {"src": f"c{i + 1}", "dest": node,
@@ -251,6 +264,8 @@ class MaelstromRunner:
                 self.queue_drain()
         self.queue_drain()
         self.result.ops_unresolved = len(pending)
+        self.result.unanswered += [(at, appends) for at, appends
+                                   in pending.values() if appends]
         if verify:
             # finals: after quiescence every owning replica has the full
             # list; take the longest copy per token across data stores
@@ -261,6 +276,7 @@ class MaelstromRunner:
                     value = store.get(token)
                     if len(value) > len(finals.get(token, ())):
                         finals[token] = value
+            self.result.finals = finals
             for token, value in finals.items():
                 verifier.set_final(token, value)
             verifier.verify()

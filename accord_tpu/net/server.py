@@ -626,6 +626,7 @@ class NodeServer:
             "frames_coalesced": sum(l["frames_coalesced"]
                                     for l in links.values()),
             "client_replies": self.n_client_replies,
+            "coordination": self._coordination_stats(),
             "unroutable": self.n_unroutable,
             "reply_drops": self.n_reply_drops,
             "frame_errors": (self.frame_server.n_frame_errors
@@ -643,6 +644,21 @@ class NodeServer:
             "chunks": dict(self._chunks.stats(),
                            streams_tx=self.n_chunk_streams_tx,
                            chunk_frames_tx=self.n_chunk_frames_tx),
+        }
+
+    def _coordination_stats(self) -> Optional[dict]:
+        """Which path this node's coordinated txns took (the PreAccept
+        decision, obs/spans.decision) and the recoveries it started, from
+        the counters obs.metrics already keeps; None while nothing counts
+        the decisions (ACCORD_TPU_OBS=off, or before start())."""
+        obs = self.proc.obs if self.proc is not None else None
+        if obs is None or obs.spans is None:
+            return None
+        return {
+            "fast": obs.metrics.peek_counter("txn_path", path="fast"),
+            "slow": obs.metrics.peek_counter("txn_path", path="slow"),
+            "recoveries": obs.metrics.counter_totals(
+                "recoveries", by="event").get("attempt", 0),
         }
 
     # -- lifecycle ------------------------------------------------------------

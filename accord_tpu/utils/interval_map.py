@@ -196,11 +196,13 @@ class ReducingRangeMap(Generic[V]):
         # neighbours — e.g. a max() above both)
         kb: List[int] = list(nb[:max(w0, 0)])
         kv: List[Optional[V]] = list(nv[:max(w0, 0) + 1])
-        for k in range(max(w0, 0), len(nb)):
-            if k <= w1 and nv[k + 1] == kv[-1]:
+        for k in range(max(w0, 0), min(w1, len(nb) - 1) + 1):
+            if nv[k + 1] == kv[-1]:
                 continue
             kb.append(nb[k])
             kv.append(nv[k + 1])
+        kb.extend(nb[w1 + 1:])     # past the splice nothing can have changed
+        kv.extend(nv[w1 + 2:])
         return ReducingRangeMap(kb, kv)
 
     def __eq__(self, o):
