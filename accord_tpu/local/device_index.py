@@ -66,7 +66,14 @@ is CORRECTNESS-PRESERVING by construction:
  - while quarantined every flush (and drain tick) is pinned to host; the
    quarantine expires after an exponential-backoff flush count with
    deterministic jitter, then ONE probe flush re-tries the device route —
-   success restores it, failure re-quarantines deeper;
+   success restores it, failure re-quarantines deeper (a drain tick that
+   the ROUTER sweeps on the host, because its live set is too small to
+   pay for the round trip, is no rung of this ladder: it is counted in
+   ``n_priced_host_ticks`` and kernel_times ``drain_tick_host``, never in
+   ``n_host_ticks`` or a fault counter; so that such a store still meets
+   this ladder, its first tick and then one priced tick every
+   ``TICK_AUDIT_MICROS`` of the node's clock go to the device all the
+   same: ``n_audit_ticks``);
  - paranoia mode (utils.faults.PARANOIA or DeviceState.paranoia)
    shadow-verifies every device flush against the host route and treats a
    mismatch as a device fault — the detector for silent result corruption
@@ -89,7 +96,10 @@ host per-element scan cost (DeviceState._measure_route_calibration); the
 router compares a modeled host scan cost (live-above-floor working set,
 estimated O(1) per dispatch from _DepsMirror's incremental counters +
 RedundantBefore.version) against the modeled device cost and picks the
-cheaper side.  ``DeviceState.route_override`` pins a route for tests and
+cheaper side.  The drain tick is priced the same way (_host_tick_pays: the
+Python sweep over the driven rows and the mirror's dep edges, ``c_sweep``
+each, against the round trip plus the frontier program over the padded state).
+``DeviceState.route_override`` pins a route for tests and
 benches; per-route dispatch counters (n_host_queries / n_bucketed_queries /
 n_dense_queries / n_mesh_queries) make routing regressions visible in
 every BENCH artifact.
@@ -1131,11 +1141,14 @@ class _DrainMirror:
         self.version = 0
         self.membership_version = 0
         self.edge_version = 0
+        self.n_edges = 0        # sum of len(deps_of[.]): what a tick prices
         self._dirty_scalars: Set[int] = set()
         self._state_cache: Optional[Dict[str, object]] = None
 
     # -- edge maintenance ---------------------------------------------------
     def add_edge(self, waiter: int, dep: int) -> None:
+        if dep not in self.deps_of[waiter]:
+            self.n_edges += 1
         self.deps_of[waiter].add(dep)
         self.waiters_of[dep].add(waiter)
         self.edge_version += 1
@@ -1147,6 +1160,7 @@ class _DrainMirror:
             self.version += 1
         for dep in self.deps_of[slot]:
             self.waiters_of[dep].discard(slot)
+        self.n_edges -= len(self.deps_of[slot])
         self.deps_of[slot].clear()
 
     def _clear_edges(self, slot: int) -> None:
@@ -1156,6 +1170,7 @@ class _DrainMirror:
             self.version += 1
         for w in self.waiters_of[slot]:
             self.deps_of[w].discard(slot)
+        self.n_edges -= len(self.waiters_of[slot])
         self.waiters_of[slot].clear()
 
     def alloc(self, txn_id: TxnId) -> int:
@@ -1339,6 +1354,41 @@ class _DrainMirror:
             s = int(slot)
             if not self.waiters_of[s] and self.id_of.get(s) is not None:
                 self.free(s)
+
+    def host_ready_slots(self) -> np.ndarray:
+        """The drain frontier sweep on the host — EXACTLY
+        drain_kernel.ready_frontier's rule over the sparse adjacency: a
+        driven Stable row is ready unless some dep is live, non-applied,
+        and gating (undecided, executing earlier, or the row awaits all
+        deps).  A Python loop over the driven rows and the dep edges they
+        visit, so its cost is theirs (``c_sweep`` of the route
+        calibration): the route of every tick whose live set is too small
+        to pay for a device round trip (DeviceState._host_tick_pays), and
+        the bottom rung of the degradation ladder for the rest."""
+        m64 = (1 << 64) - 1
+        out = []
+        for i in np.nonzero((self.status == dk.SLOT_STABLE) & self.active)[0]:
+            i = int(i)
+            ei = (int(self.exec_msb[i]) & m64, int(self.exec_lsb[i]) & m64,
+                  int(self.exec_node[i]))
+            awaits = bool(self.awaits_all[i])
+            blocked = False
+            for j in self.deps_of[i]:
+                stj = int(self.status[j])
+                if stj in (dk.SLOT_FREE, dk.SLOT_INVALIDATED,
+                           dk.SLOT_APPLIED):
+                    continue
+                if stj < dk.SLOT_COMMITTED or awaits:
+                    blocked = True      # undecided always gates
+                    break
+                ej = (int(self.exec_msb[j]) & m64, int(self.exec_lsb[j]) & m64,
+                      int(self.exec_node[j]))
+                if ej < ei:             # executes before i: gates
+                    blocked = True
+                    break
+            if not blocked:
+                out.append(i)
+        return np.array(out, np.int64)
 
 
 def _group_dedupe(cols):
@@ -1811,7 +1861,16 @@ class DeviceState:
         self.n_compactions = 0
         self.n_compacted_slots = 0
         self.n_oom_degraded = 0
-        self.n_host_ticks = 0          # drain ticks swept on host fallback
+        # drain ticks swept on the host: as the ladder's fallback
+        # (quarantined, host-pinned or a fault mid-tick), and because the
+        # router priced the device round trip dearer (_host_tick_pays) —
+        # a choice, never counted with the faults
+        self.n_host_ticks = 0
+        self.n_priced_host_ticks = 0
+        # priced to the host and sent to the device all the same
+        # (_audit_tick), and the node's clock at the last device tick
+        self.n_audit_ticks = 0
+        self._tick_dev_micros: Optional[int] = None
         # r21 store-sharded residency (parallel.store_shard): the spill
         # rung's StoreShards instance (None until the ladder activates it),
         # flush/byte counters, the per-slice quarantine tallies, and the
@@ -2110,39 +2169,6 @@ class DeviceState:
                 d.free(tid)
                 freed += 1
         return freed
-
-    def _host_ready_slots(self) -> np.ndarray:
-        """Host replacement of the drain frontier sweep (the bottom rung of
-        the degradation ladder) — EXACTLY drain_kernel.ready_frontier's
-        rule over the drain mirror's sparse adjacency: a Stable row is
-        ready unless some dep is live, non-applied, and gating (undecided,
-        executing earlier, or the row awaits all deps).  Python-loop over
-        the in-flight set: this path runs only quarantined/degraded."""
-        dr = self.drain
-        m64 = (1 << 64) - 1
-        out = []
-        for i in np.nonzero((dr.status == dk.SLOT_STABLE) & dr.active)[0]:
-            i = int(i)
-            ei = (int(dr.exec_msb[i]) & m64, int(dr.exec_lsb[i]) & m64,
-                  int(dr.exec_node[i]))
-            awaits = bool(dr.awaits_all[i])
-            blocked = False
-            for j in dr.deps_of[i]:
-                stj = int(dr.status[j])
-                if stj in (dk.SLOT_FREE, dk.SLOT_INVALIDATED,
-                           dk.SLOT_APPLIED):
-                    continue
-                if stj < dk.SLOT_COMMITTED or awaits:
-                    blocked = True      # undecided always gates
-                    break
-                ej = (int(dr.exec_msb[j]) & m64, int(dr.exec_lsb[j]) & m64,
-                      int(dr.exec_node[j]))
-                if ej < ei:             # executes before i: gates
-                    blocked = True
-                    break
-            if not blocked:
-                out.append(i)
-        return np.array(out, np.int64)
 
     # ------------------------------------------------------------------
     # the deps query (device replacement of map_reduce_active fold)
@@ -2457,8 +2483,9 @@ class DeviceState:
     FORCE_TRIPLE_DEDUPE = False
 
     # process-wide route calibration: {"rtt": s, "c_dev": s/elem,
-    # "c_host": s/elem}, measured once by a micro-probe (or injected by
-    # tests via set_route_calibration)
+    # "c_host": s/elem, "c_sweep": s per row or edge of the host drain
+    # sweep, ...}, measured once by a micro-probe (or injected by tests via
+    # set_route_calibration)
     _CALIB = None
 
     @classmethod
@@ -2467,11 +2494,12 @@ class DeviceState:
                               rtt_mesh: Optional[float] = None,
                               c_xfer: float = 0.0,
                               c_attr: float = 0.0,
-                              c_shard: float = 0.0) -> None:
+                              c_shard: float = 0.0,
+                              c_sweep: float = 1e-6) -> None:
         cls._CALIB = {"rtt": rtt, "c_host": c_host, "c_dev": c_dev,
                       "rtt_mesh": rtt_mesh if rtt_mesh is not None else rtt,
                       "c_xfer": c_xfer, "c_attr": c_attr,
-                      "c_shard": c_shard}
+                      "c_shard": c_shard, "c_sweep": c_sweep}
 
     @staticmethod
     def _measure_route_calibration():
@@ -2480,8 +2508,9 @@ class DeviceState:
         on a high-round-trip host-device link this dominates small scans),
         (b) the device per-element kernel cost (a mid-size dense scan minus
         the round trip), (c) the host per-element cost of the vectorized
-        numpy predicate the host route runs.  No hard-coded thresholds:
-        the crossover IS these three numbers."""
+        numpy predicate the host route runs, and the coefficients of the
+        later routes (copy, transfer, attribution, the host drain sweep).
+        No hard-coded thresholds: the crossovers ARE these numbers."""
         import statistics as _st
         import time as _time
         x = jnp.arange(256, dtype=jnp.int64)
@@ -2583,8 +2612,29 @@ class DeviceState:
                 table, attr, aidx, qmat, rb0, *zeros3, m, s_probe, 64))
         t_attr = (_time.perf_counter() - t0) / 3
         c_attr = max(t_attr - t_raw, 0.0) / s_probe
+        # the host drain sweep is a Python loop, not a numpy pass, so
+        # c_host is the wrong price for it: time the sweep itself on a
+        # mirror of Stable rows whose deps are all decided and execute
+        # later (nothing gates, so every edge is visited), and divide by
+        # the rows and edges it walked
+        rows, deg = 256, 4
+        mirror = _DrainMirror(2 * rows)
+        mirror.status[:rows] = dk.SLOT_STABLE
+        mirror.status[rows:] = dk.SLOT_COMMITTED
+        mirror.exec_msb[rows:] = 1
+        mirror.active[:rows] = True
+        for i in range(rows):
+            mirror.deps_of[i] = {rows + (i + j) % rows for j in range(deg)}
+        mirror.host_ready_slots()                # warm
+        sweeps = []
+        for _ in range(3):
+            t0 = _time.perf_counter()
+            mirror.host_ready_slots()
+            sweeps.append(_time.perf_counter() - t0)
+        c_sweep = max(_st.median(sweeps), 1e-9) / (rows * (1 + deg))
         return {"rtt": rtt, "c_dev": c_dev, "c_host": c_host,
-                "c_copy": c_copy, "c_xfer": c_xfer, "c_attr": c_attr}
+                "c_copy": c_copy, "c_xfer": c_xfer, "c_attr": c_attr,
+                "c_sweep": c_sweep}
 
     @staticmethod
     def _measure_mesh_rtt(mesh) -> float:
@@ -4171,6 +4221,72 @@ class DeviceState:
             + calib["c_dev"] * float(n) * n / d
         return mesh < single
 
+    def _host_tick_pays(self) -> bool:
+        """Priced drain tick: sweep the frontier on the host when the
+        Python loop over the driven Stable rows and the dep edges is
+        modeled cheaper than the device round trip — models, not
+        thresholds, from the calibration the deps router uses.  The host
+        side counts EVERY edge of the mirror (``n_edges``, kept O(1)), the
+        undriven rows' too: an upper bound on what the sweep visits, so
+        the error is toward the device.  The device side is the
+        single-device frontier program over the padded state (dense
+        [n, n], or ELL [n, degree] above DENSE_MAX with the mean degree
+        per driven row, padded as state() pads it, standing in for the
+        maximum state() would have to walk the live set to find); the
+        Python rebuild and upload state() makes after an edge moved are
+        not in it, which errs toward the device as well.  A served store's
+        few dozen live slots price to the host; a deep or wide drain
+        prices to the device.  ``route_override`` pins either way
+        ("host", else the device)."""
+        if self.route_override is not None:
+            return self.route_override == "host"
+        dr = self.drain
+        calib = self._calibration()
+        rows = int(np.count_nonzero((dr.status == dk.SLOT_STABLE)
+                                    & dr.active))
+        host = calib["c_sweep"] * (rows + dr.n_edges)
+        n = _pow2_at_least(len(dr.id_of), dr.MIN_STATE_SLOTS)
+        if n <= dr.DENSE_MAX:
+            elems = float(n) * n
+        else:
+            elems = float(n) * _pow2_at_least(-(-dr.n_edges // max(rows, 1)))
+        return host < 2.0 * calib["rtt"] + calib["c_dev"] * elems
+
+    # A store whose every tick prices to the host would never cross the
+    # device boundary again after start-up: a device route that broke
+    # meanwhile would be found by the first deep drain that needs it and
+    # not by the ladder while the host sweep can still carry the load, and
+    # no run would hold the device tick's cost beside the host sweep's.
+    # So the device route of a tick is never left alone for longer than
+    # this, on the node's clock (simulated in a sim: replays stay exact).
+    # 3 s lies under the 4 s slice the served benchmark cells trace, which
+    # has to hold a device operation (PERF.md §7); nothing else sizes it
+    TICK_AUDIT_MICROS = 3_000_000
+
+    def _node_micros(self) -> Optional[int]:
+        now = getattr(getattr(self.store, "node", None), "now_micros", None)
+        return None if now is None else now()
+
+    def _audit_tick(self) -> bool:
+        """Asked by _tick of a tick the router priced to the host: True,
+        and counted, when this store's last device tick is
+        ``TICK_AUDIT_MICROS`` old or it never had one (so a store meets the
+        tick's program in its first tick, where a served node warms up, and
+        not seconds into its traffic), so this tick is the store's audit of
+        the device route: the whole tick on the device, solo, with the
+        ladder under it as under any device tick.  Never under a pin, nor
+        on a store without a clock."""
+        if self.route_override is not None:
+            return False
+        now = self._node_micros()
+        if now is None:
+            return False
+        if self._tick_dev_micros is not None \
+                and now - self._tick_dev_micros < self.TICK_AUDIT_MICROS:
+            return False
+        self.n_audit_ticks += 1
+        return True
+
     # Coalescing quantum for drain ticks (simulated/real micros): many dep
     # transitions land per tick, so the per-tick adjacency upload + kernel
     # sweep amortizes across a whole antichain instead of firing per event.
@@ -4216,17 +4332,31 @@ class DeviceState:
         used_fused = False
         mode = None
         if not (self.host_pinned or self._dev_quar_flushes > 0):
+            import time as _time
+            _t0 = _time.perf_counter()
             if fused is not None and fused.serves(self):
                 try:
                     cand_slots = fused.result_for(self)
                     self.n_fused_ticks += 1
                     used_fused = True
                     mode = "fused"
+                    self._tick_dev_micros = self._node_micros()
                 except faults.DEVICE_EXCEPTIONS as e:
                     fused.poison(e)
+            elif self._drain_wavefront <= 1 and self._host_tick_pays() \
+                    and not self._audit_tick():
+                # priced to the host: nothing is uploaded or launched.  A
+                # choice of the router and no fault, so it counts in no
+                # ladder counter and not in n_host_ticks.  A widened
+                # wavefront (mid-cascade) is not re-priced: one antichain is
+                # what the host sweep finds, the level kernel finds W
+                cand_slots = self.drain.host_ready_slots()
+                self._ktime("drain_tick_host", _t0)
+                self.n_priced_host_ticks += 1
+                mode = "host-priced"
             else:
+                self._tick_dev_micros = self._node_micros()
                 try:
-                    import time as _time
                     _t0 = _time.perf_counter()
                     dk.launch_check("drain")
                     state, live = self.drain.state()
@@ -4289,7 +4419,7 @@ class DeviceState:
                     self._device_fault(e, f"drain tick: {e}")
         if cand_slots is None:
             self.n_host_ticks += 1
-            cand_slots = self._host_ready_slots()
+            cand_slots = self.drain.host_ready_slots()
             mode = "host"
         obs = getattr(getattr(self.store, "node", None),
                       "drain_observer", None)
@@ -4305,10 +4435,15 @@ class DeviceState:
         # adaptive wavefront control (r19): widen only in the synchronous-
         # cascade regime — every candidate this tick reached Applied before
         # the tick returned (a serial chain drains in O(log depth) ticks
-        # instead of one tick per link).  Anything else (async execution,
-        # host/fused/mesh route, empty sweep, escape hatch) pins W back to
-        # 1, so protocol-flow ticks run the exact pre-r19 frontier sweep.
-        if mode in ("device", "ell", "wave", "ell-wave") \
+        # instead of one tick per link).  A tick the router swept on the
+        # host widens like the device sweep it stands for, or a store whose
+        # one-antichain tick prices to the host could never start the
+        # cascade and would drain a chain one link a tick; the widened tick
+        # then runs the level kernel.  Anything else (async execution, the
+        # ladder's host fallback, fused/mesh route, empty sweep, escape
+        # hatch) pins W back to 1, so protocol-flow ticks run the exact
+        # pre-r19 frontier sweep.
+        if mode in ("device", "ell", "wave", "ell-wave", "host-priced") \
                 and len(cand_slots) != 0 and drk.drain_logdepth_enabled() \
                 and all(int(self.drain.status[int(s)]) == dk.SLOT_APPLIED
                         for s in cand_slots):

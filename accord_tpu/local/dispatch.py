@@ -29,7 +29,9 @@ boundary:
   one dispatcher event, and the single-device frontier sweeps of the
   members fuse into one vmapped launch
   (ops.drain_kernel.fused_ready_frontier[_ell]) when the same pricing says
-  it pays.
+  it pays.  Members are the stores whose sweep goes to the device at all:
+  a live set too small to pay for a round trip is swept on the host
+  (DeviceState._host_tick_pays) and joins no fused launch.
 
 Correctness contract: every fused launch is BIT-IDENTICAL to the solo
 launches it replaces (tests/test_routing.py property tests), and the r07
@@ -501,11 +503,16 @@ class DeviceDispatcher:
     def _prepare_fused_ticks(self, devs) -> Dict[int, FusedTick]:
         # a widened-wavefront store (r19, _drain_wavefront > 1) is mid-
         # cascade and runs the level kernel solo — the fused frontier sweep
-        # would shrink its candidate set back to one antichain
+        # would shrink its candidate set back to one antichain.  A store
+        # whose sweep is priced cheaper on the host stays out too: a fused
+        # tick pays the same round trip its solo tick would (and its audit
+        # tick, DeviceState._audit_tick, runs solo: one program, not one
+        # for every tuple of sizes)
         cands = [d for d in devs
                  if not (d.host_pinned or d._dev_quar_flushes > 0)
                  and getattr(d, "_drain_wavefront", 1) <= 1
-                 and d.drain.active.any()]
+                 and d.drain.active.any()
+                 and not d._host_tick_pays()]
         if len(cands) < 2:
             return {}
         try:

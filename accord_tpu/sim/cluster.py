@@ -428,8 +428,9 @@ class Cluster:
         node.fault_observer = fault_observer
 
         def drain_observer(store, mode, frontier, nid=node.node_id):
-            """One drain-tick frontier sweep (mode device/fused/host/ell/
-            mesh, frontier = ready candidates): the drain-regime forensics
+            """One drain-tick frontier sweep (mode device/fused/ell/mesh/
+            wave, host-priced = the router's choice, host = the ladder's
+            fallback; frontier = ready candidates): the drain-regime forensics
             leg — per-tick frontier sizes as a registry histogram and a
             flight-ring entry, so a drain stall's shape (many empty sweeps?
             one giant antichain?) is in the post-mortem, not lost."""

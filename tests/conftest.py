@@ -167,6 +167,24 @@ class DeviceTestSafe:
         return self.store.redundant_before
 
 
+@pytest.fixture
+def drain_ticks_on_device():
+    """For a test that means the device: the live sets of a sim are a few
+    slots, which the router sweeps on the host (DeviceState._host_tick_pays).
+    Prices that sweep out, on top of the process's own calibration, so that
+    every drain tick meets the device boundary: its launches, its fused
+    launches and the faults armed there."""
+    from accord_tpu.local.device_index import DeviceState
+    saved = DeviceState._CALIB
+    DeviceState._CALIB = {
+        **(saved or DeviceState._measure_route_calibration()),
+        "c_sweep": 1.0}
+    try:
+        yield
+    finally:
+        DeviceState._CALIB = saved
+
+
 def make_device_state(mesh="auto"):
     """(store, DeviceState, safe) — ``mesh=None`` pins the single-device
     path under the test mesh; "auto" keeps DeviceState's own choice."""

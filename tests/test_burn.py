@@ -123,7 +123,8 @@ def test_burn_boundary_churn_sweep(seed):
 
 @pytest.mark.faults
 @pytest.mark.parametrize("kind", ["transfer", "all"])
-def test_burn_device_faults_equivalent_and_deterministic(kind):
+def test_burn_device_faults_equivalent_and_deterministic(
+        kind, drain_ticks_on_device):
     """Device-fault nemesis (--device-faults): with accelerator faults
     continuously injected at 5% per boundary crossing, the burn must (a)
     complete with zero unresolved ops and zero node-level failures, (b)
@@ -222,7 +223,8 @@ def test_burn_recovery_nemesis_deterministic():
 
 
 @pytest.mark.faults
-def test_burn_recovery_nemesis_composes_with_device_faults():
+def test_burn_recovery_nemesis_composes_with_device_faults(
+        drain_ticks_on_device):
     """The r07 device-fault nemesis and the r14 recovery nemesis compose:
     with both armed, the burn converges, replays deterministically, and
     the degradation ladder stays protocol-invisible — the composed run's

@@ -149,7 +149,7 @@ def test_fusion_env_knob(monkeypatch):
     __import__("accord_tpu.local.dispatch",
                fromlist=["fusion_enabled"]).fusion_enabled() is False,
     reason="ACCORD_TPU_FUSION=off canary run: live-path fusion pinned solo")
-def test_sim_burn_coalesces_launches():
+def test_sim_burn_coalesces_launches(drain_ticks_on_device):
     from accord_tpu.sim.burn import run_burn
     r = run_burn(5, n_ops=30)
     assert r.ops_unresolved == 0

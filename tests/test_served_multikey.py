@@ -1,14 +1,17 @@
 """Five NodeServers in this process over loopback TCP, device on, under
 multi-key txns on a skewed key space (the lin-kv-5n-zipf shape at a small
 size): the history replays through the plain reference and passes the
-composite verifier, and ``stats()["coordination"]`` accounts for every txn
-the nodes coordinated."""
+composite verifier, ``stats()["coordination"]`` accounts for every txn the
+nodes coordinated, and the stores' drain ticks, whose live sets are a few
+slots, were swept on the host by the router's choice, but for the audits."""
 
 import asyncio
 import gc
 import itertools
 import random
 import time
+
+import pytest
 
 from accord_tpu.maelstrom.node import token_of
 from accord_tpu.net.harness import free_ports
@@ -86,6 +89,8 @@ async def _serve_and_drive(journal_root):
             finals.update(reads)
         failures = sum(len(s.proc.failures) for s in servers)
         retries = client.n_retries
+        devs = [st.device for s in servers
+                for st in s.proc.node.command_stores.stores]
     finally:
         await client.close()
         for s in servers:
@@ -94,17 +99,28 @@ async def _serve_and_drive(journal_root):
         for s in servers:
             if s.frame_server is not None:
                 await asyncio.wait_for(s.close(), 30.0)
-    return verifier, answered, finals, before, after, failures, retries
+    return (verifier, answered, finals, before, after, failures, retries,
+            devs)
 
 
-def test_five_served_nodes_multikey_zipf_replay_and_coordination(tmp_path):
+@pytest.fixture(scope="module")
+def served_run(tmp_path_factory):
+    """One run of the cluster for every test of this file."""
+    from accord_tpu.local.device_index import DeviceState
     threshold = gc.get_threshold()
+    calib = DeviceState._CALIB
+    DeviceState._CALIB = None    # the run prices with what it measures
     try:
-        verifier, answered, finals, before, after, failures, retries = \
-            asyncio.run(_serve_and_drive(tmp_path))
+        return asyncio.run(_serve_and_drive(tmp_path_factory.mktemp("wal")))
     finally:
+        DeviceState._CALIB = calib
         gc.unfreeze()            # NodeServer.start() retunes the collector
         gc.set_threshold(*threshold)
+
+
+def test_five_served_nodes_multikey_zipf_replay_and_coordination(served_run):
+    verifier, answered, finals, before, after, failures, retries = \
+        served_run[:7]
     assert failures == 0
     assert len(finals) == KEYS
     for token, final in finals.items():
@@ -122,3 +138,25 @@ def test_five_served_nodes_multikey_zipf_replay_and_coordination(tmp_path):
     assert CLIENTS * TXNS_PER_CLIENT <= decided \
         <= CLIENTS * TXNS_PER_CLIENT + retries
     assert sum(a["fast"] for a in after) > 0
+
+
+def test_served_drain_ticks_are_swept_on_the_host_by_price(served_run):
+    """Hot keys chain txns and every store ticks, but no tick's live set
+    pays for a device round trip: the router sweeps them on the host, none
+    as a fallback, in the run whose history the test above verifies.  The
+    device ticks there are, are the stores' audits of the device route, its
+    first tick and then one a store every TICK_AUDIT_MICROS at most, and none
+    is fused."""
+    devs = served_run[7]
+    kinds = {kind: sum(d.kernel_times.get(kind, (0, 0.0))[0] for d in devs)
+             for kind in ("drain_tick_host", "drain_tick_wait",
+                          "drain_tick_dispatch")}
+    audits = sum(d.n_audit_ticks for d in devs)
+    assert kinds["drain_tick_host"] > 0, kinds
+    assert kinds["drain_tick_wait"] == kinds["drain_tick_dispatch"] == audits
+    for d in devs:
+        uptime = d.store.node.now_micros()
+        assert 1 <= d.n_audit_ticks <= 1 + uptime // d.TICK_AUDIT_MICROS
+    assert sum(d.n_priced_host_ticks for d in devs) == kinds["drain_tick_host"]
+    assert sum(d.n_host_ticks + d.n_fused_ticks + d.n_device_faults
+               for d in devs) == 0
