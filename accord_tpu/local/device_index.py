@@ -4,7 +4,9 @@ This is the live protocol wiring of the two TPU kernels (SURVEY.md §7
 stages 3-4): every globally-visible transaction a store witnesses is
 registered in a struct-of-arrays DepsTable slot kept incrementally in sync
 with the host command state, PreAccept/Accept/BeginRecovery dependency scans
-run through ops.deps_kernel.calculate_deps, and the executeAt-gated
+run through ONE flush path (deps_query_batch_begin ->
+deps_query_batch_end_attributed, the attributed kernels of
+ops.deps_kernel), and the executeAt-gated
 execution drain is driven by ops.drain_kernel.ready_frontier over a live
 adjacency graph instead of per-dependency listener fan-out.
 
@@ -27,13 +29,15 @@ against its WaitingOn bitset before executing — any mirror divergence
 degrades to a no-op, never a wrong execution.
 
 Regime-adaptive dispatch: every batched deps scan is routed per flush to
-the cheapest of THREE routes, all of which feed the same snapshot, exact
-overlap triples, floors, elision and attribution code — the protocol never
-sees which route ran (results are bit-identical by construction).  Since
-r10 the device kernels answer EXACTLY (sorted composite overlap-triple
-codes; ops.deps_kernel module docstring) and the result download is
-two-stage and compacted: the scalar header first, then only the live
-entry prefix — the host-side collect is a pure vectorized decode:
+the cheapest of THREE routes, all of which hand the shared finalize the
+same ATTRIBUTED entry set over the same snapshot — the protocol never
+sees which route ran (results are bit-identical by construction).  The
+device kernels answer EXACTLY (sorted composite overlap-triple codes;
+ops.deps_kernel module docstring) with the batch-global RedundantBefore
+prune, the per-token floors, elision and the key dedupe applied
+in-kernel, and the result download is two-stage and compacted: the
+scalar header first, then only the live entry prefix — the host-side
+collect is a pure vectorized decode:
 
  - **host**: a vectorized numpy interval scan over only the LIVE TAIL
    (slots above the batch-global RedundantBefore floor): token-sorted point
@@ -42,12 +46,13 @@ entry prefix — the host-side collect is a pure vectorized decode:
    round trip (the hot-key / durable-prefix-dominated regime, where 90%+ of
    the table sits below the floor and RTTs dominate a ~10k-entry scan).
  - **bucketed** (device): the CINTIA-analogue bucket index
-   (ops.deps_kernel.bucketed_flat) — O(candidates) per query.  Under a
-   mesh the bucket rows are row-sharded (parallel.sharded.
-   sharded_bucketed_flat) with device-side floor pruning.
- - **dense** (device): the exact O(N) kernel — the fallback when footprint
-   distributions defeat bucketing (straggler spill, wide queries).  Under a
-   mesh it row-shards the slot table (sharded_calculate_deps_flat[_pruned]).
+   (ops.deps_kernel.bucketed_attr_jit) — O(candidates) per query.  Under
+   a mesh the bucket rows are row-sharded (parallel.sharded.
+   sharded_bucketed_attr) and the shard blocks merge on device.
+ - **dense** (device): the exact O(N) kernel (calculate_deps_flat_attr) —
+   the fallback when footprint distributions defeat bucketing (straggler
+   spill, wide queries).  Under a mesh it row-shards the slot table
+   (sharded_flat_attr).
 
 Device-fault tolerance (the degradation ladder): the accelerator is a
 FAILURE DOMAIN, not a trusted coprocessor.  Every device-boundary operation
@@ -185,52 +190,32 @@ def _prefix_len(maxtot: int, s: int) -> int:
     return min(s, -(-maxtot // gran) * gran)
 
 
-def _fetch_entry_prefix(ent_dev, d: int, s: int, maxtot: int) -> np.ndarray:
+def _fetch_entry_prefix(ent_dev, s: int, maxtot: int) -> np.ndarray:
     """Stage-2 of the compacted download: transfer ONLY the live prefix of
-    each shard's entry block (the pow2-padded tail never crosses the wire).
-    Returns host [d, L]."""
+    the entry block (the pow2-padded tail never crosses the wire).
+    Returns host [1, L]."""
     length = _prefix_len(maxtot, s)
     if length == 0:
-        return np.zeros((d, 0), np.dtype(ent_dev.dtype))
-    if d == 1:
-        return np.asarray(ent_dev[:length]).reshape(1, length)
-    return np.asarray(ent_dev.reshape(d, s)[:, :length])
+        return np.zeros((1, 0), np.dtype(ent_dev.dtype))
+    return np.asarray(ent_dev[:length]).reshape(1, length)
+
+
+# scalar prefix of the attributed header (ops.deps_kernel: total, the two
+# overflow watermarks, the two elision tallies), before row_end[B]
+_HOFF = 5
 
 
 def _decode_triples(hdr: np.ndarray, ent: np.ndarray, nq: int,
-                    shard_n: int, global_ids: bool, mq: int, q_m: int,
-                    hoff: int = 2):
-    """Vectorized parse of a (possibly multi-shard) exact CSR download:
-    one concatenate/gather over the stacked shard headers replaces the
-    per-shard Python parse loop.  Returns per-TRIPLE arrays
-    (b, slot, dep_col, q_col); slot indices are shard-local for the
-    slot-sharded kernels (offset by the shard's slice here) and GLOBAL
-    for the bucket-indexed kernels (codes embed global slot ids).
-    ``hoff`` is the header's scalar prefix length (2 raw, 5 attributed)."""
-    d = hdr.shape[0]
-    counts = np.diff(hdr[:, hoff:].astype(np.int64), prepend=0, axis=1)
-    totals = hdr[:, 0].astype(np.int64)
-    b = np.repeat(np.tile(np.arange(nq, dtype=np.int64), d),
-                  counts.reshape(-1))
-    live = np.arange(ent.shape[1])[None, :] < totals[:, None]
-    j, m_i, q_i = dk.decode_triples(ent[live], mq // q_m, q_m)
-    if not global_ids and d > 1:
-        j = j + np.repeat(np.arange(d, dtype=np.int64) * shard_n, totals)
+                    mq: int, q_m: int):
+    """Vectorized parse of one attributed CSR download (header [1, 5+nq],
+    entries [1, L]; a mesh route's shard blocks arrive merged on device).
+    Returns per-TRIPLE arrays (b, slot, dep_col, q_col); slot ids are
+    GLOBAL on every route."""
+    counts = np.diff(hdr[0, _HOFF:].astype(np.int64), prepend=0)
+    b = np.repeat(np.arange(nq, dtype=np.int64), counts)
+    j, m_i, q_i = dk.decode_triples(ent[0, :int(hdr[0, 0])], mq // q_m,
+                                    q_m)
     return b, j, m_i, q_i
-
-
-def _tri_pairs(tb: np.ndarray, tj: np.ndarray):
-    """Derive the exact (query, slot) pair list from triple arrays whose
-    (b, j) runs are contiguous (true per shard block by the kernels' code
-    sort, and preserved by concatenation because shard/part pair sets are
-    disjoint).  Returns (b_idx, j_idx, p_i) with p_i mapping each triple
-    to its pair row — the shape attribution consumes."""
-    n = len(tb)
-    first = np.ones(n, bool)
-    if n:
-        first[1:] = (tb[1:] != tb[:-1]) | (tj[1:] != tj[:-1])
-    p_i = np.cumsum(first) - 1
-    return tb[first], tj[first], p_i
 
 
 # one bucket-index entry as the host keeps it: the BucketTable's eight
@@ -595,7 +580,7 @@ class _DepsMirror:
     def bucket_device_sharded(self, mesh) -> "dk.BucketTable":
         """Mesh placement of the bucket index: bucket ROWS and the wide list
         row-sharded across the mesh (the per-shard slices feed
-        parallel.sharded.sharded_bucketed_flat).  Any mutation triggers a
+        parallel.sharded.sharded_bucketed_attr).  Any mutation triggers a
         full sharded re-upload, keyed on the bucket/wide version counters —
         same policy as device_table_sharded."""
         d = int(np.prod(list(mesh.shape.values())))
@@ -908,15 +893,15 @@ class _DepsMirror:
         return self._hidx
 
     def host_pairs(self, qnp: np.ndarray, q_m: int, floor_id,
-                   snapshot=None, entries: bool = False):
-        """The host route's candidate generation: (b_idx, j_idx) pairs
+                   snapshot=None):
+        """The host route's candidate generation: per-ENTRY arrays
+        (query row, slot, entry interval column, query interval column)
         satisfying the EXACT kernel predicate (liveness + floor structurally
         via the index; witness / earlier / not-self as vectorized compares
-        identical to the device ts_lt), deduped per (query, slot), plus the
-        exact emit triples (pair row, entry interval column, query interval
-        column) the probes discovered — the same set np.nonzero over the
-        device routes' overlap matrix yields, so attribution sees identical
-        inputs and results are bit-identical by construction.
+        identical to the device ts_lt) — the same overlap triples the
+        device routes' raw compaction yields, so the attribution filter
+        sees identical inputs and results are bit-identical by
+        construction.
 
         ``snapshot`` = (msb, lsb, node, kind, status, lo, hi) computes the
         scan against a begin-time copy of the mirror instead of the live
@@ -930,12 +915,10 @@ class _DepsMirror:
                 and floor_id > TxnId.NONE else None
             idx = _host_index_of(s_status, s_lo, s_hi, s_msb, s_lsb,
                                  s_node, fkey)
-            cap = len(s_msb)
         else:
             s_msb, s_lsb, s_node, s_kind = (self.msb, self.lsb, self.node,
                                             self.kind)
             idx = self.host_index(floor_id)
-            cap = self.capacity
         ptok, pslot, pcol, rlo, rhi, rslot, rcol = idx
         lo = qnp[:, 7:7 + q_m]
         hi = qnp[:, 7 + q_m:7 + 2 * q_m]
@@ -982,11 +965,8 @@ class _DepsMirror:
             parts_j.append(rslot[jj])
             parts_m.append(rcol[jj])
             parts_q.append(mi[ii])
-        empty = (np.zeros(0, np.int64), np.zeros(0, np.int64))
         if not parts_b:
-            if entries:
-                return (np.zeros(0, np.int64),) * 4
-            return empty + ((np.zeros(0, np.int64),) * 3,)
+            return (np.zeros(0, np.int64),) * 4
         cb = np.concatenate(parts_b).astype(np.int64)
         cj = np.concatenate(parts_j).astype(np.int64)
         cm = np.concatenate(parts_m).astype(np.int64)
@@ -1002,14 +982,7 @@ class _DepsMirror:
                   & (en == qnp[cb, 6]))
         if not keep.all():
             cb, cj, cm, cq = cb[keep], cj[keep], cm[keep], cq[keep]
-        if entries:
-            # the attributed paths consume per-ENTRY arrays directly —
-            # skip the (query, slot) pair compression (one 1-D sort of
-            # the whole emit set) the legacy pair API pays
-            return cb, cj, cm, cq
-        pair, p_i = np.unique(cb * np.int64(cap) + cj,
-                              return_inverse=True)
-        return pair // cap, pair % cap, (p_i, cm, cq)
+        return cb, cj, cm, cq
 
     def snapshot_cols(self):
         """(ids 9-tuple, ivs 3-tuple, kind) copies of every column the
@@ -1800,8 +1773,6 @@ class DeviceState:
         # repeated flushes over a stable watermark map resolve the prune
         # floor with one dict hit instead of a segment walk
         self._floor_memo: Optional[tuple] = None
-        # token -> (cfk version, may_elide_any) memo for attribution
-        self._elidable_cache: Dict[int, tuple] = {}
         # -- device-resident attribution (r15) --
         # elision registry: tokens that ever carried a decided key-domain
         # write (maintained by _advance_status); the batched elision index
@@ -2188,9 +2159,7 @@ class DeviceState:
                                  witnesses)
         if query is None:
             return
-        handle = self.deps_query_batch_begin([query], immediate=True,
-                                             prune_floors=True,
-                                             attributed=True)
+        handle = self.deps_query_batch_begin([query], immediate=True)
         self.deps_query_batch_end_attributed(safe, handle, [builder])
 
     def build_query(self, safe, txn_id: TxnId, keys,
@@ -2208,190 +2177,6 @@ class DeviceState:
         if not q_toks and not q_rngs:
             return None
         return (txn_id, started_before, witnesses, q_toks, q_rngs)
-
-    def _attribute_batch(self, safe, b_idx, j_idx, pmq, ids, ivs, qnp,
-                         queries, builders) -> None:
-        """Fold a whole batch's kernel answer into the builders with the
-        floors, elision and key/range attribution of the host path: the
-        kernel answers "who", the mirror snapshot answers "where",
-        RedundantBefore floors and the CFK elision rule decide "whether".
-
-        The geometry runs ONCE, vectorized over all (pair, dep-interval,
-        query-interval) triples — no per-query Python overhead.  The
-        unification that makes this possible: a key-domain dep's footprint
-        is a point, so its emitted key is its own token whether the query
-        interval was a key or a range; a range-domain dep emits the
-        dep∩query interval clip, which for a point query degenerates to the
-        width-1 range.  Python touches only the deduplicated surviving
-        emits."""
-        if len(j_idx) == 0:
-            return
-        lo, hi, dom = ivs
-        rb = safe.redundant_before()
-        _MISSING = object()
-        cfks: Dict[int, object] = {}
-
-        def elide_ctx(t: int, bound):
-            """(cfk, pivot) when elision is possible on this key for this
-            bound, else None — ONE lookup per (token, bound) instead of one
-            per (dep, token) pair (the common key has nothing elidable)."""
-            key = (t, bound)
-            ctx = cfks.get(key, _MISSING)
-            if ctx is not _MISSING:
-                return ctx
-            cfk = self.store.commands_for_key.get(t)
-            ctx = None
-            if cfk is not None:
-                pivot = cfk.can_elide(bound)
-                if pivot is not None:
-                    ctx = (cfk, pivot)
-            cfks[key] = ctx
-            return ctx
-
-        q_m = (qnp.shape[1] - 7) // 2
-        # the exact (pair row, dep-interval col, query-interval col) emit
-        # triples arrive precomputed from the collect pass (host probes or
-        # np.nonzero over the kernel parts' overlap geometry)
-        p_i, m_i, q_i = pmq
-        key_dep = (dom[j_idx] == int(Domain.Key))[p_i]
-
-        # key-domain deps: emitted at the dep's own footprint point,
-        # deduped per (pair, token); floors + elision decide survival.
-        # Emits reach the builders through the batch finalize (whole-batch
-        # vectorized dedupe/CSR, set_prebuilt per builder) — per-emit
-        # Python runs only for the rare keys with elidable state
-        kp, km = p_i[key_dep], m_i[key_dep]
-        (msb_a, lsb_a, node_a, obj_a, status_a, xm_a, xl_a, xn_a,
-         xk_a) = ids
-        if len(kp):
-            jj, bb = j_idx[kp], b_idx[kp]
-            tt = lo[jj, km]                   # key-domain footprint = point
-            # vectorized RedundantBefore floor: dep >= floor(token),
-            # lexicographic over the packed (msb, lsb, node) triples (the
-            # same int64 ordering the kernel's ts_lt assumes)
-            fmsb, flsb, fnode = rb.deps_floor_batch(tt)
-            dmsb, dlsb, dnode = msb_a[jj], lsb_a[jj], node_a[jj]
-            keep = ((dmsb > fmsb)
-                    | ((dmsb == fmsb)
-                       & ((dlsb > flsb)
-                          | ((dlsb == flsb) & (dnode >= fnode)))))
-            jj_k, bb_k, tt_k = jj[keep], bb[keep], tt[keep]
-            # object resolution: pure take from the snapshot object column
-            deps_k = obj_a[jj_k]
-            # VECTORIZED transitive elision (the per-key skip rule,
-            # CommandsForKey.is_elided): transitively-known deps never
-            # emit; decided deps executing below the key's latest
-            # committed-write pivot (for this query's bound) are reached
-            # through that write's stable deps.  The pivot is looked up
-            # once per unique (token, query) on keys with anything
-            # elidable; the per-emit judgement is pure array compares over
-            # the mirror's status/executeAt snapshot — no per-emit Python
-            uniq_t2, inv_t2 = np.unique(tt_k, return_inverse=True)
-            tok_maybe = np.zeros(len(uniq_t2), bool)
-            cfk_map = self.store.commands_for_key
-            ecache = self._elidable_cache
-            for i, t in enumerate(uniq_t2.tolist()):
-                cfk = cfk_map.get(t)
-                if cfk is None:
-                    continue
-                # version-keyed memo: may_elide_any flips only when a
-                # committed write or unwitnessable lands on the key, both
-                # monotone counters — the common spread key resolves to a
-                # single dict hit instead of the CFK probe
-                ver = (len(cfk._committed_write_execs),
-                       cfk._n_unwitnessable)
-                hit = ecache.get(t)
-                if hit is not None and hit[0] == ver:
-                    tok_maybe[i] = hit[1]
-                else:
-                    m = cfk.may_elide_any()
-                    ecache[t] = (ver, m)
-                    tok_maybe[i] = m
-            status_k = status_a[jj_k]
-            elide = status_k == dk.SLOT_TRANSITIVE
-            flagged = tok_maybe[inv_t2]
-            if flagged.any():
-                f_idx = np.nonzero(flagged)[0]
-                # (builder, token) pairs as ONE int64 composite key over
-                # the token RANKS (np.unique(axis=0) on the raw 2-column
-                # stack cost ~250ms/1k queries in the hot regime — the
-                # void-dtype argsort dominated attribution)
-                ntok2 = len(uniq_t2)
-                key_bt = bb_k[f_idx] * np.int64(ntok2) + inv_t2[f_idx]
-                ubt_key, inv_bt = np.unique(key_bt, return_inverse=True)
-                pv = np.zeros((len(ubt_key), 3), np.int64)
-                pv_ok = np.zeros(len(ubt_key), bool)
-                ub_list = (ubt_key // ntok2).tolist()
-                ut_list = uniq_t2[ubt_key % ntok2].tolist()
-                for i, (b, t) in enumerate(zip(ub_list, ut_list)):
-                    ctx = elide_ctx(int(t), queries[b][1])
-                    if ctx is not None and ctx[1] is not Timestamp.NONE \
-                            and ctx[1] is not None:
-                        pv[i] = (to_i64(ctx[1].msb), to_i64(ctx[1].lsb),
-                                 ctx[1].node)
-                        pv_ok[i] = True
-                pm, pl, pn = (pv[inv_bt, 0], pv[inv_bt, 1], pv[inv_bt, 2])
-                jf = jj_k[f_idx]
-                sf = status_k[f_idx]
-                xm, xl, xn = xm_a[jf], xl_a[jf], xn_a[jf]
-                below = ((xm < pm) | ((xm == pm)
-                                      & ((xl < pl)
-                                         | ((xl == pl) & (xn < pn)))))
-                decided = ((sf >= dk.SLOT_COMMITTED)
-                           & (sf <= dk.SLOT_APPLIED) & xk_a[jf])
-                elide[f_idx] |= pv_ok[inv_bt] & decided & below
-            keep2 = ~elide
-            if keep2.any():
-                jj_f = jj_k[keep2]
-                # dense dep ranks over the batch's unique slots, ordered by
-                # the packed id (same signed lexicographic order the old
-                # 5-column lexsort used) — the finalize sorts become single
-                # int64 argsorts
-                u_slots, slot_inv = np.unique(jj_f, return_inverse=True)
-                ordr = np.lexsort((node_a[u_slots], lsb_a[u_slots],
-                                   msb_a[u_slots]))
-                rank = np.empty(len(u_slots), np.int64)
-                rank[ordr] = np.arange(len(u_slots))
-                _finalize_key_batch(builders, bb_k[keep2], tt_k[keep2],
-                                    inv_t2[keep2], len(uniq_t2),
-                                    rank[slot_inv], len(u_slots),
-                                    deps_k[keep2])
-
-        # range-domain deps: emit the dep∩query interval clip per pair —
-        # batch-finalized (dedupe/sort/CSR in one vectorized pass; Range
-        # objects materialize once per unique clip)
-        rp, rm, rq = p_i[~key_dep], m_i[~key_dep], q_i[~key_dep]
-        if len(rp):
-            jj_r = j_idx[rp]
-            bb_r = b_idx[rp]
-            ilo = np.maximum(lo[jj_r, rm], qnp[bb_r, 7 + rq])
-            ihi = np.minimum(hi[jj_r, rm], qnp[bb_r, 7 + q_m + rq]) + 1
-            dmsb_r, dlsb_r, dnode_r = msb_a[jj_r], lsb_a[jj_r], node_a[jj_r]
-            # batch-global RedundantBefore floor on range-domain deps (the
-            # host analogue of the device prune, applied on EVERY attributed
-            # path so pruned and unpruned kernels agree; the pruned history
-            # is covered by the boundary fence dep, messages/preaccept.py:
-            # add_boundary_deps)
-            m_all = qnp[:, 7:7 + q_m]
-            h_all = qnp[:, 7 + q_m:7 + 2 * q_m]
-            u_all = m_all <= h_all
-            if u_all.any():
-                fl = rb.min_floor_over(int(m_all[u_all].min()),
-                                       int(h_all[u_all].max()))
-                if fl > TxnId.NONE:
-                    fm, fls, fn = (to_i64(fl.msb), to_i64(fl.lsb), fl.node)
-                    keep_r = ((dmsb_r > fm)
-                              | ((dmsb_r == fm)
-                                 & ((dlsb_r > fls)
-                                    | ((dlsb_r == fls) & (dnode_r >= fn)))))
-                    rp, ilo, ihi, jj_r = (rp[keep_r], ilo[keep_r],
-                                          ihi[keep_r], jj_r[keep_r])
-                    dmsb_r, dlsb_r, dnode_r = (dmsb_r[keep_r],
-                                               dlsb_r[keep_r],
-                                               dnode_r[keep_r])
-            if len(rp):
-                _finalize_range_batch(builders, b_idx[rp], ilo, ihi,
-                                      dmsb_r, dlsb_r, dnode_r, obj_a[jj_r])
 
     # ------------------------------------------------------------------
     # store-level coalescing (the lived batched path): queries arriving
@@ -2435,8 +2220,7 @@ class DeviceState:
             return
         try:
             handle = self.deps_query_batch_begin(
-                [q for q, _b, _d in batch], immediate=True,
-                prune_floors=True, attributed=True)
+                [q for q, _b, _d in batch], immediate=True)
             self.deps_query_batch_end_attributed(
                 safe, handle, [b for _q, b, _d in batch])
         except BaseException as e:  # noqa: BLE001
@@ -2446,20 +2230,6 @@ class DeviceState:
         for _q, _b, d in batch:
             d(None, safe)
 
-    def deps_query_batch(self, queries):
-        """Batched deps scan: ONE kernel call for B concurrent queries (the
-        server-side batching a pipelined deployment uses).
-
-        ``queries`` = [(txn_id, started_before, witnesses, tokens, ranges)].
-        Returns the dep sets in the device-native packed-CSR layout —
-        ``(row_ptr int64[B+1], msb int64[D], lsb int64[D], node int32[D])``
-        — the same encoding KeyDeps/RangeDeps use (ref: KeyDeps.java:150-156
-        CSR layout); consumers materialise TxnId objects lazily."""
-        if not queries:
-            return (np.zeros(1, np.int64), np.zeros(0, np.int64),
-                    np.zeros(0, np.int64), np.zeros(0, np.int32))
-        return self.deps_query_batch_end(self.deps_query_batch_begin(queries))
-
     def deps_query_batch_attributed(self, safe, queries, builders):
         """The correctness-complete batched scan: one kernel dispatch for B
         queries, then the full host-path semantics (floors, elision,
@@ -2467,20 +2237,13 @@ class DeviceState:
         the exact code deps_query runs (B=1) — and what the bench times."""
         if not queries:
             return
-        handle = self.deps_query_batch_begin(queries, prune_floors=True,
-                                             attributed=True)
+        handle = self.deps_query_batch_begin(queries)
         self.deps_query_batch_end_attributed(safe, handle, builders)
 
     # below this many stragglers the bucketed path is used for narrow
     # queries on a single device; above it (hot/adversarial footprints) the
     # dense scan is the better kernel anyway
     BUCKETED = True
-
-    # test knob: force the global triple-dedupe pass even for single-part
-    # exact kernels (whose CSRs are unique by construction, so the pass is
-    # skipped in production) — test_routing asserts results are
-    # byte-identical either way
-    FORCE_TRIPLE_DEDUPE = False
 
     # process-wide route calibration: {"rtt": s, "c_dev": s/elem,
     # "c_host": s/elem, "c_sweep": s per row or edge of the host drain
@@ -2914,19 +2677,22 @@ class DeviceState:
         return tb[keep], tj[keep], tm[keep], tq[keep], n_trans, n_dec
 
     def deps_query_batch_begin(self, queries, immediate: bool = False,
-                               prune_floors: bool = False,
-                               attributed: bool = False):
+                               prune_floors: bool = True,
+                               attributed: bool = True):
         """Dispatch a batched deps scan WITHOUT waiting: one fused query
         upload per kernel part + enqueue; returns an opaque handle for
-        deps_query_batch_end.
+        deps_query_batch_end_attributed.
 
-        ``attributed=True`` (every protocol path) dispatches the r15
-        ATTRIBUTED kernels: per-token RedundantBefore floors, elision and
-        the key dedupe run in-kernel against the device-resident
-        attribution columns + the packed floor/elision index, and the CSR
-        that comes back holds exactly the entries the builders keep — the
-        host side is a pure decode + finalize.  Mesh routes additionally
-        merge their shard blocks ON DEVICE (one replicated download).  Callers overlap the next batch's dispatch
+        There is ONE flush: every device kind launches its ATTRIBUTED
+        kernel — the batch-global RedundantBefore prune, the per-token
+        floors, elision and the key dedupe run in-kernel against the
+        device-resident attribution columns + the packed floor/elision
+        index, and the CSR that comes back holds exactly the entries the
+        builders keep — the host side is a pure decode + finalize.  Mesh
+        routes additionally merge their shard blocks ON DEVICE (one
+        replicated download).  ``prune_floors`` / ``attributed`` are
+        accepted only as True (ROADMAP D11: the benchmark's store driver
+        still passes them).  Callers overlap the next batch's dispatch
         with the previous batch's result download (double-buffering) — on a
         high-round-trip host-device link the round trips dominate the
         kernel, so the pipeline nearly doubles sustained throughput.
@@ -2936,8 +2702,11 @@ class DeviceState:
         probe the bucketed index (O(candidates) instead of O(N)), wide
         queries — and everything, when the straggler list says the
         footprint distribution defeats bucketing — take the dense kernel.
-        All parts share one mirror snapshot and one geometry/attribution
-        pass, so every path yields identical protocol results."""
+        All parts share one mirror snapshot and one finalize, so every
+        path yields identical protocol results."""
+        if not (prune_floors and attributed):
+            raise TypeError("deps_query_batch_begin has one flush path: "
+                            "prune_floors and attributed are always on")
         q_m = _pow2_at_least(max(len(t[3]) + len(t[4]) for t in queries))
         packed = [(sb, wit, toks, rngs, tid)
                   for (tid, sb, wit, toks, rngs) in queries]
@@ -2948,56 +2717,41 @@ class DeviceState:
         # DEVICE (the exact floors still run in attribution): in durable-
         # prefix-dominated stores this keeps the CSR to the live tail
         # instead of shipping redundant history — on EVERY device route,
-        # sharded included (the r05 mesh path hard-disabled this).  Opt-in:
-        # the attributed (protocol) paths enable it; the raw-CSR path
-        # documents no floors and never prunes
+        # sharded included
         prune = None
-        floor_id = None
-        if prune_floors:
-            floor_id, prune_np = self._batch_floor(qnp, q_m)
-            if floor_id is not None:
-                prune = (jnp.asarray(prune_np[0]), jnp.asarray(prune_np[1]),
-                         jnp.asarray(prune_np[2]))
-        aidx = rankb_np = None
-        floor_skip = False
-        if attributed:
-            aidx = self._attr_index()
-            rankb_np = aidx.rank_bounds(qnp)
-            # when the exact per-token floors equal the structurally
-            # applied batch floor everywhere the batch reaches, the
-            # per-entry floor leg is provably a no-op — on the host route
-            # AND in the kernels (the mask's batch-global prune is that
-            # same floor); an empty elision index likewise drops the
-            # whole pivot leg from the traced program (static flags)
-            floor_skip = aidx.floors_match(qnp, q_m, floor_id)
-            k_floors = not floor_skip
-            k_elide = aidx.u > 0
+        floor_id, prune_np = self._batch_floor(qnp, q_m)
+        if floor_id is not None:
+            prune = (jnp.asarray(prune_np[0]), jnp.asarray(prune_np[1]),
+                     jnp.asarray(prune_np[2]))
+        aidx = self._attr_index()
+        rankb_np = aidx.rank_bounds(qnp)
+        # when the exact per-token floors equal the structurally applied
+        # batch floor everywhere the batch reaches, the per-entry floor leg
+        # is provably a no-op — on the host route AND in the kernels (the
+        # mask's batch-global prune is that same floor); an empty elision
+        # index likewise drops the whole pivot leg from the traced program
+        # (static flags)
+        floor_skip = aidx.floors_match(qnp, q_m, floor_id)
+        k_floors = not floor_skip
+        k_elide = aidx.u > 0
 
         def dispatch(kind, rows, qcols=None):
             """rows: np int64 array of query indices for this part, padded
             to a pow2 batch by repeating the last row (pads map to -1).
-            Under ``attributed`` every device kind launches its r15
-            ATTRIBUTED kernel variant (suffix ``attr_`` in the devprof
-            slices); mesh kinds come back as ONE merged replicated block
-            (d=1, entry buffer d_mesh * s)."""
+            Every device kind launches its ATTRIBUTED kernel (``attr_`` +
+            kind in the devprof slices and kernel_times); mesh kinds come
+            back as ONE merged replicated block (d=1, entry buffer
+            d_mesh * s)."""
             import time as _time
             _t0 = _time.perf_counter()
-            kname = ("attr_" + kind) if attributed else kind
             if kind == "host":
-                # the host route computes its (query, slot) pairs AND the
-                # exact emit triples right here — no device box, no
-                # download thread; under ``attributed`` the floor/elision
-                # drops run at collect over the same snapshot the
-                # builders read
-                if attributed:
-                    ent4 = self.deps.host_pairs(qnp, q_m, floor_id,
-                                                entries=True)
-                    parts.append({"kind": "host", "ent": ent4})
-                else:
-                    b_h, j_h, pmq = self.deps.host_pairs(qnp, q_m,
-                                                         floor_id)
-                    parts.append({"kind": "host", "b": b_h, "j": j_h,
-                                  "pmq": pmq})
+                # the host route computes its exact emit entries right
+                # here — no device box, no download thread; the
+                # floor/elision drops run at collect over the same
+                # snapshot the builders read
+                parts.append({"kind": "host",
+                              "ent": self.deps.host_pairs(qnp, q_m,
+                                                          floor_id)})
                 self.n_host_queries += len(rows)
                 self.n_dispatches += 1
                 self._ktime("dispatch_host", _t0)
@@ -3009,8 +2763,7 @@ class DeviceState:
                 # host mirror — disjoint from the device part's slot set
                 # by construction, so the concatenated entries finalize
                 # byte-identically to an all-device answer
-                cb, cj, cm, cq = self.deps.host_pairs(qnp, q_m, floor_id,
-                                                      entries=True)
+                cb, cj, cm, cq = self.deps.host_pairs(qnp, q_m, floor_id)
                 keep = self.store_shards.quarantined_slot_mask(cj)
                 parts.append({"kind": "host_slice",
                               "ent": (cb[keep], cj[keep], cm[keep],
@@ -3019,6 +2772,7 @@ class DeviceState:
                 self._ktime("dispatch_host_slice", _t0)
                 return
             dk.launch_check(kind)
+            kname = "attr_" + kind
             b_pad = _pow2_at_least(len(rows), 1)
             rows_p = np.concatenate(
                 [rows, np.full(b_pad - len(rows), rows[-1], np.int64)])
@@ -3027,10 +2781,10 @@ class DeviceState:
             m_t = self.deps.max_intervals
             part: Dict[str, object] = {"kind": kname, "gmap": gmap,
                                        "nq": b_pad, "q_m": q_m,
-                                       "mq": m_t * q_m, "hoff": 2,
-                                       "d_ent": 1,
+                                       "mq": m_t * q_m, "d_ent": 1,
                                        "immediate": immediate}
-            rankb = jnp.asarray(rankb_np[rows_p]) if attributed else None
+            rankb = jnp.asarray(rankb_np[rows_p])
+            pz = prune if prune is not None else _prune_zeros()
             if kind == "sharded":
                 table = self.deps.device_table_sharded(self.mesh)
                 d = int(np.prod(list(self.mesh.shape.values())))
@@ -3039,42 +2793,22 @@ class DeviceState:
                 k = min(self._batch_k, (n // d) * m_t * q_m)
                 qmat = jnp.asarray(qnp[rows_p])
                 mesh = self.mesh
-                if attributed:
-                    # merged replicated block with GLOBAL slot codes: the
-                    # cross-shard Deps.merge happens on device
-                    wide = dk.wide_codes(n, m_t, q_m)
-                    from ..parallel.sharded import sharded_flat_attr
-                    acols = self.deps.device_attr_cols_sharded(mesh)
-                    ai = aidx.device_replicated(mesh)
-                    pz = prune if prune is not None else _prune_zeros()
+                # merged replicated block with GLOBAL slot codes: the
+                # cross-shard Deps.merge happens on device
+                wide = dk.wide_codes(n, m_t, q_m)
+                from ..parallel.sharded import sharded_flat_attr
+                acols = self.deps.device_attr_cols_sharded(mesh)
+                ai = aidx.device_replicated(mesh)
 
-                    def relaunch(s2, k2, _m=mesh, _t=table, _q=qmat,
-                                 _a=acols, _i=ai, _r=rankb, _p=pz):
-                        return sharded_flat_attr(
-                            _m, q_m, s2, k2, wide, k_floors,
-                            k_elide)(_t, _a, _i, _q, _r, *_p)
+                def relaunch(s2, k2, _m=mesh, _t=table, _q=qmat,
+                             _a=acols, _i=ai, _r=rankb, _p=pz):
+                    return sharded_flat_attr(
+                        _m, q_m, s2, k2, wide, k_floors,
+                        k_elide)(_t, _a, _i, _q, _r, *_p)
 
-                    part.update(d=1, d_ent=d, shard_n=n, s=s, k=k,
-                                wide=wide, hoff=5, global_ids=True,
-                                s_cap=b_pad * (n // d) * m_t * q_m,
-                                k_cap=(n // d) * m_t * q_m)
-                else:
-                    wide = dk.wide_codes(n // d, m_t, q_m)
-                    from ..parallel.sharded import (
-                        sharded_calculate_deps_flat,
-                        sharded_calculate_deps_flat_pruned)
-
-                    def relaunch(s2, k2, _m=mesh, _t=table, _q=qmat,
-                                 _p=prune):
-                        if _p is not None:
-                            return sharded_calculate_deps_flat_pruned(
-                                _m, q_m, s2, k2, wide)(_t, _q, *_p)
-                        return sharded_calculate_deps_flat(
-                            _m, q_m, s2, k2, wide)(_t, _q)
-
-                    part.update(d=d, shard_n=n // d, s=s, k=k, wide=wide,
-                                s_cap=b_pad * (n // d) * m_t * q_m,
-                                k_cap=(n // d) * m_t * q_m)
+                part.update(d_ent=d, s=s, k=k, wide=wide,
+                            s_cap=b_pad * (n // d) * m_t * q_m,
+                            k_cap=(n // d) * m_t * q_m)
                 self.n_mesh_queries += len(rows)
             elif kind == "sharded_bucketed":
                 btable = self.deps.bucket_device_sharded(self.mesh)
@@ -3092,36 +2826,22 @@ class DeviceState:
                 qb = qcols[rows_p].reshape(b_pad, q_m * span)
                 qmat = jnp.asarray(np.concatenate(
                     [qnp[rows_p], qb], axis=1))
-                pz = prune if prune is not None else _prune_zeros()
                 mesh = self.mesh
-                if attributed:
-                    from ..parallel.sharded import sharded_bucketed_attr
-                    acols = self.deps.device_attr_cols_replicated(mesh)
-                    ai = aidx.device_replicated(mesh)
-                    tsh = self.deps.device_table_sharded(mesh)
+                from ..parallel.sharded import sharded_bucketed_attr
+                acols = self.deps.device_attr_cols_replicated(mesh)
+                ai = aidx.device_replicated(mesh)
+                tsh = self.deps.device_table_sharded(mesh)
 
-                    def relaunch(s2, k2, _m=mesh, _b=btable, _t=tsh,
-                                 _q=qmat, _a=acols, _i=ai, _r=rankb,
-                                 _p=pz):
-                        return sharded_bucketed_attr(
-                            _m, q_m, span, s2, k2, m_t, keff, wide,
-                            k_floors, k_elide)(_b, _t, _a, _i, _q, _r,
-                                               *_p)
+                def relaunch(s2, k2, _m=mesh, _b=btable, _t=tsh,
+                             _q=qmat, _a=acols, _i=ai, _r=rankb,
+                             _p=pz):
+                    return sharded_bucketed_attr(
+                        _m, q_m, span, s2, k2, m_t, keff, wide,
+                        k_floors, k_elide)(_b, _t, _a, _i, _q, _r,
+                                           *_p)
 
-                    part.update(d=1, d_ent=d, shard_n=c, s=s, k=k, c=c,
-                                wide=wide, hoff=5, global_ids=True,
-                                s_cap=b_pad * c, k_cap=c)
-                else:
-                    from ..parallel.sharded import sharded_bucketed_flat
-
-                    def relaunch(s2, k2, _m=mesh, _b=btable, _q=qmat,
-                                 _p=pz):
-                        return sharded_bucketed_flat(
-                            _m, q_m, span, s2, k2, m_t, keff,
-                            wide)(_b, _q, *_p)
-
-                    part.update(d=d, shard_n=c, s=s, k=k, c=c, wide=wide,
-                                global_ids=True, s_cap=b_pad * c, k_cap=c)
+                part.update(d_ent=d, s=s, k=k, wide=wide,
+                            s_cap=b_pad * c, k_cap=c)
                 self.n_mesh_queries += len(rows)
                 self.n_mesh_bucketed_queries += len(rows)
             elif kind == "dense":
@@ -3131,28 +2851,17 @@ class DeviceState:
                 s = min(self._batch_flat, b_pad * n * m_t * q_m)
                 k = min(self._batch_k, n * m_t * q_m)
                 qmat = jnp.asarray(qnp[rows_p])
-                if attributed:
-                    acols = self.deps.device_attr_cols()
-                    ai = aidx.device()
-                    pz = prune if prune is not None else _prune_zeros()
+                acols = self.deps.device_attr_cols()
+                ai = aidx.device()
 
-                    def relaunch(s2, k2, _t=table, _q=qmat, _a=acols,
-                                 _i=ai, _r=rankb, _p=pz):
-                        return dk.calculate_deps_flat_attr(
-                            _t, _a, _i, _q, _r, *_p, q_m, s2, k2, wide,
-                            k_floors, k_elide)
-
-                    part.update(hoff=5)
-                else:
-                    def relaunch(s2, k2, _t=table, _q=qmat, _p=prune):
-                        if _p is not None:
-                            return dk.calculate_deps_flat_pruned(
-                                _t, _q, *_p, q_m, s2, k2, wide)
-                        return dk.calculate_deps_flat(_t, _q, q_m, s2,
-                                                      k2, wide)
+                def relaunch(s2, k2, _t=table, _q=qmat, _a=acols,
+                             _i=ai, _r=rankb, _p=pz):
+                    return dk.calculate_deps_flat_attr(
+                        _t, _a, _i, _q, _r, *_p, q_m, s2, k2, wide,
+                        k_floors, k_elide)
 
                 self.n_dense_queries += len(rows)
-                part.update(d=1, shard_n=n, s=s, k=k, wide=wide,
+                part.update(s=s, k=k, wide=wide,
                             s_cap=b_pad * n * m_t * q_m,
                             k_cap=n * m_t * q_m)
             else:   # bucketed
@@ -3167,33 +2876,18 @@ class DeviceState:
                 qb = qcols[rows_p].reshape(b_pad, q_m * span)
                 qmat = jnp.asarray(np.concatenate(
                     [qnp[rows_p], qb], axis=1))
-                if attributed:
-                    acols = self.deps.device_attr_cols()
-                    ai = aidx.device()
-                    pz = prune if prune is not None else _prune_zeros()
+                acols = self.deps.device_attr_cols()
+                ai = aidx.device()
 
-                    def relaunch(s2, k2, _t=table, _b=btable, _q=qmat,
-                                 _a=acols, _i=ai, _r=rankb, _p=pz):
-                        return dk.bucketed_attr_jit(
-                            _t, _a, _i, _b, _q, _r, q_m, span, s2, k2,
-                            _p, keff=keff, wide=wide, floors=k_floors,
-                            elide=k_elide)
-
-                    part.update(hoff=5)
-                else:
-                    def relaunch(s2, k2, _t=table, _b=btable, _q=qmat,
-                                 _p=prune):
-                        if _p is not None:
-                            return dk.bucketed_flat_pruned(
-                                _t, _b, _q, q_m, span, s2, k2, *_p,
-                                keff=keff, wide=wide)
-                        return dk.bucketed_flat_jit(_t, _b, _q, q_m, span,
-                                                    s2, k2, keff=keff,
-                                                    wide=wide)
+                def relaunch(s2, k2, _t=table, _b=btable, _q=qmat,
+                             _a=acols, _i=ai, _r=rankb, _p=pz):
+                    return dk.bucketed_attr_jit(
+                        _t, _a, _i, _b, _q, _r, q_m, span, s2, k2,
+                        _p, keff=keff, wide=wide, floors=k_floors,
+                        elide=k_elide)
 
                 self.n_bucketed_queries += len(rows)
-                part.update(d=1, shard_n=table.capacity, s=s, k=k, c=c,
-                            wide=wide, global_ids=True, s_cap=b_pad * c,
+                part.update(s=s, k=k, wide=wide, s_cap=b_pad * c,
                             k_cap=c)
             hdr_dev, ent_dev = relaunch(s, k)
             part["relaunch"] = relaunch
@@ -3205,27 +2899,24 @@ class DeviceState:
                 # two-stage prefetch on a worker thread: the header join
                 # blocks on the kernel (GIL released), then ONLY the live
                 # entry prefix crosses the wire — a pipelined caller
-                # attributes batch i while batch i+1 computes AND
+                # finalizes batch i while batch i+1 computes AND
                 # downloads.  No faults.check here: injection draws stay
                 # on the deterministic store-task thread (_collect_part
                 # re-checks before consuming each stage)
-                d_, nq_, s_, k_ = part["d"], b_pad, s, k
-                hoff_, de_ = part["hoff"], part["d_ent"]
+                nq_, s_, k_, de_ = b_pad, s, k, part["d_ent"]
 
                 def _fetch():
                     import time as _time
                     try:
                         t0 = _time.perf_counter()
-                        hdr = np.asarray(hdr_dev).reshape(d_, hoff_ + nq_)
+                        hdr = np.asarray(hdr_dev).reshape(1, _HOFF + nq_)
                         box["hdr_np"] = hdr
                         box["t_hdr"] = (t0, _time.perf_counter())
-                        ovf_s = int(hdr[:, 1 if hoff_ == 5 else 0].max())
-                        ovf_k = int(hdr[:, 2 if hoff_ == 5 else 1].max())
-                        if ovf_s > s_ or ovf_k > k_:
+                        if int(hdr[0, 1]) > s_ or int(hdr[0, 2]) > k_:
                             return    # overflowed: collector re-runs
                         t1 = _time.perf_counter()
                         box["ent_np"] = _fetch_entry_prefix(
-                            ent_dev, d_, de_ * s_, int(hdr[:, 0].max()))
+                            ent_dev, de_ * s_, int(hdr[0, 0]))
                         box["t_ent"] = (t1, _time.perf_counter())
                     except BaseException as e:     # surfaced after join
                         box["err"] = e
@@ -3247,9 +2938,7 @@ class DeviceState:
         else:
             route = self.route_override
             if route is None:
-                route = self._choose_route(qnp, q_m,
-                                           floor_id if prune_floors
-                                           else None)
+                route = self._choose_route(qnp, q_m, floor_id)
             if route != "host" and may_probe:
                 probing = True
                 self.n_reprobes += 1
@@ -3260,20 +2949,10 @@ class DeviceState:
         if (sh is not None and sh.active and self.mesh is not None
                 and forced is None and route != "host"):
             sh.tick_flush()
-            if sh.any_quarantined():
-                if attributed:
-                    # hybrid: healthy slices answer on device, the sick
-                    # slices' slots from the host twin (a host_slice part)
-                    hybrid = True
-                else:
-                    # the raw-CSR path consumes whole per-part CSRs (no
-                    # per-entry merge point for a twin to join at): serve
-                    # the whole flush from host while any slice is sick
-                    route = "host"
-                    self.n_fallback_queries += nq
-                    probing = False
-            if route != "host":
-                self.n_store_sharded_flushes += 1
+            # hybrid: healthy slices answer on device, the sick slices'
+            # slots from the host twin (a host_slice part)
+            hybrid = sh.any_quarantined()
+            self.n_store_sharded_flushes += 1
         observed = forced or route
         if self.on_route is not None:
             self.on_route(observed, nq)
@@ -3335,25 +3014,21 @@ class DeviceState:
                    self.deps.elsb, self.deps.enode, self.deps.eknown)
             ivs = (self.deps.lo, self.deps.hi, self.deps.domain)
         elif len(parts) == 1 and parts[0]["kind"] == "host":
-            # host route: the pairs are already known, so snapshot ONLY the
-            # referenced slots (a gather of ~live-tail rows instead of a
-            # full-capacity copy) and remap the pair/slot indices onto the
-            # compact snapshot.  np.unique is sorted, so the remap is
-            # monotonic and the CSR's ascending-slot order — and therefore
-            # every downstream byte — is unchanged
+            # host route: the entries are already known, so snapshot ONLY
+            # the referenced slots (a gather of ~live-tail rows instead of
+            # a full-capacity copy) and remap the slot indices onto the
+            # compact snapshot.  The remap is monotonic, so the entries'
+            # ascending-slot order — and therefore every downstream byte —
+            # is unchanged
             part = parts[0]
             d = self.deps
-            if "ent" in part:
-                cb, cj, cm, cq = part["ent"]
-                flag = np.zeros(d.capacity, bool)
-                flag[cj] = True
-                u = np.nonzero(flag)[0]
-                remap = np.empty(d.capacity, np.int64)
-                remap[u] = np.arange(len(u), dtype=np.int64)
-                part["ent"] = (cb, remap[cj], cm, cq)
-            else:
-                u = np.unique(part["j"])
-                part["j"] = np.searchsorted(u, part["j"])
+            cb, cj, cm, cq = part["ent"]
+            flag = np.zeros(d.capacity, bool)
+            flag[cj] = True
+            u = np.nonzero(flag)[0]
+            remap = np.empty(d.capacity, np.int64)
+            remap[u] = np.arange(len(u), dtype=np.int64)
+            part["ent"] = (cb, remap[cj], cm, cq)
             ids = (d.msb[u], d.lsb[u], d.node[u], d.obj[u], d.status[u],
                    d.emsb[u], d.elsb[u], d.enode[u], d.eknown[u])
             ivs = (d.lo[u], d.hi[u], d.domain[u])
@@ -3365,8 +3040,7 @@ class DeviceState:
             # pipelined batches over an unmutated mirror share one
             ids, ivs, _kind = self.deps.snapshot_cols()
         fmeta = {"floor_id": floor_id, "probing": probing,
-                 "immediate": immediate, "attributed": attributed,
-                 "aidx": aidx, "rankb": rankb_np,
+                 "immediate": immediate, "aidx": aidx, "rankb": rankb_np,
                  "floor_skip": floor_skip}
         return (parts, ids, ivs, qnp, q_m, list(queries), fmeta)
 
@@ -3442,25 +3116,25 @@ class DeviceState:
         self._batch_k = max(self._batch_k, k)
         return s, k
 
-    def _prefix_pays(self, d: int, s: int, maxtot: int,
-                     itemsize: int) -> bool:
+    def _prefix_pays(self, s: int, maxtot: int, itemsize: int) -> bool:
         """Stage-2 transfer model for a SYNCHRONOUS fetch: slicing the
         live prefix costs one extra device dispatch (~an rtt) and saves
         the padded tail's bytes — a model over the calibrated per-byte
         transfer cost, not a threshold.  On a local CPU device bytes are
         ~free and the single full fetch wins; on a slow MB/s link the
         prefix wins from ~100KB of tail."""
-        saved = d * (s - _prefix_len(maxtot, s)) * itemsize
+        saved = (s - _prefix_len(maxtot, s)) * itemsize
         if saved <= 0:
             return False
         calib = self._calibration()
         return saved * calib.get("c_xfer", 0.0) > calib["rtt"]
 
     def _collect_part(self, part):
-        """Two-stage download + decode of one kernel part's exact CSR.
-        Stage 1 fetches the scalar header (totals / max row width /
-        row_end) — a few hundred int32s whose join also absorbs the kernel
-        wait; stage 2 transfers ONLY the live prefix of the entry buffer.
+        """Two-stage download + decode of one kernel part's attributed CSR.
+        Stage 1 fetches the scalar header (total / overflow watermarks /
+        elision tallies / row_end) — a few hundred int32s whose join also
+        absorbs the kernel wait; stage 2 transfers ONLY the live prefix of
+        the entry buffer.
         When the learned flat capacity or row width overflowed, the re-run
         is sized from the exact header already downloaded and rides the
         same compacted transfer — the full pow2-padded buffer is never
@@ -3469,10 +3143,8 @@ class DeviceState:
         import time as _time
         box = part["box"]
         th = part.get("th")
-        nq, d = part["nq"], part["d"]
+        nq, d_ent = part["nq"], part["d_ent"]
         s, k = part["s"], part["k"]
-        hoff, d_ent = part.get("hoff", 2), part.get("d_ent", 1)
-        attr = hoff == 5
         itemsize = 8 if part["wide"] else 4
         faults.check("transfer", "header download")
         _t0 = _time.perf_counter()
@@ -3484,23 +3156,21 @@ class DeviceState:
             hdr = box["hdr_np"]
             t_h = box.get("t_hdr")
         else:
-            hdr = np.asarray(box["hdr"]).reshape(d, hoff + nq)
+            hdr = np.asarray(box["hdr"]).reshape(1, _HOFF + nq)
             t_h = None
         self._ktime_span("wait_header_" + part["kind"],
                          *(t_h or (_t0, _time.perf_counter())))
         self.download_bytes += hdr.nbytes
-        self.download_bytes_padded += hdr.nbytes + d * d_ent * s * itemsize
+        self.download_bytes_padded += hdr.nbytes + d_ent * s * itemsize
         runs = 0
-        while int(hdr[:, 1 if attr else 0].max()) > s \
-                or int(hdr[:, 2 if attr else 1].max()) > k:
+        while int(hdr[0, 1]) > s or int(hdr[0, 2]) > k:
             # overflow: re-size from the exact header (shared policy,
             # _overflow_resize), then re-dispatch against the same
             # snapshot tables via the part's relaunch closure —
             # registrations interleaved between begin and end must not
             # shift the queried snapshot
             s, k = self._overflow_resize(
-                int(hdr[:, 1 if attr else 0].max()),
-                int(hdr[:, 2 if attr else 1].max()), s, k,
+                int(hdr[0, 1]), int(hdr[0, 2]), s, k,
                 part["s_cap"], part["k_cap"], runs)
             dk.launch_check(part["kind"])
             hdr_dev, ent_dev = part["relaunch"](s, k)
@@ -3508,11 +3178,11 @@ class DeviceState:
             th = None
             faults.check("transfer", "header download")
             _t0 = _time.perf_counter()
-            hdr = np.asarray(hdr_dev).reshape(d, hoff + nq)
+            hdr = np.asarray(hdr_dev).reshape(1, _HOFF + nq)
             self._ktime("wait_header_" + part["kind"], _t0)
             self.download_bytes += hdr.nbytes
             self.download_bytes_padded += hdr.nbytes \
-                + d * d_ent * s * itemsize
+                + d_ent * s * itemsize
             runs += 1
         faults.check("transfer", "entry download")
         _t1 = _time.perf_counter()
@@ -3525,11 +3195,11 @@ class DeviceState:
             # extra slice dispatch — on the pipelined path the prefix
             # fetch rides the prefetch thread and overlaps compute, so it
             # never asks
-            maxtot = int(hdr[:, 0].max())
-            if self._prefix_pays(d, d_ent * s, maxtot, itemsize):
-                ent = _fetch_entry_prefix(box["ent"], d, d_ent * s, maxtot)
+            maxtot = int(hdr[0, 0])
+            if self._prefix_pays(d_ent * s, maxtot, itemsize):
+                ent = _fetch_entry_prefix(box["ent"], d_ent * s, maxtot)
             else:
-                ent = np.asarray(box["ent"]).reshape(d, d_ent * s)
+                ent = np.asarray(box["ent"]).reshape(1, d_ent * s)
             t_e = None
         self._ktime_span("wait_entries_" + part["kind"],
                          *(t_e or (_t1, _time.perf_counter())))
@@ -3539,17 +3209,14 @@ class DeviceState:
             # bytes the sharded-store merge shipped home (header + merged
             # entry block) — the ``shard_merge_bytes`` index counter
             self.n_shard_merge_bytes += hdr.nbytes + ent.nbytes
-        if attr:
-            # the attributed header carries the in-kernel elision tallies
-            # (eknown-graded transitive rows vs decided-below-pivot rows)
-            # and the download is the post-attribution entry set
-            self.n_elided_transitive += int(hdr[:, 3].sum())
-            self.n_elided_decided += int(hdr[:, 4].sum())
-            self.attr_download_bytes += hdr.nbytes + ent.nbytes
-        tb, tj, tm, tq = _decode_triples(hdr, ent, nq, part["shard_n"],
-                                         bool(part.get("global_ids")),
-                                         part["mq"], part["q_m"],
-                                         hoff=hoff)
+        # the attributed header carries the in-kernel elision tallies
+        # (eknown-graded transitive rows vs decided-below-pivot rows) and
+        # the download is the post-attribution entry set
+        self.n_elided_transitive += int(hdr[0, 3])
+        self.n_elided_decided += int(hdr[0, 4])
+        self.attr_download_bytes += hdr.nbytes + ent.nbytes
+        tb, tj, tm, tq = _decode_triples(hdr, ent, nq, part["mq"],
+                                         part["q_m"])
         # stale/corrupted-result injection: perturb the slot indices the
         # kernel answered with.  Only where the detector actually runs —
         # paranoia shadow-verify on an IMMEDIATE flush (the protocol path);
@@ -3563,144 +3230,8 @@ class DeviceState:
         keep = b_global >= 0                      # drop pad rows
         return b_global[keep], tj[keep], tm[keep], tq[keep]
 
-    def _batch_collect(self, handle):
-        """Collect a dispatched batch: one two-stage compacted download per
-        part (plus an exact-header-sized re-run on overflow), then a pure
-        DECODE — the kernels answer with exact overlap triples, so no
-        false-positive pair exists to re-filter and the old host geometry
-        pass (``_exact_geometry``) has nothing to do on any device route.
-        The host route's probes were always exact, so its pairs and
-        triples arrive precomputed either way.  Re-runs use the table
-        snapshot captured at begin — registrations interleaved between
-        begin and end must not shift the queried snapshot.
-
-        Device-boundary failures here (transfer/download, injected or real)
-        quarantine the device routes and fail the flush over to the host
-        route; in paranoia mode the surviving device answer is additionally
-        shadow-verified against the host route and any mismatch is treated
-        as a device fault (both correctness-preserving: all routes are
-        bit-identical by construction).  The host fallback/shadow scan runs
-        against the live mirror — exact under the immediate (protocol)
-        path, where no mutation can interleave between begin and end."""
-        (parts, ids, ivs, qnp, q_m, queries, fmeta) = handle
-        import time as _time
-        nq = len(queries)
-        if len(parts) == 1 and parts[0]["kind"] == "host":
-            part = parts[0]
-            b_idx, j_idx = part["b"], part["j"]
-            self.n_queries += nq
-            self.n_kernel_deps += len(j_idx)
-            return b_idx, j_idx, part["pmq"], ids, ivs, qnp, queries
-        try:
-            outs = [self._collect_part(p) for p in parts]
-        except faults.DEVICE_EXCEPTIONS as e:
-            self._device_fault(e, f"collect: {e}", sliced=True)
-            return self._host_fallback_collect(handle)
-        _tg = _time.perf_counter()
-        if len(outs) == 1:
-            tb, tj, tm, tq = outs[0]
-        else:
-            tb = np.concatenate([o[0] for o in outs])
-            tj = np.concatenate([o[1] for o in outs])
-            tm = np.concatenate([o[2] for o in outs])
-            tq = np.concatenate([o[3] for o in outs])
-        # global triple dedupe: the in-kernel dedupe is per-part only —
-        # under the row-sharded bucket index one triple can surface from
-        # several shards.  The (b-major, code-ascending) dedupe order
-        # matches the per-part CSR order, so results are byte-identical
-        # with or without this pass; single-part exact kernels skip it
-        # (slot-sharded and single-device CSRs are unique by construction)
-        if len(tj) and (self.FORCE_TRIPLE_DEDUPE or len(parts) > 1
-                        or parts[0]["kind"] == "sharded_bucketed"):
-            order, first = _group_dedupe((tq, tm, tj, tb))
-            order = order[first]
-            tb, tj, tm, tq = tb[order], tj[order], tm[order], tq[order]
-        b_idx, j_idx, p_i = _tri_pairs(tb, tj)
-        if self._paranoid() and fmeta["immediate"]:
-            # shadow-verify: the exact (query, slot) pair set must match
-            # the host route's byte-for-byte; a mismatch means the device
-            # answered wrong (stale/corrupted result) — quarantine it and
-            # serve the host answer
-            self.n_shadow_checks += 1
-            b_h, j_h, pmq_h = self.deps.host_pairs(qnp, q_m,
-                                                   fmeta["floor_id"])
-            cap = np.int64(self.deps.capacity)
-            if not np.array_equal(np.unique(b_idx * cap + j_idx),
-                                  np.unique(b_h * cap + j_h)):
-                self.n_shadow_mismatches += 1
-                self._device_fault("stale_result", "shadow mismatch",
-                                   sliced=True)
-                self.n_fallback_queries += nq
-                self.n_queries += nq
-                self.n_kernel_deps += len(j_h)
-                return b_h, j_h, pmq_h, ids, ivs, qnp, queries
-        sh = self.store_shards
-        if sh is not None and sh.active:
-            sh.note_success()   # probing suspect slices are healthy again
-        if fmeta["probing"]:
-            self._restore_device()   # the probe flush succeeded end-to-end
-        self.n_queries += nq
-        self.n_kernel_deps += len(j_idx)
-        self._ktime("host_decode", _tg)
-        return b_idx, j_idx, (p_i, tm, tq), ids, ivs, qnp, queries
-
-    def _exact_geometry(self, b_idx, j_idx, ivs, qnp, q_m):
-        """REFERENCE implementation of the exact overlap geometry over a
-        (query, slot) pair list, yielding the (pair, dep-interval,
-        query-interval) emit triples.  r10 pushed this into every device
-        kernel (the CSR entries ARE the triples, as sorted composite
-        codes), so no production route calls it anymore — it remains as
-        the oracle the exact-kernel property tests compare against
-        (tests/test_exact_collect.py) and as the executable spec of the
-        emit-triple order (np.nonzero over [P, M, Q] = pair-major,
-        dep-column, query-column — exactly the kernels' code sort)."""
-        lo, hi, _dom = ivs
-        lo_p, hi_p = lo[j_idx], hi[j_idx]                       # [P, M]
-        used = lo_p <= hi_p
-        qlo_p = qnp[b_idx, 7:7 + q_m]                           # [P, Q]
-        qhi_p = qnp[b_idx, 7 + q_m:7 + 2 * q_m]
-        overlap = (used[:, :, None]
-                   & (lo_p[:, :, None] <= qhi_p[:, None, :])
-                   & (qlo_p[:, None, :] <= hi_p[:, :, None]))   # [P, M, Q]
-        p_i, m_i, q_i = np.nonzero(overlap)
-        # drop pairs with no exact overlap (bounding-box false positives)
-        present = np.zeros(len(j_idx), bool)
-        present[p_i] = True
-        if not present.all():
-            new_pos = np.cumsum(present) - 1
-            b_idx, j_idx = b_idx[present], j_idx[present]
-            p_i = new_pos[p_i]
-        return b_idx, j_idx, (p_i, m_i, q_i)
-
-    def _host_fallback_collect(self, handle):
-        """Serve a flush whose device parts failed mid-collect from the
-        host route (identical bytes by the routing invariant)."""
-        (_parts, ids, ivs, qnp, q_m, queries, fmeta) = handle
-        nq = len(queries)
-        b_h, j_h, pmq_h = self.deps.host_pairs(qnp, q_m, fmeta["floor_id"])
-        self.n_host_queries += nq
-        self.n_fallback_queries += nq
-        self.n_dispatches += 1
-        self.n_queries += nq
-        self.n_kernel_deps += len(j_h)
-        return b_h, j_h, pmq_h, ids, ivs, qnp, queries
-
-    def deps_query_batch_end(self, handle):
-        """Raw packed-CSR collection (no floors/attribution) — the transport
-        layout replicas exchange; deps_query_batch_end_attributed is the
-        protocol-complete variant."""
-        b_idx, j_idx, _ov, ids, _ivs, _qnp, queries = \
-            self._batch_collect(handle)
-        order = np.argsort(b_idx, kind="stable")
-        b_idx, j_idx = b_idx[order], j_idx[order]
-        counts = np.bincount(b_idx, minlength=len(queries))
-        row_ptr = np.zeros(len(queries) + 1, np.int64)
-        np.cumsum(counts, out=row_ptr[1:])
-        msb, lsb, node = ids[0], ids[1], ids[2]
-        return (row_ptr, msb[j_idx], lsb[j_idx], node[j_idx])
-
     def _host_attr_triples(self, handle, part=None, snapshot=None):
-        """Entry-level host answer for an ATTRIBUTED flush: the host
+        """Entry-level host answer for a flush: the host
         route's exact probes + the same floor/elision drops the kernels
         fold in, over the flush's snapshot columns.  Serves the host
         route itself, the device-fault failover and the paranoia shadow.
@@ -3710,8 +3241,7 @@ class DeviceState:
             tb, tj, cm, cq = part["ent"]
         else:
             tb, tj, cm, cq = self.deps.host_pairs(
-                qnp, q_m, fmeta["floor_id"], snapshot=snapshot,
-                entries=True)
+                qnp, q_m, fmeta["floor_id"], snapshot=snapshot)
         tb, tj, tm, tq, n_t, n_d = self._attr_filter_entries(
             tb, tj, cm, cq, ids, ivs, fmeta["aidx"], fmeta["rankb"],
             fmeta["floor_skip"])
@@ -3720,7 +3250,7 @@ class DeviceState:
         return tb, tj, tm, tq
 
     def _batch_collect_attr(self, handle):
-        """Collect an ATTRIBUTED dispatched batch: the kernels already
+        """Collect a dispatched batch: the kernels already
         applied floors/elision/dedupe, so the download IS the final entry
         set and this is a pure decode.  The host route (and any device
         failover / paranoia shadow) applies the identical drops through
@@ -3856,25 +3386,17 @@ class DeviceState:
 
     def deps_query_batch_end_attributed(self, safe, handle, builders) -> None:
         """Collect a dispatched batch and fold each query's deps into its
-        builder with full host-path semantics.  Attributed handles (every
-        protocol path since r15) arrive pre-floored/pre-elided from the
-        kernels and take the thin shared finalize; raw handles keep the
-        legacy host _attribute_batch pass (the property-test oracle)."""
+        builder with full host-path semantics: the entries arrive
+        pre-floored/pre-elided (in-kernel on the device routes,
+        _attr_filter_entries on the host route) and take the thin shared
+        finalize."""
         import time as _time
-        if handle[6].get("attributed"):
-            tb, tj, tm, tq, ids, ivs, qnp, q_m, _queries = \
-                self._batch_collect_attr(handle)
-            _ta = _time.perf_counter()
-            self._finalize_attr_entries(tb, tj, tm, tq, ids, ivs, qnp,
-                                        q_m, builders)
-            self._ktime("host_attr_finalize", _ta)
-            return
-        b_idx, j_idx, overlap, ids, ivs, qnp, queries = \
-            self._batch_collect(handle)
+        tb, tj, tm, tq, ids, ivs, qnp, q_m, _queries = \
+            self._batch_collect_attr(handle)
         _ta = _time.perf_counter()
-        self._attribute_batch(safe, b_idx, j_idx, overlap, ids, ivs, qnp,
-                              queries, builders)
-        self._ktime("host_attribute", _ta)
+        self._finalize_attr_entries(tb, tj, tm, tq, ids, ivs, qnp,
+                                    q_m, builders)
+        self._ktime("host_attr_finalize", _ta)
 
     # ------------------------------------------------------------------
     # fused cross-store dispatch (r08; driven by local.dispatch's
@@ -3976,7 +3498,7 @@ class DeviceState:
         self.n_fallback_queries += hint["nq"]
         hint["probing"] = False
         hint["host"] = self.deps.host_pairs(hint["qnp"], hint["q_m"],
-                                            hint["floor_id"], entries=True)
+                                            hint["floor_id"])
 
     def _hint_attr_entries(self, hint, ent4) -> tuple:
         """Turn a fused hint's host-route per-entry answer into the
@@ -4063,7 +3585,7 @@ class DeviceState:
                 if int(hdr[:, 1].max()) <= s_ \
                         and int(hdr[:, 2].max()) <= k_:
                     faults.check("transfer", "entry download")
-                    ent = _fetch_entry_prefix(ent_dev, 1, d_ent * s_,
+                    ent = _fetch_entry_prefix(ent_dev, d_ent * s_,
                                               int(hdr[:, 0].max()))
                     self.download_bytes += ent.nbytes
                 runs += 1
@@ -4085,12 +3607,11 @@ class DeviceState:
             return self._hint_attr_entries(
                 hint, self.deps.host_pairs(
                     qnp, q_m, hint["floor_id"],
-                    snapshot=self._fused_snapshot(hint), entries=True))
+                    snapshot=self._fused_snapshot(hint)))
         self.n_elided_transitive += int(hdr[:, 3].sum())
         self.n_elided_decided += int(hdr[:, 4].sum())
         self.attr_download_bytes += hdr.nbytes + ent.nbytes
-        tb, tj, tm, tq = _decode_triples(hdr, ent, b_pad, shard_n,
-                                         True, mq, qmc, hoff=5)
+        tb, tj, tm, tq = _decode_triples(hdr, ent, b_pad, mq, qmc)
         if pad_stride is not None:
             # mesh fused codes number slots on the PADDED per-shard
             # stride (every member padded to the group's largest slice):
@@ -4110,7 +3631,7 @@ class DeviceState:
             hb, hj, hm, hq = self._hint_attr_entries(
                 hint, self.deps.host_pairs(
                     qnp, q_m, hint["floor_id"],
-                    snapshot=self._fused_snapshot(hint), entries=True))
+                    snapshot=self._fused_snapshot(hint)))
             cap = np.int64(len(hint["ids"][0]))
             if not np.array_equal(np.unique(tb * cap + tj),
                                   np.unique(hb * cap + hj)):

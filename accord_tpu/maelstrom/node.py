@@ -632,10 +632,12 @@ class MaelstromProcess:
                 self.node, shard_cycle_micros=5_000_000,
                 global_cycle_micros=15_000_000)
             self.durability.start()
-        # warm-compile the device deps kernel BEFORE acking init: Maelstrom
+        # warm the device deps flush BEFORE acking init — the path the
+        # first PreAccept takes, calibration probe included: Maelstrom
         # sends no work until init_ok, and a cold first compile (seconds)
         # would otherwise race the 1s callback sweeper into spurious
         # client-visible timeouts on the first txns
+        from ..primitives.deps import DepsBuilder
         from ..primitives.timestamp import Domain, TxnKind
         for store in self.node.command_stores.stores:
             dev = getattr(store, "device", None)
@@ -643,8 +645,11 @@ class MaelstromProcess:
                 continue
             tid = self.node.next_txn_id(TxnKind.Write, Domain.Key)
             try:
-                dev.deps_query_batch(
-                    [(tid, tid, tid.kind().witnesses(), [0], [])])
+                # the finalize reads the handle's own snapshot, never
+                # ``safe``: there is no store task to take one from here
+                dev.deps_query_batch_attributed(
+                    None, [(tid, tid, tid.kind().witnesses(), [0], [])],
+                    [DepsBuilder()])
             except Exception:
                 pass   # warmup must never block startup
         self._reply_client(src, body["msg_id"], {"type": "init_ok"})

@@ -40,13 +40,18 @@ integer key::
 where ``M_t`` is the table's interval width and ``Q`` the query's.  Codes
 ascend (slot-major, then dep column, then query column) within each CSR
 row, which is exactly the (pair, m, q) order the host's old
-``np.nonzero(overlap)`` geometry pass produced — so the device answer
-plugs straight into attribution and ``_exact_geometry`` has nothing left
-to do on any device route.  The result ships as TWO buffers, ``(header,
-entries)``: the header (total, max_row_count, row_end[B]) is a few hundred
+``np.nonzero(overlap)`` geometry pass produced (tests/deps_oracle.py
+keeps that pass as the reference) — so the device answer plugs straight
+into the attribution stage.  The result ships as TWO buffers, ``(header,
+entries)``: the header (scalars, then row_end[B]) is a few hundred
 int32s the host fetches first; only the LIVE PREFIX of the entry buffer
 crosses the wire after it (int32 entries whenever
 ``capacity * M_t * Q <= INT32_CODE_MAX``, int64 past that crossover).
+The store's flush launches only the ATTRIBUTED variants (the r15 section
+below: ``calculate_deps_flat_attr``, ``bucketed_attr_jit``,
+``fused_flat_attr`` and their mesh twins in parallel.sharded), which run
+the raw phases here (``flat_csr_local``, ``bucketed_flat``) and then the
+attribution stage over the compacted entries.
 """
 
 from __future__ import annotations
@@ -252,6 +257,10 @@ def _compact_topk(dep_mask: jnp.ndarray, k: int):
     return idx, counts
 
 
+# NOT a route: no flush launches this program.  It is the route
+# calibration's (DeviceState._measure_route_calibration): the probe times it
+# for ``c_dev`` and, against calculate_deps_flat_attr, for ``c_attr`` — the
+# prices that place every route crossover.
 @partial(jax.jit, static_argnames=("m", "s", "k", "wide"))
 def calculate_deps_flat(table: DepsTable, qmat: jnp.ndarray,
                         m: int, s: int, k: int, wide: bool = False):
@@ -532,22 +541,6 @@ def bucketed_flat(table: DepsTable, buckets: BucketTable, qmat: jnp.ndarray,
     return header, ent
 
 
-bucketed_flat_jit = jax.jit(
-    bucketed_flat, static_argnames=("m", "span", "s", "k", "keff", "wide"))
-
-
-@partial(jax.jit, static_argnames=("m", "span", "s", "k", "keff", "wide"))
-def bucketed_flat_pruned(table: DepsTable, buckets: BucketTable,
-                         qmat: jnp.ndarray, m: int, span: int, s: int,
-                         k: int, prune_msb: jnp.ndarray = None,
-                         prune_lsb: jnp.ndarray = None,
-                         prune_node: jnp.ndarray = None,
-                         keff: int = None, wide: bool = False):
-    return bucketed_flat(table, buckets, qmat, m, span, s, k,
-                         (prune_msb, prune_lsb, prune_node),
-                         keff=keff, wide=wide)
-
-
 def decode_triples(codes: np.ndarray, m_t: int, q_m: int):
     """Host decode of composite overlap codes -> (slot, dep_col, q_col)
     int64 triples (the inverse of the kernel-side encoding)."""
@@ -561,17 +554,16 @@ def decode_triples(codes: np.ndarray, m_t: int, q_m: int):
 
 # -- fused (batched-over-stores) dispatch ------------------------------------
 #
-# The launch-coalescing entry point (r08): one device dispatch answers the
-# deps flushes of SEVERAL CommandStores that became runnable in the same
-# event-loop step.  Each store's table is padded (free slots / PAD intervals
-# prune themselves out of the mask, so padding never changes a store's
-# answer) to the group maximum and stacked on a leading store axis; the
-# per-store scan is the EXACT flat_csr_local trace vmapped over that axis —
-# integer compares/sorts/cumsums vmap losslessly, so every store's CSR block
-# is bit-identical to the solo launch it replaces.  The per-store prune
-# floors ride as [S] triples (zeros = prune nothing, the ts_lt convention).
-
-_FUSED_CACHE = {}
+# Launch coalescing (r08; the entry point is fused_flat_attr below): one
+# device dispatch answers the deps flushes of SEVERAL CommandStores that
+# became runnable in the same event-loop step.  Each store's table is padded
+# (free slots / PAD intervals prune themselves out of the mask, so padding
+# never changes a store's answer) to the group maximum and stacked on a
+# leading store axis; the per-store scan is the solo trace vmapped over that
+# axis — integer compares/sorts/cumsums vmap losslessly, so every store's
+# CSR block is bit-identical to the solo launch it replaces.  The per-store
+# prune floors ride as [S] triples (zeros = prune nothing, the ts_lt
+# convention).
 
 
 def _pad_table_cols(cols, n, m):
@@ -587,59 +579,6 @@ def _pad_table_cols(cols, n, m):
                                    constant_values=fill)
     return (pad1(msb, 0), pad1(lsb, 0), pad1(node, 0), pad1(kind, 0),
             pad1(status, SLOT_FREE), pad2(lo, PAD_LO), pad2(hi, PAD_HI))
-
-
-def fused_flat_csr(tables: Sequence[DepsTable], qmats: np.ndarray,
-                   prunes: Tuple[np.ndarray, np.ndarray, np.ndarray],
-                   m: int, s: int, k: int, wide: bool = False):
-    """One fused launch for S stores' batched deps scans.
-
-    ``tables``: each store's (cached, device-resident) DepsTable — may
-    differ in capacity/max_intervals; padding + stacking happens INSIDE the
-    jitted program so the launch consumes the cached per-store buffers
-    directly (no host re-upload, no eager stack dispatches).
-    ``qmats``: int64[S, B, 7 + 2m] (per-store query matrices, row-padded to
-    a common B by the caller).  ``prunes``: per-store floor triples
-    (int64[S], int64[S], int32[S]); zeros prune nothing.
-    Returns (header int32[S, 2 + B], entries [S, s]) — row i is EXACTLY
-    the solo calculate_deps_flat[_pruned] output for store i (codes scale
-    on the GROUP interval width m_max, which the harvest decodes with)."""
-    caps = tuple((t.capacity, t.lo.shape[1]) for t in tables)
-    b = qmats.shape[1]
-    key = (caps, b, m, s, k, wide)
-    fn = _FUSED_CACHE.get(key)
-    if fn is None:
-        n_max = max(c for c, _ in caps)
-        m_max = max(mi for _, mi in caps)
-
-        def traced(flat_cols, qm, pm, pl, pn):
-            padded = [_pad_table_cols(cols, n_max, m_max)
-                      for cols in flat_cols]
-            stacked = DepsTable(*(jnp.stack(col)
-                                  for col in zip(*padded)))
-            return jax.vmap(
-                lambda t, q, a, b_, c: flat_csr_local(t, q, m, s, k,
-                                                      (a, b_, c),
-                                                      wide=wide)
-            )(stacked, qm, pm, pl, pn)
-
-        fn = _FUSED_CACHE[key] = jax.jit(traced)
-    return fn(tuple(tuple(t) for t in tables), jnp.asarray(qmats),
-              jnp.asarray(prunes[0]), jnp.asarray(prunes[1]),
-              jnp.asarray(prunes[2]))
-
-
-@partial(jax.jit, static_argnames=("m", "s", "k", "wide"))
-def calculate_deps_flat_pruned(table: DepsTable, qmat: jnp.ndarray,
-                               prune_msb: jnp.ndarray, prune_lsb: jnp.ndarray,
-                               prune_node: jnp.ndarray,
-                               m: int, s: int, k: int, wide: bool = False):
-    """calculate_deps_flat with a device-side RedundantBefore floor: entries
-    below the (conservative, batch-global) floor never enter the CSR, so a
-    hot store whose durable prefix dominates ships only the live tail (the
-    host attribution still applies the exact per-token floors on top)."""
-    return flat_csr_local(table, qmat, m, s, k,
-                          (prune_msb, prune_lsb, prune_node), wide=wide)
 
 
 def pack_query_matrix(queries: Sequence[tuple], max_intervals: int) -> np.ndarray:

@@ -1,11 +1,9 @@
 """Multi-chip parallelism: mesh construction + sharded protocol kernels."""
 
 from .sharded import (STORE_AXIS, make_mesh, shard_bucket_table, shard_table,
-                      sharded_bucketed_flat, sharded_calculate_deps,
-                      sharded_calculate_deps_flat_pruned, sharded_drain,
+                      sharded_calculate_deps, sharded_drain,
                       sharded_protocol_step)
 
 __all__ = ["STORE_AXIS", "make_mesh", "shard_bucket_table", "shard_table",
-           "sharded_bucketed_flat", "sharded_calculate_deps",
-           "sharded_calculate_deps_flat_pruned", "sharded_drain",
+           "sharded_calculate_deps", "sharded_drain",
            "sharded_protocol_step"]

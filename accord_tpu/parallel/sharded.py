@@ -201,178 +201,6 @@ def sharded_ready_frontier(mesh: Mesh):
     return fn
 
 
-_FLAT_CACHE = {}
-
-
-def sharded_calculate_deps_flat(mesh: Mesh, m: int, s: int, k: int,
-                                wide: bool = False):
-    """Mesh-sharded variant of ops.deps_kernel.calculate_deps_flat: the slot
-    dimension lives across the mesh (the reference's CommandStores scatter,
-    CommandStores.java:575-643), the query batch is replicated, each device
-    scans and CSR-compacts its slice, and the per-shard CSRs concatenate —
-    the cross-shard ``Deps.merge`` (Deps.java:256) happens as the host
-    merges shard-local slot indices with their shard offsets.
-
-    Returns fn(table_sharded, qmat) -> (header int32[D * (2 + B)],
-    entries [D * s]) where each shard block is (total, max_row_count,
-    row_end[B]) / (entries[s]) with SHARD-LOCAL triple codes — the host
-    fetches headers, then only the live prefix of each shard's entries."""
-    from ..ops import deps_kernel as dk
-    # key by the mesh's device placement, not just its shape: two equal-
-    # shaped meshes with different device orderings must not share a jitted
-    # shard_map closed over the first mesh object
-    dev_key = tuple(d.id for d in mesh.devices.flat)
-    key = (tuple(mesh.shape.items()), dev_key, m, s, k, wide)
-    fn = _FLAT_CACHE.get(key)
-    if fn is not None:
-        return fn
-    table_specs = DepsTable(P(STORE_AXIS), P(STORE_AXIS), P(STORE_AXIS),
-                            P(STORE_AXIS), P(STORE_AXIS),
-                            P(STORE_AXIS, None), P(STORE_AXIS, None))
-
-    def local(table: DepsTable, qmat):
-        return dk.flat_csr_local(table, qmat, m, s, k, wide=wide)
-
-    fn = jax.jit(_shard_map(local, mesh, (table_specs, P()),
-                            (P(STORE_AXIS), P(STORE_AXIS))))
-    _FLAT_CACHE[key] = fn
-    return fn
-
-
-_FLATP_CACHE = {}
-
-
-def sharded_calculate_deps_flat_pruned(mesh: Mesh, m: int, s: int, k: int,
-                                       wide: bool = False):
-    """sharded_calculate_deps_flat with a device-side RedundantBefore floor:
-    the (conservative, batch-global) prune triple is replicated to every
-    shard, so entries below the durable watermark never enter any shard's
-    CSR — a durable-prefix-dominated store stops shipping redundant history
-    off every device (the r05 mesh path hard-disabled this; VERDICT Weak #3).
-
-    Returns fn(table_sharded, qmat, pm, pl, pn) -> (header int32[D*(2+B)],
-    entries [D*s]) with SHARD-LOCAL triple codes, same block layout as the
-    unpruned variant."""
-    from ..ops import deps_kernel as dk
-    dev_key = tuple(d.id for d in mesh.devices.flat)
-    key = (tuple(mesh.shape.items()), dev_key, m, s, k, wide)
-    fn = _FLATP_CACHE.get(key)
-    if fn is not None:
-        return fn
-    table_specs = DepsTable(P(STORE_AXIS), P(STORE_AXIS), P(STORE_AXIS),
-                            P(STORE_AXIS), P(STORE_AXIS),
-                            P(STORE_AXIS, None), P(STORE_AXIS, None))
-
-    def local(table: DepsTable, qmat, pm, pl, pn):
-        return dk.flat_csr_local(table, qmat, m, s, k, (pm, pl, pn),
-                                 wide=wide)
-
-    fn = jax.jit(_shard_map(local, mesh,
-                            (table_specs, P(), P(), P(), P()),
-                            (P(STORE_AXIS), P(STORE_AXIS))))
-    _FLATP_CACHE[key] = fn
-    return fn
-
-
-_BUCK_CACHE = {}
-
-
-def sharded_bucketed_flat(mesh: Mesh, m: int, span: int, s: int, k: int,
-                          m_t: int = None, keff: int = None,
-                          wide: bool = False):
-    """Mesh-sharded variant of ops.deps_kernel.bucketed_flat: the bucket
-    ROWS (and the wide/straggler list) are row-sharded across the mesh, the
-    query batch is replicated, and each shard probes only the bucket rows it
-    owns — a query's global bucket-row columns are translated to shard-local
-    rows inside the shard_map (rows outside the shard become "no bucket
-    here"), so the union of per-shard CSRs is exactly the single-device
-    bucketed answer.  Entries carry GLOBAL slot ids inside their overlap
-    codes (BucketTable embeds them), so the host merge applies no shard
-    offset; a triple whose bucket rows land on different shards can appear
-    in several shard blocks — the host-side triple dedupe removes the
-    cross-shard duplicates (in-kernel dedupe is per-shard only).  ``m_t``
-    is the owning table's interval width (codes scale on it; the mesh local
-    has no table to read it from) and ``keff`` the live bucket-occupancy
-    slice, both static.
-
-    The prune triple is replicated (pass zeros for no floor, which the
-    unsigned ts_lt treats as prune-nothing).  Returns
-    fn(buckets_sharded, qmat, pm, pl, pn) -> (header int32[D * (2 + B)],
-    entries [D * s])."""
-    from ..ops import deps_kernel as dk
-    dev_key = tuple(d.id for d in mesh.devices.flat)
-    key = (tuple(mesh.shape.items()), dev_key, m, span, s, k, m_t, keff,
-           wide)
-    fn = _BUCK_CACHE.get(key)
-    if fn is not None:
-        return fn
-    bucket_specs = BucketTable(*([P(STORE_AXIS, None)] * 8),
-                               *([P(STORE_AXIS)] * 8))
-
-    def local(buckets: BucketTable, qmat, pm, pl, pn):
-        off = lax.axis_index(STORE_AXIS).astype(jnp.int32) \
-            * buckets.blo.shape[0]
-        return dk.bucketed_flat(None, buckets, qmat, m, span, s, k,
-                                (pm, pl, pn), row_offset=off,
-                                keff=keff, wide=wide, m_t=m_t)
-
-    fn = jax.jit(_shard_map(local, mesh,
-                            (bucket_specs, P(), P(), P(), P()),
-                            (P(STORE_AXIS), P(STORE_AXIS))))
-    _BUCK_CACHE[key] = fn
-    return fn
-
-
-_FUSEDSH_CACHE = {}
-
-
-def sharded_fused_flat(mesh: Mesh, n_stores: int, m: int, s: int, k: int,
-                       wide: bool = False):
-    """Batched-over-stores variant of sharded_calculate_deps_flat — the
-    mesh leg of r08 launch coalescing.  Each of the S stores' slot-sharded
-    DepsTables rides in as its own (cached, device-resident) sharded
-    pytree; inside the shard_map every shard pads its local slices to the
-    group maximum (free slots / PAD intervals prune themselves out of the
-    mask) and vmaps the exact flat_csr_local trace over the store axis, so
-    each store's shard blocks are bit-identical to the solo sharded launch
-    they replace.  Per-store prune floors ride as replicated [S] triples
-    (zeros prune nothing).
-
-    Returns fn(*tables, qmats, pm, pl, pn) -> (header int32[S, D*(2+B)],
-    entries [S, D*s]): store row i holds D shard blocks with SHARD-LOCAL
-    triple codes — the host decode offsets slots by the store's OWN
-    shard_n (capacity_i / d; padding rows are free and never surface) and
-    scales codes on the GROUP interval width m_max."""
-    from ..ops import deps_kernel as dk
-    dev_key = tuple(d.id for d in mesh.devices.flat)
-    key = (dev_key, n_stores, m, s, k, wide)
-    fn = _FUSEDSH_CACHE.get(key)
-    if fn is not None:
-        return fn
-    table_specs = DepsTable(P(STORE_AXIS), P(STORE_AXIS), P(STORE_AXIS),
-                            P(STORE_AXIS), P(STORE_AXIS),
-                            P(STORE_AXIS, None), P(STORE_AXIS, None))
-    in_specs = tuple([table_specs] * n_stores) + (P(), P(), P(), P())
-
-    def local(*args):
-        tables = args[:n_stores]
-        qmats, pm, pl, pn = args[n_stores:]
-        n_max = max(t.msb.shape[0] for t in tables)
-        m_max = max(t.lo.shape[1] for t in tables)
-        padded = [dk._pad_table_cols(tuple(t), n_max, m_max)
-                  for t in tables]
-        stacked = DepsTable(*(jnp.stack(col) for col in zip(*padded)))
-        return jax.vmap(
-            lambda t, q, a, b, c: dk.flat_csr_local(t, q, m, s, k,
-                                                    (a, b, c), wide=wide)
-        )(stacked, qmats, pm, pl, pn)
-
-    fn = jax.jit(_shard_map(local, mesh, in_specs,
-                            (P(None, STORE_AXIS), P(None, STORE_AXIS))))
-    _FUSEDSH_CACHE[key] = fn
-    return fn
-
-
 def shard_bucket_table(mesh: Mesh, buckets: BucketTable) -> BucketTable:
     """Place a BucketTable's bucket-row and wide dimensions across the mesh
     (row counts must divide the device count evenly)."""
@@ -512,14 +340,19 @@ def sharded_flat_attr(mesh: Mesh, m: int, s: int, k: int,
 def sharded_bucketed_attr(mesh: Mesh, m: int, span: int, s: int, k: int,
                           m_t: int, keff: int, wide: bool = False,
                           floors: bool = True, elide: bool = True):
-    """Mesh-sharded bucketed_attr: bucket rows and the wide list sharded as
-    in sharded_bucketed_flat; the attribution columns are REPLICATED (the
-    entries carry global slot ids, and a shard must grade slots whose rows
-    it does not own), the floor/elision index replicated.  The on-device
-    merge removes cross-shard duplicates (one triple reachable via bucket
-    rows on different shards) and applies the key-domain (slot, col) dedupe
-    across shards — the host-side global triple dedupe has nothing left to
-    do.
+    """Mesh-sharded bucketed_attr: the bucket ROWS and the wide/straggler
+    list are row-sharded across the mesh and the query batch is replicated;
+    each shard probes only the bucket rows it owns (a query's global
+    bucket-row columns are translated to shard-local rows inside the
+    shard_map, rows outside the shard become "no bucket here"), so the
+    union over shards is exactly the single-device bucketed answer.  The
+    attribution columns are REPLICATED (the entries carry global slot ids,
+    and a shard must grade slots whose rows it does not own), the
+    floor/elision index replicated.  The on-device merge removes
+    cross-shard duplicates (one triple reachable via bucket rows on
+    different shards) and applies the key-domain (slot, col) dedupe across
+    shards.  ``m_t`` is the owning table's interval width (codes scale on
+    it) and ``keff`` the live bucket-occupancy slice, both static.
 
     The entry TOKEN (a key dep's own footprint point) lives in the
     slot-sharded interval table, so each shard contributes the tokens of

@@ -384,8 +384,7 @@ def bench_hot_keys():
         return sum(b.build().key_deps.relation_count() for b in builders)
 
     for batch in batches:
-        pending.append((dev.deps_query_batch_begin(
-            batch, prune_floors=True, attributed=True), batch))
+        pending.append((dev.deps_query_batch_begin(batch), batch))
         if len(pending) >= 2:
             n_deps += collect3(*pending.pop(0))
     while pending:
@@ -740,122 +739,6 @@ def bench_launch_amortized():
                 "acceptance metric"}]
 
 
-def bench_store_sharded():
-    """CONFIG 5b (r21): ONE store scaled past a single chip — 1M in-flight
-    slots against a 128k single-device budget on the 8-device cpu mesh.
-    The budget ladder's spill rung activates sliced residency (each device
-    owns a contiguous 128k-slot slice) instead of pinning to host; queries
-    fan to every slice with the pair merge done on device.  The row's
-    ``dryrun_multichip`` field is a bit-exactness ASSERTION: the sharded
-    CSR must byte-equal the host oracle over the same 1M registrations."""
-    import time as _t
-    from accord_tpu.local.device_index import DeviceState
-    from accord_tpu.ops import deps_kernel as dk
-    from accord_tpu.primitives.timestamp import Domain, TxnId, TxnKind
-
-    N, BUDGET, B5, KEYS5 = 1 << 20, 1 << 17, 128, 1 << 22
-    store = BenchStore()
-    dev = DeviceState(store)
-    assert dev.mesh is not None, "config5b needs the multi-device mesh"
-    dev.device_budget_slots = BUDGET
-    dev.route_override = "dense"
-    m = dev.deps
-    # walk the budget ladder to 1M slots: every doubling consults
-    # _approve_grow, so crossing the budget exercises the real spill rung
-    # (breach -> compact(nothing to free) -> spill-to-sharded)
-    t0 = _t.time()
-    while m.capacity < N:
-        m.free_slots.clear()      # force the grow (no compacted slack)
-        m._grow_capacity()
-    grow_s = _t.time() - t0
-    assert dev.store_shards is not None and dev.store_shards.active, \
-        "config5b never spilled to the sharded store"
-    assert not dev.host_pinned, "config5b pinned to host"
-    # bulk registration fill (vectorized: 1M python register() calls would
-    # measure the interpreter, not the store) — same column layout alloc
-    # writes, full-slice rebuild on the first sliced upload
-    rng = np.random.default_rng(13)
-    hlc = rng.choice(np.arange(1, 4 * N, dtype=np.int64), size=N,
-                     replace=False)
-    flags = np.int64((int(TxnKind.Write) << 1) | int(Domain.Key))
-    m.msb[:] = np.int64(1) << 16              # epoch 1, hlc < 2^48
-    m.lsb[:] = (hlc << 16) | flags
-    m.node[:] = (np.arange(N) % 5 + 1).astype(np.int32)
-    m.kind[:] = int(TxnKind.Write)
-    m.domain[:] = int(Domain.Key)
-    m.status[:] = dk.SLOT_TRANSITIVE
-    toks = rng.integers(0, KEYS5, size=N).astype(np.int64)
-    m.lo[:, 0] = toks
-    m.hi[:, 0] = toks
-    m.free_slots = []
-    m.n_live = N
-    m.version += 1
-    m.mut_version += 1
-    m._snap = None
-    m._device = None
-    m._device_sh = None
-    m._dirty.clear()
-    m._dirty_sh.clear()
-    m._attr_dirty_sh.clear()
-    queries = []
-    for _ in range(B5):
-        bound = TxnId.create(1, int(rng.integers(5 * N, 6 * N)),
-                             TxnKind.Write, Domain.Key, 1)
-        queries.append((bound, bound, bound.kind().witnesses(),
-                        [int(rng.integers(0, KEYS5))], []))
-
-    def run_csr():
-        h = dev.deps_query_batch_begin(queries, immediate=True,
-                                       prune_floors=True)
-        return dev.deps_query_batch_end(h)
-
-    dev.route_override = "host"
-    t0 = _t.time()
-    host_csr = run_csr()
-    host_qps = B5 / (_t.time() - t0)
-    dev.route_override = "dense"
-    t0 = _t.time()
-    shard_csr = run_csr()                     # slice upload + compile
-    first_flush_s = _t.time() - t0
-    # the dryrun_multichip bit-exactness gate: deps_found on the sliced
-    # route must byte-equal the host oracle
-    for a, b in zip(host_csr, shard_csr):
-        assert np.array_equal(np.asarray(a), np.asarray(b)), \
-            "config5b sharded CSR != host oracle"
-    assert dev.n_store_sharded_flushes >= 1, \
-        "config5b flush did not route sharded"
-    reps = 2
-    t0 = _t.time()
-    for _ in range(reps):
-        run_csr()
-    dt = _t.time() - t0
-    d = dev.store_shards.d
-    return [{
-        "config": "5b",
-        "metric": "store_sharded_1M_slots_mesh8_query_txns_per_sec",
-        "value": round(B5 * reps / dt, 1), "unit": "txn/s",
-        "live_slots": N, "device_budget_slots": BUDGET,
-        "slots_per_device": N // d, "mesh_devices": d,
-        "host_oracle_qps": round(host_qps, 1),
-        "speedup_vs_host_pinned": round((B5 * reps / dt) / host_qps, 2),
-        "merge_ms_per_flush": round(1e3 * dt / reps, 1),
-        "first_flush_ms": round(1e3 * first_flush_s, 1),
-        "ladder_grow_ms": round(1e3 * grow_s, 1),
-        "shard_merge_bytes": int(dev.n_shard_merge_bytes),
-        "store_sharded_flushes": int(dev.n_store_sharded_flushes),
-        "slice_quarantines": int(dev.n_slice_quarantines),
-        "dryrun_multichip": True,
-        # wall txn/s of a single-shot 1M-slot dense scan on the cpu-mesh
-        # EMULATION oscillates with the box; the verdict-bearing signal is
-        # the dryrun_multichip assertion above (bit-exact vs host oracle),
-        # which fails the bench run itself on any drift
-        "gated": False,
-        "note": "one store's slot table sliced across the 8-device cpu "
-                "mesh via the budget ladder's r21 spill rung (1M live > "
-                "128k budget); pair merge on device, CSR byte-equal to "
-                "the host oracle (asserted), host-pinning avoided"}]
-
-
 def config4_child():
     """BASELINE configs[4], run in a subprocess on the virtual 8-device CPU
     mesh (multi-chip TPU hardware is not reachable from this environment):
@@ -1007,8 +890,7 @@ def main(em: Emitter):
 
         for batch in batches:
             t1 = time.time()
-            handle = dev.deps_query_batch_begin(batch, prune_floors=True,
-                                                attributed=True)
+            handle = dev.deps_query_batch_begin(batch)
             phases["begin"] += time.time() - t1
             pending.append((handle, batch))
             if len(pending) >= PIPELINE:
@@ -1186,14 +1068,6 @@ def main(em: Emitter):
             em.config(row)
     except Exception as e:
         em.note(f"# CONFIG 5 failed: {e!r}")
-    # CONFIG 5b is single-shot: the CSR bytes are seed-deterministic (the
-    # asserted gate) and a best-of-3 would rebuild the 1M-slot store 3x
-    try:
-        for row in bench_store_sharded():
-            row["quoted"] = "single-shot"
-            em.config(row)
-    except Exception as e:
-        em.note(f"# CONFIG 5b failed: {e!r}")
     try:
         import os
         import subprocess

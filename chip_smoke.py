@@ -243,8 +243,7 @@ def scan_pass(dev, safe, batches, route):
         return _deps_digest([b.build() for b in builders])
 
     for batch in batches:
-        pending.append((dev.deps_query_batch_begin(
-            batch, prune_floors=True, attributed=True), batch))
+        pending.append((dev.deps_query_batch_begin(batch), batch))
         if len(pending) >= 2:
             dg, n = collect(*pending.pop(0))
             digests.append(dg)

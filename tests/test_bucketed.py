@@ -17,6 +17,7 @@ from accord_tpu.primitives.keys import IntKey, Keys, Range, Ranges
 from accord_tpu.primitives.timestamp import Domain, TxnId, TxnKind
 
 
+from tests import deps_oracle
 from tests.conftest import make_device_state
 
 
@@ -110,14 +111,10 @@ def _brute(entries, q):
 
 
 def _raw_deps(dev, qs):
-    row_ptr, msb, lsb, node = dev.deps_query_batch(qs)
-    from accord_tpu.ops.packing import unpack_txn_id
-    out = []
-    for b in range(len(qs)):
-        sl = slice(int(row_ptr[b]), int(row_ptr[b + 1]))
-        out.append(sorted(unpack_txn_id(m, l, n)
-                          for m, l, n in zip(msb[sl], lsb[sl], node[sl])))
-    return out
+    """Per query, the sorted TxnIds the flush's built Deps name (these
+    stores hold no floor, no CommandsForKey and no TRANSITIVE slot, so
+    that is every overlapping earlier witnessed txn)."""
+    return deps_oracle.dep_ids(deps_oracle.flush_builders(dev, None, qs))
 
 
 def _check_rows(deps):

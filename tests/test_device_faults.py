@@ -15,12 +15,13 @@ import os
 import numpy as np
 import pytest
 
+from accord_tpu.primitives.deps import DepsBuilder
 from accord_tpu.utils import faults
 from accord_tpu.utils.random_source import RandomSource
 
 from tests.conftest import make_device_state, make_dispatch_node
-from tests.test_routing import (_attributed, _build, _csr, _enqueue_flush,
-                                _unpack_builders)
+from tests.test_routing import (_attributed, _build, _enqueue_flush,
+                                _reference, _unpack_builders)
 
 pytestmark = pytest.mark.faults
 
@@ -44,18 +45,18 @@ def _dev_q(dev):
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("route", ROUTES)
 @pytest.mark.parametrize("kind", RAISING)
-def test_fault_route_matrix_raising(route, kind):
-    """Launch/transfer faults at p=1.0 on every route: the flush fails over
-    to host and the attributed result is byte-identical."""
-    store, dev, safe, entries, floor, qs = _build(seed=31)
+@pytest.mark.parametrize("seed", [31, 53])
+def test_fault_route_matrix_raising(route, kind, seed):
+    """Launch/transfer faults at p=1.0 on every route: the WHOLE flush
+    fails over to the host route (same bytes — the host filter applies the
+    identical floor/elision drops, and both equal the reference), then the
+    store quarantines."""
+    store, dev, safe, entries, floor, qs = _build(seed=seed)
     dev.route_override = route
-    expect_csr = _csr(dev, qs, prune=True)
-    expect = _attributed(dev, safe, qs, prune=True)
+    expect = _attributed(dev, safe, qs)
+    assert expect == _reference(dev, safe, qs)
     with faults.device_fault(kind, 1.0, _rng()):
-        got_csr = _csr(dev, qs, prune=True)
-        got = _attributed(dev, safe, qs, prune=True)
-    for a, b in zip(expect_csr, got_csr):
-        np.testing.assert_array_equal(a, b)
+        got = _attributed(dev, safe, qs)
     assert got == expect
     if route == "host":
         # the host route never crosses the device boundary: no faults
@@ -64,6 +65,7 @@ def test_fault_route_matrix_raising(route, kind):
         assert dev.n_device_faults >= 1
         assert dev.n_quarantines >= 1
         assert dev._dev_quar_flushes > 0 or dev._dev_backoff > 0
+        assert dev.n_fallback_queries >= len(qs)
 
 
 @pytest.mark.parametrize("route", ROUTES)
@@ -74,10 +76,10 @@ def test_fault_route_matrix_stale_result(route):
     store, dev, safe, entries, floor, qs = _build(seed=32)
     dev.route_override = route
     dev.paranoia = True
-    expect = _attributed(dev, safe, qs, prune=True)
+    expect = _attributed(dev, safe, qs)
     checks_before = dev.n_shadow_checks
     with faults.device_fault("stale_result", 1.0, _rng()):
-        got = _attributed(dev, safe, qs, prune=True)
+        got = _attributed(dev, safe, qs)
     assert got == expect
     if route == "host":
         assert dev.n_shadow_mismatches == 0
@@ -93,7 +95,7 @@ def test_paranoia_clean_run_restores_nothing():
     store, dev, safe, entries, floor, qs = _build(seed=33)
     dev.route_override = "dense"
     dev.paranoia = True
-    _attributed(dev, safe, qs, prune=True)
+    _attributed(dev, safe, qs)
     assert dev.n_shadow_checks >= 1
     assert dev.n_shadow_mismatches == 0
     assert dev.n_quarantines == 0
@@ -110,7 +112,7 @@ def test_fused_launch_fault_fails_whole_batch_to_host(kind):
     member store's flush fails over to host with byte-identical results,
     and every member quarantines."""
     node, stores = make_dispatch_node((31, 47), fusion=True)
-    expected = [_attributed(dev, safe, qs, prune=True)
+    expected = [_attributed(dev, safe, qs)
                 for dev, safe, qs in stores]
     results = []
     with faults.device_fault(kind, 1.0, _rng()):
@@ -137,7 +139,7 @@ def test_fused_download_fault_fails_whole_batch_to_host():
     and serves its flush from the begin-time snapshot host scan — same
     bytes."""
     node, stores = make_dispatch_node((31, 47), fusion=True)
-    expected = [_attributed(dev, safe, qs, prune=True)
+    expected = [_attributed(dev, safe, qs)
                 for dev, safe, qs in stores]
     results = [_enqueue_flush(dev, qs) for dev, _safe, qs in stores]
     # step ONE scheduler event: the dispatcher — the fused launch is
@@ -162,7 +164,7 @@ def test_fused_stale_result_detected_by_shadow():
     node, stores = make_dispatch_node((31, 47), fusion=True)
     for dev, _safe, _qs in stores:
         dev.paranoia = True
-    expected = [_attributed(dev, safe, qs, prune=True)
+    expected = [_attributed(dev, safe, qs)
                 for dev, safe, qs in stores]
     results = [_enqueue_flush(dev, qs) for dev, _safe, qs in stores]
     with faults.device_fault("stale_result", 1.0, _rng()):
@@ -180,7 +182,7 @@ def test_fused_quarantine_recovers_to_fused():
     """After a fused-batch fault, the members re-probe independently and —
     once healthy — fuse again: the ladder composes with coalescing."""
     node, stores = make_dispatch_node((31, 47), fusion=True)
-    expected = [_attributed(dev, safe, qs, prune=True)
+    expected = [_attributed(dev, safe, qs)
                 for dev, safe, qs in stores]
 
     def round_trip():
@@ -211,9 +213,9 @@ def test_fused_quarantine_recovers_to_fused():
 def test_quarantine_backoff_probe_restore():
     store, dev, safe, entries, floor, qs = _build(seed=34)
     dev.route_override = "dense"
-    expect = _attributed(dev, safe, qs, prune=True)
+    expect = _attributed(dev, safe, qs)
     with faults.device_fault("transfer", 1.0, _rng()):
-        got = _attributed(dev, safe, qs, prune=True)   # faulted flush
+        got = _attributed(dev, safe, qs)   # faulted flush
     assert got == expect
     assert dev.n_quarantines == 1 and dev._dev_backoff == 1
     quarantined = dev._dev_quar_flushes
@@ -222,34 +224,34 @@ def test_quarantine_backoff_probe_restore():
     dev_mid = _dev_q(dev)
     fallback_before = dev.n_fallback_queries
     for _ in range(quarantined):
-        assert _attributed(dev, safe, qs, prune=True) == expect
+        assert _attributed(dev, safe, qs) == expect
     assert _dev_q(dev) == dev_mid
     assert dev.n_fallback_queries > fallback_before
     assert dev._dev_quar_flushes == 0
     # quarantine expired: the next flush is the PROBE — fault gone, so it
     # succeeds on the device route and restores health
-    assert _attributed(dev, safe, qs, prune=True) == expect
+    assert _attributed(dev, safe, qs) == expect
     assert dev.n_reprobes == 1
     assert dev.n_restores == 1
     assert dev._dev_backoff == 0 and dev._dev_quar_flushes == 0
     assert _dev_q(dev) > dev_mid
     # and the restored route keeps serving device-side
     dev_after = _dev_q(dev)
-    assert _attributed(dev, safe, qs, prune=True) == expect
+    assert _attributed(dev, safe, qs) == expect
     assert _dev_q(dev) > dev_after
 
 
 def test_probe_failure_requarantines_deeper():
     store, dev, safe, entries, floor, qs = _build(seed=35)
     dev.route_override = "dense"
-    expect = _attributed(dev, safe, qs, prune=True)
+    expect = _attributed(dev, safe, qs)
     with faults.device_fault("kernel_launch", 1.0, _rng()):
-        assert _attributed(dev, safe, qs, prune=True) == expect
+        assert _attributed(dev, safe, qs) == expect
         first = dev._dev_quar_flushes
         # burn down the quarantine with the fault STILL armed: the probe
         # flush fails and re-quarantines with a deeper backoff
         for _ in range(first + 1):
-            assert _attributed(dev, safe, qs, prune=True) == expect
+            assert _attributed(dev, safe, qs) == expect
     assert dev._dev_backoff == 2
     assert dev.n_quarantines == 2
     assert dev._dev_quar_flushes > first  # exponential: 8+jitter > 4+jitter
@@ -310,14 +312,14 @@ def test_oom_degrades_to_host_when_compaction_cannot_help():
     bound = TxnId.create(1, 10_000_000, TxnKind.Write, Domain.Key, 1)
     qs = [(bound, bound, bound.kind().witnesses(), [(i * 37) % 4096], [])
           for i in range(8)]
-    got = _attributed(dev, safe, qs, prune=True)
+    got = _attributed(dev, safe, qs)
     store2, dev2, safe2 = make_device_state(mesh=None)
     dev2.route_override = "dense"
     _register_n(dev2, 200, hlc_base=1)
-    expect = _attributed(dev2, safe2, qs, prune=True)
+    expect = _attributed(dev2, safe2, qs)
     assert got == expect
     host_before = dev.n_host_queries
-    _attributed(dev, safe, qs, prune=True)
+    _attributed(dev, safe, qs)
     assert dev.n_host_queries > host_before
 
 
@@ -369,35 +371,9 @@ def test_device_fault_context_restores_prior_arming():
 
 
 # ---------------------------------------------------------------------------
-# r15: faults during the ATTRIBUTED collect (the in-kernel
-# floors/elision path every protocol flush now rides)
+# r15: faults during the collect of the in-kernel floored/elided entries
+# (seed 53; the route x kind matrix is test_fault_route_matrix_raising's)
 # ---------------------------------------------------------------------------
-
-def _attr_blocks(dev, safe, qs):
-    from tests.test_routing import _attributed_blocks
-    return _attributed_blocks(dev, safe, qs, prune=True)
-
-
-@pytest.mark.parametrize("route", ROUTES)
-@pytest.mark.parametrize("kind", RAISING)
-def test_attr_collect_fault_fails_whole_flush_to_host(route, kind):
-    """Launch/transfer faults at p=1.0 during an ATTRIBUTED flush: the
-    WHOLE flush fails over to the host attribution path (same bytes —
-    the host filter applies the identical floor/elision drops), then the
-    store quarantines."""
-    store, dev, safe, entries, floor, qs = _build(seed=53)
-    dev.route_override = route
-    expect = _attr_blocks(dev, safe, qs)
-    with faults.device_fault(kind, 1.0, _rng()):
-        got = _attr_blocks(dev, safe, qs)
-    assert got == expect
-    if route == "host":
-        assert dev.n_device_faults == 0
-    else:
-        assert dev.n_device_faults >= 1
-        assert dev.n_quarantines >= 1
-        assert dev.n_fallback_queries >= len(qs)
-
 
 @pytest.mark.parametrize("route", ("device", "dense"))
 def test_attr_stale_result_detected_by_shadow(route):
@@ -406,10 +382,10 @@ def test_attr_stale_result_detected_by_shadow(route):
     and serves the host answer — bytes never change."""
     store, dev, safe, entries, floor, qs = _build(seed=53)
     dev.route_override = route
-    expect = _attr_blocks(dev, safe, qs)
+    expect = _attributed(dev, safe, qs)
     dev.paranoia = True
     with faults.device_fault("stale_result", 1.0, _rng()):
-        got = _attr_blocks(dev, safe, qs)
+        got = _attributed(dev, safe, qs)
     assert got == expect
     assert dev.n_shadow_mismatches >= 1
     assert dev.n_quarantines >= 1
@@ -421,16 +397,16 @@ def test_attr_quarantine_recovers_and_serves_device_again():
     blocks again — all byte-identical throughout."""
     store, dev, safe, entries, floor, qs = _build(seed=53)
     dev.route_override = "dense"
-    expect = _attr_blocks(dev, safe, qs)
+    expect = _attributed(dev, safe, qs)
     with faults.device_fault("transfer", 1.0, _rng()):
-        assert _attr_blocks(dev, safe, qs) == expect
+        assert _attributed(dev, safe, qs) == expect
     assert dev._dev_quar_flushes > 0
     while dev._dev_quar_flushes > 0:
-        assert _attr_blocks(dev, safe, qs) == expect
-    assert _attr_blocks(dev, safe, qs) == expect     # the probe
+        assert _attributed(dev, safe, qs) == expect
+    assert _attributed(dev, safe, qs) == expect     # the probe
     assert dev._dev_backoff == 0 and dev.n_restores >= 1
     before = dev.n_fallback_queries
-    assert _attr_blocks(dev, safe, qs) == expect     # healthy again
+    assert _attributed(dev, safe, qs) == expect     # healthy again
     assert dev.n_fallback_queries == before
 
 
@@ -548,10 +524,10 @@ def test_slice_fault_quarantines_one_slice_only(kind):
     fails over to host byte-identically, and exactly ONE slice quarantines
     — the whole-device ladder stays untouched."""
     store, dev, safe, qs = _sharded_build(seed=31)
-    expect = _attributed(dev, safe, qs, prune=True)
+    expect = _attributed(dev, safe, qs)
     quar_before = dev.n_quarantines
     with faults.device_fault(kind, 1.0, _rng()):
-        got = _attributed(dev, safe, qs, prune=True)
+        got = _attributed(dev, safe, qs)
     assert got == expect
     assert dev.n_slice_quarantines == 1
     assert dev.n_quarantines == quar_before      # no whole-device quarantine
@@ -565,23 +541,23 @@ def test_slice_quarantine_hybrid_then_probe_restore():
     flushes (masked device dispatch + host twin for the sick slice) ->
     backoff expiry -> reprobe -> restore.  Byte-identical at every step."""
     store, dev, safe, qs = _sharded_build(seed=47)
-    expect = _attributed(dev, safe, qs, prune=True)
+    expect = _attributed(dev, safe, qs)
     with faults.device_fault("transfer", 1.0, _rng()):
-        assert _attributed(dev, safe, qs, prune=True) == expect
+        assert _attributed(dev, safe, qs) == expect
     sh = dev.store_shards
     assert sh.any_quarantined()
     sharded_before = dev.n_store_sharded_flushes
     # hybrid flushes while quarantined: device route still counted, the
     # sick slice answered from the host twin
     while sh.any_quarantined():
-        assert _attributed(dev, safe, qs, prune=True) == expect
+        assert _attributed(dev, safe, qs) == expect
     assert dev.n_store_sharded_flushes > sharded_before
     # the tick that hit zero marked the slice suspect; the next healthy
     # flush is the probe and restores it
-    assert _attributed(dev, safe, qs, prune=True) == expect
+    assert _attributed(dev, safe, qs) == expect
     assert dev.n_slice_restores >= 1
     assert not any(sh.suspect)
-    assert _attributed(dev, safe, qs, prune=True) == expect
+    assert _attributed(dev, safe, qs) == expect
 
 
 @_shard_canary
@@ -589,11 +565,11 @@ def test_slice_stale_result_detected_by_shadow():
     """Silent corruption during a sliced collect: paranoia shadow-verify
     catches it and quarantines the SLICE, not the device."""
     store, dev, safe, qs = _sharded_build(seed=53)
-    expect = _attributed(dev, safe, qs, prune=True)
+    expect = _attributed(dev, safe, qs)
     dev.paranoia = True
     quar_before = dev.n_quarantines
     with faults.device_fault("stale_result", 1.0, _rng()):
-        got = _attributed(dev, safe, qs, prune=True)
+        got = _attributed(dev, safe, qs)
     assert got == expect
     assert dev.n_shadow_mismatches >= 1
     assert dev.n_slice_quarantines >= 1
@@ -601,20 +577,24 @@ def test_slice_stale_result_detected_by_shadow():
 
 
 @_shard_canary
-def test_raw_route_forced_host_under_slice_quarantine():
-    """The raw (non-attributed) CSR path has no per-entry merge point, so
-    under ANY slice quarantine the whole flush runs host — byte-identical,
-    counted as fallback, never as a sharded flush."""
+def test_flush_under_slice_quarantine_is_hybrid_not_whole_host():
+    """While a slice is quarantined a flush is HYBRID: the healthy slices
+    answer through the masked sharded dense kernel, the sick slice's slots
+    through a host twin part — byte-identical, counted as a sharded flush,
+    never as a fallback."""
     store, dev, safe, qs = _sharded_build(seed=31)
-    expect_csr = _csr(dev, qs, prune=True)
+    expect = _attributed(dev, safe, qs)
+    assert expect == _reference(dev, safe, qs)
     with faults.device_fault("transfer", 1.0, _rng()):
-        _attributed(dev, safe, qs, prune=True)
+        _attributed(dev, safe, qs)
     sh = dev.store_shards
     assert sh.any_quarantined()
     sharded_before = dev.n_store_sharded_flushes
     fallback_before = dev.n_fallback_queries
-    got_csr = _csr(dev, qs, prune=True)
-    for a, b in zip(expect_csr, got_csr):
-        np.testing.assert_array_equal(a, b)
-    assert dev.n_store_sharded_flushes == sharded_before
-    assert dev.n_fallback_queries > fallback_before
+    builders = [DepsBuilder() for _ in qs]
+    handle = dev.deps_query_batch_begin(qs, immediate=True)
+    assert [p["kind"] for p in handle[0]] == ["attr_sharded", "host_slice"]
+    dev.deps_query_batch_end_attributed(safe, handle, builders)
+    assert _unpack_builders(builders) == expect
+    assert dev.n_store_sharded_flushes == sharded_before + 1
+    assert dev.n_fallback_queries == fallback_before

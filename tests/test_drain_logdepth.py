@@ -1,8 +1,9 @@
 """r19 log-depth drain: every route vs a brute-force host oracle.
 
 The fixpoint kernels are the standing oracle for ``applied``/``newly``
-(exactly as ``_attribute_batch`` was for attribution), and a brute-force
-host Kahn/fixpoint drain is the oracle for THEM — so this sweep pins the
+(exactly as ``tests/deps_oracle.attribute_batch`` is for attribution),
+and a brute-force host Kahn/fixpoint drain is the oracle for THEM — so
+this sweep pins the
 whole route fan (dense/ELL x fixpoint/log-depth x fused/solo, plus the
 watermark prefix form and the routed ``drain_auto`` entrypoints) to one
 numpy reference over random DAGs that exercise every gate the drain

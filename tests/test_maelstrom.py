@@ -249,3 +249,53 @@ def test_runner_multi_partition_zipf_workload():
     for proc in runner.processes.values():
         toks |= set(proc.node.data_store.tokens())
     assert max(toks) > (1 << 31), "keys all collapsed into low shards"
+
+
+def test_init_warms_the_flush_the_first_preaccept_launches(monkeypatch):
+    """``init`` warms every store's device path through the PRODUCT flush
+    (deps_query_batch_begin -> deps_query_batch_end_attributed), never
+    through a program the protocol does not launch: each warm-up handle
+    is an attributed one (it carries the flush's AttrIndex, its parts are
+    host entries or ``attr_*`` kernels) and is collected by the product
+    collector."""
+    from accord_tpu.local.device_index import DeviceState
+    from accord_tpu.maelstrom.node import MaelstromProcess
+    from tests.test_store_group import _Scheduler
+
+    begun, ended = [], []
+    begin = DeviceState.deps_query_batch_begin
+    end = DeviceState.deps_query_batch_end_attributed
+
+    def spy_begin(self, queries, **kw):
+        handle = begin(self, queries, **kw)
+        begun.append((kw, handle))
+        return handle
+
+    def spy_end(self, safe, handle, builders):
+        ended.append(handle)
+        return end(self, safe, handle, builders)
+
+    monkeypatch.setattr(DeviceState, "deps_query_batch_begin", spy_begin)
+    monkeypatch.setattr(DeviceState, "deps_query_batch_end_attributed",
+                        spy_end)
+    sent = []
+    proc = MaelstromProcess(
+        emit=lambda dest, body: sent.append((dest, body)),
+        scheduler=_Scheduler(), now_micros=lambda: 0, num_stores=2,
+        device_mode=True, durability=False)
+    proc.handle({"src": "c1", "dest": "n1",
+                 "body": {"type": "init", "msg_id": 1, "node_id": "n1",
+                          "node_ids": ["n1", "n2", "n3"]}})
+    assert [b["type"] for _d, b in sent] == ["init_ok"]
+    stores = proc.node.command_stores.stores
+    assert len(stores) == 2 and all(s.device is not None for s in stores)
+    assert len(begun) == len(stores), "one warm-up flush a store"
+    for kw, handle in begun:
+        assert "prune_floors" not in kw and "attributed" not in kw
+        parts, fmeta = handle[0], handle[6]
+        assert fmeta["aidx"] is not None
+        assert parts and all("ent" in p if p["kind"] == "host"
+                             else p["kind"].startswith("attr_")
+                             for p in parts)
+    assert [id(h) for h in ended] == [id(h) for _kw, h in begun]
+    assert sum(s.device.n_queries for s in stores) == len(stores)

@@ -167,9 +167,10 @@ def _mesh_queries(rng, nq, keyspace, n):
 @pytest.mark.parametrize("prune", [False, True])
 def test_sharded_bucketed_and_pruned_match_single_device(mesh, prune):
     """The mesh-sharded bucketed kernel (row-sharded BucketTable +
-    replicated floor) and the pruned sharded dense kernel must produce the
-    SAME packed CSR as the single-device device route, bit for bit, through
-    the full dispatch/collect/dedupe stack."""
+    replicated floor) and the sharded dense kernel must build the SAME
+    Deps as the single-device device route and the reference
+    (tests/deps_oracle.py), through the full dispatch/collect/merge stack,
+    with (``prune``) and without a RedundantBefore floor in the store."""
     from accord_tpu.primitives.keys import Range as _Range, Ranges
     from accord_tpu.primitives.timestamp import TxnKind as _K
 
@@ -185,24 +186,23 @@ def test_sharded_bucketed_and_pruned_match_single_device(mesh, prune):
             TxnId.NONE
     qs = _mesh_queries(rng, 24, keyspace, 250)
 
+    from tests.conftest import DeviceTestSafe
+    from tests.test_routing import _attributed, _reference
+    safe = DeviceTestSafe(store)
+
     def run(route, mesh_on):
         dev.route_override = route
         saved = dev.mesh
         dev.mesh = mesh if mesh_on else None
         try:
-            h = dev.deps_query_batch_begin(qs, immediate=True,
-                                           prune_floors=prune)
-            return dev.deps_query_batch_end(h)
+            return _attributed(dev, safe, qs)
         finally:
             dev.mesh = saved
 
-    single = run("device", mesh_on=False)
-    sharded = run("device", mesh_on=True)
+    want = _reference(dev, safe, qs)
+    assert any(k or r for k, r in want), "no dep to compare"
+    assert run("device", mesh_on=False) == want, "single"
+    assert run("device", mesh_on=True) == want, "sharded"
     assert dev.n_mesh_bucketed_queries > 0, \
         "the sharded bucketed kernel never ran"
-    sharded_dense = run("dense", mesh_on=True)
-    for got, name in ((sharded, "sharded"), (sharded_dense,
-                                             "sharded_dense")):
-        for a, b in zip(single, got):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                          err_msg=name)
+    assert run("dense", mesh_on=True) == want, "sharded_dense"

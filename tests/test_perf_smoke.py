@@ -24,6 +24,7 @@ from accord_tpu.primitives.keys import IntKey, Keys, Range, Ranges
 from accord_tpu.primitives.timestamp import Domain, TxnId, TxnKind
 
 from tests.conftest import make_device_state
+from tests.test_routing import _attributed, _reference
 
 HOT = 128
 N = 2_000
@@ -31,7 +32,7 @@ N = 2_000
 
 def _hot_store():
     rng = np.random.default_rng(13)
-    store, dev, _safe = make_device_state()
+    store, dev, safe = make_device_state()
     hlcs = np.sort(rng.choice(np.arange(1, 20 * N), size=N, replace=False))
     floor_hlc = int(hlcs[int(N * 0.9)])
     for i in range(N):
@@ -50,7 +51,7 @@ def _hot_store():
                              TxnKind.Write, Domain.Key, 1)
         toks = [int(t) for t in rng.integers(0, HOT, rng.integers(1, 4))]
         qs.append((bound, bound, bound.kind().witnesses(), toks, []))
-    return store, dev, qs
+    return dev, safe, qs
 
 
 def test_router_picks_host_in_low_live_set_regime():
@@ -61,23 +62,18 @@ def test_router_picks_host_in_low_live_set_regime():
     DeviceState.set_route_calibration(rtt=2e-3, c_host=meas["c_host"],
                                       c_dev=meas["c_dev"])
     try:
-        store, dev, qs = _hot_store()
+        dev, safe, qs = _hot_store()
         routes = []
         dev.on_route = lambda route, nq: routes.append((route, nq))
-        handle = dev.deps_query_batch_begin(qs, immediate=True,
-                                            prune_floors=True)
-        host_out = dev.deps_query_batch_end(handle)
+        host_out = _attributed(dev, safe, qs)
         assert routes and routes[0][0] == "host", routes
         assert dev.n_host_queries == len(qs)
+        assert host_out == _reference(dev, safe, qs)
+        assert any(k for k, _r in host_out), "no dep to compare"
         # identical to the pinned device kernels on the same store
         for route in ("device", "dense"):
             dev.route_override = route
-            h = dev.deps_query_batch_begin(qs, immediate=True,
-                                           prune_floors=True)
-            got = dev.deps_query_batch_end(h)
-            for a, b in zip(host_out, got):
-                np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                              err_msg=route)
+            assert _attributed(dev, safe, qs) == host_out, route
         # route counters are disjoint and complete
         assert dev.n_host_queries + dev.n_bucketed_queries \
             + dev.n_dense_queries + dev.n_mesh_queries == dev.n_queries
@@ -95,7 +91,7 @@ def test_at_scale_shape_routes_to_device():
                                       c_dev=meas["c_dev"])
     try:
         rng = np.random.default_rng(17)
-        store, dev, _safe = make_device_state()
+        store, dev, safe = make_device_state()
         keyspace = 500_000
         hlcs = rng.choice(np.arange(1, 500_000), size=4_000, replace=False)
         for i in range(4_000):
@@ -113,8 +109,7 @@ def test_at_scale_shape_routes_to_device():
             qs.append((bound, bound, bound.kind().witnesses(), [], ivs))
         routes = []
         dev.on_route = lambda route, nq: routes.append(route)
-        dev.deps_query_batch_end(
-            dev.deps_query_batch_begin(qs, immediate=True))
+        _attributed(dev, safe, qs)
         assert routes == ["device"], routes
         assert dev.n_host_queries == 0
     finally:

@@ -1,11 +1,12 @@
 """Routing equivalence: the regime-adaptive dispatch layer must be invisible
 to the protocol.  Property-style seeded runs generate mixed point/range
 footprints over live + redundant (below-floor) + invalidated tables and
-assert that every route — host, bucketed, dense, and the mesh-sharded
-kernels — returns bit-identical packed-CSR dep sets and identical attributed
-(floors + elision + key/range attribution) builder output, with floor
-pruning on and off.  A host brute force anchors the shared answer so an
-error common to all routes cannot hide."""
+assert that every route of the ONE flush — host, bucketed, dense, and the
+mesh-sharded kernels — builds bit-identical Deps, equal to the reference
+passes of tests/deps_oracle.py (floors + elision + key/range attribution,
+with and without the batch-global floor in the candidate scan).  A host
+brute force anchors the shared answer so an error common to all routes
+and the reference cannot hide."""
 
 import numpy as np
 import pytest
@@ -15,6 +16,7 @@ from accord_tpu.primitives.deps import DepsBuilder
 from accord_tpu.primitives.keys import IntKey, Keys, Range, Ranges
 from accord_tpu.primitives.timestamp import Domain, TxnId, TxnKind
 
+from tests import deps_oracle
 from tests.conftest import make_device_state
 
 ROUTES = ("host", "device", "dense")
@@ -100,11 +102,6 @@ def _brute(entries, q, floor=None):
     return sorted(out)
 
 
-def _csr(dev, qs, prune):
-    h = dev.deps_query_batch_begin(qs, immediate=True, prune_floors=prune)
-    return dev.deps_query_batch_end(h)
-
-
 def _unpack_builders(builders):
     out = []
     for b in builders:
@@ -118,11 +115,15 @@ def _unpack_builders(builders):
     return out
 
 
-def _attributed(dev, safe, qs, prune):
-    builders = [DepsBuilder() for _ in qs]
-    h = dev.deps_query_batch_begin(qs, immediate=True, prune_floors=prune)
-    dev.deps_query_batch_end_attributed(safe, h, builders)
-    return _unpack_builders(builders)
+def _reference(dev, safe, qs, prune=True):
+    """The REFERENCE answer (tests/deps_oracle.py), unpacked."""
+    return _unpack_builders(
+        deps_oracle.reference_builders(dev, safe, qs, prune))
+
+
+def _attributed(dev, safe, qs):
+    """The product flush on the pinned route, unpacked."""
+    return _unpack_builders(deps_oracle.flush_builders(dev, safe, qs))
 
 
 def _enqueue_flush(dev, qs):
@@ -142,56 +143,48 @@ def _enqueue_flush(dev, qs):
 
 @pytest.mark.parametrize("seed", [11, 23, 47])
 def test_all_routes_bit_identical(seed):
-    """host == bucketed/dense split == dense == sharded (mesh) CSR output,
-    pruned and unpruned, on random mixed footprints — anchored by a host
-    brute force over the live entries."""
+    """host == bucketed/dense split == dense == sharded (mesh) flush output
+    on random mixed footprints — anchored by a host brute force over the
+    live entries above the floor."""
     store, dev, safe, entries, floor, qs = _build(seed)
-    from accord_tpu.ops.packing import unpack_txn_id
-    for prune in (False, True):
-        outs = {}
+    outs = {}
+    for route in ROUTES:
+        dev.route_override = route
+        outs["mesh_" + route] = deps_oracle.flush_builders(dev, safe, qs)
+    if dev.mesh is not None:    # single-device kernels as well
+        saved = dev.mesh
+        dev.mesh = None
         for route in ROUTES:
             dev.route_override = route
-            outs["mesh_" + route] = _csr(dev, qs, prune)
-        if dev.mesh is not None:    # single-device kernels as well
-            saved = dev.mesh
-            dev.mesh = None
-            for route in ROUTES:
-                dev.route_override = route
-                outs["single_" + route] = _csr(dev, qs, prune)
-            dev.mesh = saved
-        base_name = "mesh_host"
-        base = outs[base_name]
-        for name, got in outs.items():
-            for a, b in zip(base, got):
-                np.testing.assert_array_equal(
-                    np.asarray(a), np.asarray(b),
-                    err_msg=f"seed={seed} prune={prune} "
-                            f"{name} != {base_name}")
-        # anchor against brute force (dedupe route-common bugs)
-        row_ptr, msb, lsb, node = base
-        for b, q in enumerate(qs):
-            sl = slice(int(row_ptr[b]), int(row_ptr[b + 1]))
-            got = sorted(unpack_txn_id(m, l, n)
-                         for m, l, n in zip(msb[sl], lsb[sl], node[sl]))
-            want = _brute(entries, q, floor if prune else None)
-            assert got == want, f"seed={seed} prune={prune} query {b}"
+            outs["single_" + route] = deps_oracle.flush_builders(dev, safe,
+                                                                 qs)
+        dev.mesh = saved
+    base_name = "mesh_host"
+    base = _unpack_builders(outs[base_name])
+    for name, got in outs.items():
+        assert _unpack_builders(got) == base, \
+            f"seed={seed} {name} != {base_name}"
+    # anchor against brute force (dedupe route-common bugs)
+    for b, (q, got) in enumerate(zip(qs, deps_oracle.dep_ids(
+            outs[base_name]))):
+        assert got == _brute(entries, q, floor), f"seed={seed} query {b}"
 
 
-@pytest.mark.parametrize("seed", [7, 31])
-def test_all_routes_identical_attributed(seed):
-    """The protocol-complete path (floors + elision + attribution into
-    DepsBuilder) must not depend on the route either."""
+@pytest.mark.parametrize("seed", [7, 31, 11, 47])
+def test_attributed_routes_bit_identical(seed):
+    """Every route's flush — host filter, dense/bucketed in-kernel
+    attribution, mesh-merged variants — builds byte-equal Deps to the
+    reference passes (tests/deps_oracle.py), whether or not the
+    reference's candidate scan used the batch-global floor."""
     store, dev, safe, entries, floor, qs = _build(seed)
-    for prune in (False, True):
-        base = None
+    oracle = _reference(dev, safe, qs)
+    assert oracle == _reference(dev, safe, qs, prune=False)
+    for mesh in (dev.mesh, None):
+        dev.mesh = mesh
         for route in ROUTES:
             dev.route_override = route
-            got = _attributed(dev, safe, qs, prune)
-            if base is None:
-                base = got
-            else:
-                assert got == base, \
-                    f"seed={seed} prune={prune} route={route}"
+            assert _attributed(dev, safe, qs) == oracle, \
+                f"seed={seed} route={route} mesh={mesh is not None}"
 
 
 @pytest.mark.parametrize("seed_set", [(11, 23), (31, 47, 7)])
@@ -204,7 +197,7 @@ def test_fused_vs_solo_bit_identical(seed_set):
 
     from tests.conftest import make_dispatch_node
     node, stores = make_dispatch_node(seed_set, fusion=True)
-    expected = [_attributed(dev, safe, qs, True)
+    expected = [_reference(dev, safe, qs)
                 for dev, safe, qs in stores]
     for r in range(1, len(stores) + 1):
         for combo in itertools.combinations(range(len(stores)), r):
@@ -229,7 +222,7 @@ def test_fused_vs_solo_bit_identical(seed_set):
         tid = TxnId.create(1, 500_000 + i, TxnKind.Write, Domain.Key, 1)
         dev0.register(tid, int(InternalStatus.PREACCEPTED),
                       Keys([IntKey((i * 131) % 6000)]))
-    expected0 = _attributed(dev0, safe0, qs0, True)
+    expected0 = _reference(dev0, safe0, qs0)
     results = {i: _enqueue_flush(stores[i][0], stores[i][2])
                for i in range(len(stores))}
     node.scheduler.run()
@@ -251,7 +244,7 @@ def test_fused_unequal_capacities_bit_identical():
         dev.route_override = "dense"
         stores.append((dev, safe, qs))
     assert len({dev.deps.capacity for dev, _s, _q in stores}) == 2
-    expected = [_attributed(dev, safe, qs, True)
+    expected = [_reference(dev, safe, qs)
                 for dev, safe, qs in stores]
     results = [_enqueue_flush(dev, qs) for dev, _s, qs in stores]
     node.scheduler.run()
@@ -268,7 +261,7 @@ def test_fusion_off_pins_solo_launches():
     launch is solo — and results are unchanged."""
     from tests.conftest import make_dispatch_node
     node, stores = make_dispatch_node((11, 23), fusion=False)
-    expected = [_attributed(dev, safe, qs, True)
+    expected = [_reference(dev, safe, qs)
                 for dev, safe, qs in stores]
     results = [_enqueue_flush(dev, qs) for dev, _safe, qs in stores]
     node.scheduler.run()
@@ -279,56 +272,31 @@ def test_fusion_off_pins_solo_launches():
         assert _unpack_builders(builders) == expected[i]
 
 
-@pytest.mark.parametrize("seed", [11, 47])
-def test_triple_dedupe_is_identity_for_exact_kernels(seed):
-    """r10 satellite: the global triple-dedupe pass is skipped for
-    single-part exact kernels (their CSRs are unique by construction) and
-    kept for multi-part / sharded_bucketed — forcing it ON for EVERY route
-    must be byte-invisible, proving the skip drops only dead work."""
-    from accord_tpu.local.device_index import DeviceState
-    store, dev, safe, entries, floor, qs = _build(seed)
-    for prune in (False, True):
-        for route in ROUTES:
-            dev.route_override = route
-            plain = _csr(dev, qs, prune)
-            attr_plain = _attributed(dev, safe, qs, prune)
-            try:
-                DeviceState.FORCE_TRIPLE_DEDUPE = True
-                forced = _csr(dev, qs, prune)
-                attr_forced = _attributed(dev, safe, qs, prune)
-            finally:
-                DeviceState.FORCE_TRIPLE_DEDUPE = False
-            for a, b in zip(plain, forced):
-                np.testing.assert_array_equal(
-                    np.asarray(a), np.asarray(b),
-                    err_msg=f"seed={seed} route={route} prune={prune}")
-            assert attr_plain == attr_forced
-
-
 @pytest.mark.parametrize("seed", [13, 61])
 def test_exact_kernels_match_host_geometry_property(seed):
-    """r10 tentpole contract: every device kernel's emitted triples equal
-    the host ``_exact_geometry`` reference over its own pair list — on the
-    mixed point/range footprints of the routing property generator (the
-    reference is the executable spec of the emit order)."""
+    """r10 tentpole contract: the entries every device kernel ships equal
+    the reference geometry over its own pair list (with the in-kernel
+    key-domain first-column dedupe: deps_oracle.attributed_entries) — on
+    the mixed point/range footprints of the routing property generator
+    (the reference is the executable spec of the emit order)."""
     store, dev, safe, entries, floor, qs = _build(seed)
     for route in ("device", "dense"):
         for mesh in (dev.mesh, None):
             saved = dev.mesh
             dev.mesh = mesh
             dev.route_override = route
-            h = dev.deps_query_batch_begin(qs, immediate=True,
-                                           prune_floors=True)
-            b_d, j_d, (p_i, m_i, q_i), _ids, ivs, qnp, _q = \
-                dev._batch_collect(h)
+            h = dev.deps_query_batch_begin(qs, immediate=True)
+            tb, tj, tm, tq, _ids, ivs, qnp, q_m, _q = \
+                dev._batch_collect_attr(h)
             dev.mesh = saved
-            q_m = (qnp.shape[1] - 7) // 2
-            b_r, j_r, (p_r, m_r, q_r) = dev._exact_geometry(
+            b_d, j_d, _p = deps_oracle.entry_pairs(tb, tj)
+            rb, rj, rm, rq = deps_oracle.attributed_entries(
                 b_d.copy(), j_d.copy(), ivs, qnp, q_m)
-            np.testing.assert_array_equal(b_d, b_r)
-            np.testing.assert_array_equal(j_d, j_r)
-            got = set(zip(p_i.tolist(), m_i.tolist(), q_i.tolist()))
-            ref = set(zip(p_r.tolist(), m_r.tolist(), q_r.tolist()))
+            got = set(zip(tb.tolist(), tj.tolist(), tm.tolist(),
+                          tq.tolist()))
+            ref = set(zip(rb.tolist(), rj.tolist(), rm.tolist(),
+                          rq.tolist()))
+            assert len(got) == len(tb), "a kernel shipped a duplicate"
             assert got == ref, f"seed={seed} route={route} mesh={mesh}"
 
 
@@ -337,50 +305,19 @@ def test_adaptive_route_is_invisible():
     the pinned routes — the router can only change cost, never results."""
     store, dev, safe, entries, floor, qs = _build(97)
     dev.route_override = "dense"
-    want = _csr(dev, qs, True)
+    want = _attributed(dev, safe, qs)
     dev.route_override = None
-    got = _csr(dev, qs, True)
-    for a, b in zip(want, got):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert _attributed(dev, safe, qs) == want
     assert dev.n_queries == len(qs) * 2
 
 
-# -- r15: attributed-block route identity -------------------------------------
-
-def _attributed_blocks(dev, safe, qs, prune):
-    """The r15 ATTRIBUTED path (floors/elision/dedupe in-kernel, thin
-    shared finalize) — same output surface as the legacy oracle pass."""
-    builders = [DepsBuilder() for _ in qs]
-    h = dev.deps_query_batch_begin(qs, immediate=True, prune_floors=prune,
-                                   attributed=True)
-    dev.deps_query_batch_end_attributed(safe, h, builders)
-    return _unpack_builders(builders)
-
-
-@pytest.mark.parametrize("seed", [11, 47])
-def test_attributed_routes_bit_identical(seed):
-    """Every route's ATTRIBUTED blocks — host filter, dense/bucketed
-    in-kernel attribution, mesh-merged variants — build byte-equal Deps
-    to the legacy host oracle (_attribute_batch), which survives exactly
-    as _exact_geometry did in r10: as this test's reference."""
-    store, dev, safe, entries, floor, qs = _build(seed)
-    dev.route_override = "host"
-    oracle = _attributed(dev, safe, qs, prune=True)
-    for mesh in (dev.mesh, None):
-        dev.mesh = mesh
-        for route in ROUTES:
-            dev.route_override = route
-            got = _attributed_blocks(dev, safe, qs, prune=True)
-            assert got == oracle, f"route={route} mesh={mesh is not None}"
-
-
 def test_attributed_fused_matches_solo():
-    """Fused ATTRIBUTED launches (the dispatcher's coalesced path, now
-    running fused_flat_attr / sharded_fused_attr with the on-device
-    merge) build the same bytes as the solo oracle for every member."""
+    """Fused launches (the dispatcher's coalesced path, running
+    fused_flat_attr / sharded_fused_attr with the on-device merge) build
+    the same bytes as the reference for every member."""
     from tests.conftest import make_dispatch_node
     node, stores = make_dispatch_node((11, 23, 47), fusion=True)
-    oracles = [_attributed(dev, safe, qs, prune=True)
+    oracles = [_reference(dev, safe, qs)
                for dev, safe, qs in stores]
     outs = []
     for dev, _safe, qs in stores:
@@ -391,3 +328,79 @@ def test_attributed_fused_matches_solo():
     for (builders, failures), oracle in zip(outs, oracles):
         assert not failures
         assert _unpack_builders(builders) == oracle
+
+
+# -- one flush path (PR 28) ---------------------------------------------------
+
+@pytest.mark.parametrize("flag", ["prune_floors", "attributed"])
+def test_begin_refuses_the_options_of_the_deleted_raw_path(flag):
+    """The flush always prunes by the batch floor and always attributes:
+    the two keywords of the deleted raw-CSR family are accepted only as
+    True (the benchmark's store driver still passes them; ROADMAP D11)."""
+    store, dev, safe, entries, floor, qs = _build(11, n=40)
+    with pytest.raises(TypeError):
+        dev.deps_query_batch_begin(qs, immediate=True, **{flag: False})
+    builders = [DepsBuilder() for _ in qs]
+    dev.deps_query_batch_end_attributed(
+        safe, dev.deps_query_batch_begin(qs, immediate=True,
+                                         prune_floors=True,
+                                         attributed=True), builders)
+    assert _unpack_builders(builders) == _reference(dev, safe, qs)
+
+
+def _scan_entry_points():
+    """{name: module} of the public compiled SCAN entry points of the two
+    kernel modules: a jitted function, or a function that builds one
+    (``jax.jit`` in its body), over a packed query matrix (``qmat`` /
+    ``qmats``).  The un-jitted inner phases (flat_csr_local,
+    bucketed_flat, ...) are traced into these and are not entry points."""
+    import inspect
+
+    from accord_tpu.ops import deps_kernel
+    from accord_tpu.parallel import sharded
+    out = {}
+    for mod in (deps_kernel, sharded):
+        for name, obj in vars(mod).items():
+            if name.startswith("_") or not callable(obj) \
+                    or getattr(obj, "__module__", mod.__name__) \
+                    != mod.__name__:
+                continue
+            fn = getattr(obj, "__wrapped__", None)
+            jitted = fn is not None and hasattr(obj, "lower")
+            try:
+                src = inspect.getsource(fn if jitted else obj)
+            except (OSError, TypeError):
+                continue
+            if (jitted or "jax.jit(" in src) and "qmat" in src:
+                out[name] = mod.__name__
+    return out
+
+
+def test_every_scan_entry_point_has_a_launcher():
+    """ROADMAP D4 cannot regrow unseen: every public compiled scan entry
+    point of ops/deps_kernel.py and parallel/sharded.py is referenced from
+    accord_tpu/local/ (the flush, the fused launch, or — for
+    calculate_deps_flat — the route calibration that times it)."""
+    import inspect
+    import os
+    import re
+
+    import accord_tpu.local as local_pkg
+    from accord_tpu.local.device_index import DeviceState
+    found = _scan_entry_points()
+    # the rule sees the programs the flush is known to launch
+    assert set(found) >= {"calculate_deps_flat", "calculate_deps_flat_attr",
+                          "bucketed_attr_jit", "fused_flat_attr",
+                          "sharded_flat_attr", "sharded_bucketed_attr",
+                          "sharded_fused_attr"}, found
+    local_dir = os.path.dirname(local_pkg.__file__)
+    text = ""
+    for fname in sorted(os.listdir(local_dir)):
+        if fname.endswith(".py"):
+            with open(os.path.join(local_dir, fname)) as f:
+                text += f.read()
+    orphans = [f"{mod}.{name}" for name, mod in sorted(found.items())
+               if not re.search(rf"\b{name}\(", text)]
+    assert not orphans, f"scan entry points nothing launches: {orphans}"
+    assert "calculate_deps_flat(" in inspect.getsource(
+        DeviceState._measure_route_calibration)

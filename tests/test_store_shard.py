@@ -25,7 +25,7 @@ from accord_tpu.utils.random_source import RandomSource
 
 from tests.conftest import make_device_state
 from tests.proptest import case_budget, run_property
-from tests.test_routing import _attributed, _csr
+from tests.test_routing import _attributed, _reference
 from tests.test_device_faults import _register_n
 
 # under the ACCORD_TPU_STORE_SHARD=off canary run the spill rung is dormant
@@ -121,9 +121,9 @@ def _replay(case, mode):
                                  Domain.Range, 1)
             store.redundant_before.add_redundant(
                 Ranges.of(Range(-(1 << 60), 1 << 60)), floor)
-    csr = _csr(dev, case.queries, prune=True)
-    attr = _attributed(dev, safe, case.queries, prune=True)
-    return dev, safe, csr, attr
+    attr = _attributed(dev, safe, case.queries)
+    assert attr == _reference(dev, safe, case.queries), mode
+    return dev, safe, attr
 
 
 def _shrink(case):
@@ -143,18 +143,14 @@ def _shrink(case):
 
 
 def _check_case(case):
-    dev, _safe, got_csr, got_attr = _replay(case, "sharded")
+    dev, _safe, got_attr = _replay(case, "sharded")
     # a case whose floor compacted below the budget may legitimately never
     # breach again; every OTHER case must have spilled, not pinned
     assert not dev.host_pinned, "spill rung skipped: store pinned to host"
     if dev.store_shards is not None and dev.store_shards.active:
         assert dev.n_store_sharded_flushes >= 1
-    _d2, _s2, host_csr, host_attr = _replay(case, "host")
-    _d3, _s3, one_csr, one_attr = _replay(case, "single")
-    for a, b in zip(host_csr, got_csr):
-        np.testing.assert_array_equal(a, b)
-    for a, b in zip(host_csr, one_csr):
-        np.testing.assert_array_equal(a, b)
+    _d2, _s2, host_attr = _replay(case, "host")
+    _d3, _s3, one_attr = _replay(case, "single")
     assert got_attr == host_attr, "sharded attributed != host oracle"
     assert one_attr == host_attr, "single-device attributed != host oracle"
 
@@ -238,8 +234,8 @@ def test_sharded_survives_capacity_growth_waves():
         _register_n(dev, 120, hlc_base=base)
         _register_n(dev2, 120, hlc_base=base)
         base += 10_000
-        got = _attributed(dev, safe, qs, prune=True)
-        expect = _attributed(dev2, safe2, qs, prune=True)
+        got = _attributed(dev, safe, qs)
+        expect = _attributed(dev2, safe2, qs)
         assert got == expect, f"wave {wave}: sharded != single-device"
     assert dev.store_shards is not None and dev.store_shards.active
     assert dev.n_store_sharded_flushes >= 2
@@ -250,11 +246,11 @@ def test_sharded_survives_capacity_growth_waves():
 # un-terminal host_pinned (satellite): recovery back off the floor
 # ---------------------------------------------------------------------------
 def _drain_recheck(dev, safe, qs, limit=200):
-    ref = _attributed(dev, safe, qs, prune=True)
+    ref = _attributed(dev, safe, qs)
     for _ in range(limit):
         if not dev.host_pinned:
             break
-        assert _attributed(dev, safe, qs, prune=True) == ref
+        assert _attributed(dev, safe, qs) == ref
     return ref
 
 
@@ -275,7 +271,7 @@ def test_host_pin_recovers_after_budget_raise():
     assert not dev.host_pinned
     assert dev.n_oom_recovered == 1
     dev_q_before = dev.n_dense_queries + dev.n_mesh_queries
-    assert _attributed(dev, safe, qs, prune=True) == ref
+    assert _attributed(dev, safe, qs) == ref
     assert dev.n_dense_queries + dev.n_mesh_queries > dev_q_before
 
 
@@ -296,7 +292,7 @@ def test_host_pin_recovers_by_spilling_to_sharded():
     assert not dev.host_pinned
     assert dev.n_oom_recovered == 1
     assert dev.store_shards is not None and dev.store_shards.active
-    assert _attributed(dev, safe, qs, prune=True) == ref
+    assert _attributed(dev, safe, qs) == ref
     assert dev.n_store_sharded_flushes >= 1
 
 
@@ -313,7 +309,7 @@ def test_host_pin_recovery_respects_escape_hatch(monkeypatch):
     bound = TxnId.create(1, 10_000_000, TxnKind.Write, Domain.Key, 1)
     qs = [(bound, bound, bound.kind().witnesses(), [37], [])]
     for _ in range(130):                   # past the first recheck window
-        _attributed(dev, safe, qs, prune=True)
+        _attributed(dev, safe, qs)
     assert dev.host_pinned and dev.n_oom_recovered == 0
 
 

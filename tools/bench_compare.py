@@ -242,9 +242,8 @@ def main(argv=None):
         print("  store-group (info-only): "
               + "  ".join(f"{k}: {o} -> {n}" for k, o, n in sg))
     # r21 store-sharded counters: printed, not gated — the headline store
-    # never breaches its budget (all zeros there); the config-5b row's
-    # dryrun_multichip assertion is the verdict-bearing gate and fails the
-    # bench run itself on any byte drift
+    # never breaches its budget (all zeros there); the gate is
+    # tests/test_store_shard.py
     shd = [(k, old_idx.get(k), new_idx.get(k))
            for k in ("store_sharded_flushes", "slice_quarantines",
                      "slice_restores", "shard_merge_bytes", "oom_recovered")

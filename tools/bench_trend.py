@@ -94,10 +94,9 @@ INDEX_GATED = {
     "drain_logdepth_failovers": None,
     "fused_front_evictions": None,
     # r21 store-sharded counters: INFO-ONLY — the headline bench's store
-    # never breaches its budget, so these sit at 0 there; the config-5b
-    # row carries the load-bearing gate (its dryrun_multichip assertion
-    # fails the BENCH RUN itself on any byte drift).  shard_merge_bytes
-    # scales with the flush shape, quarantines with injected faults.
+    # never breaches its budget, so these sit at 0 there (the gate is
+    # tests/test_store_shard.py).  shard_merge_bytes scales with the flush
+    # shape, quarantines with injected faults.
     "store_sharded_flushes": None,
     "slice_quarantines": None,
     "slice_restores": None,

@@ -188,13 +188,10 @@ def mode_headline(args):
                        lambda: np.asarray(out_dev[0]))
         from accord_tpu.local.device_index import _fetch_entry_prefix
         phase("download(entry prefix)",
-              lambda: _fetch_entry_prefix(out_dev[1], 1, s,
-                                          int(hdr_np[0])))
+              lambda: _fetch_entry_prefix(out_dev[1], s, int(hdr_np[0])))
         res = phase("begin+collect(attributed)",
                     lambda: dev._batch_collect_attr(
-                        dev.deps_query_batch_begin(queries,
-                                                   prune_floors=True,
-                                                   attributed=True)))
+                        dev.deps_query_batch_begin(queries)))
         tb, tj, tm, tq, ids, ivs, qnp2, q_m2, qs = res
         print(f"attributed entries: {len(tj)}", file=sys.stderr)
 
@@ -218,10 +215,8 @@ def mode_headline(args):
 
 
 def mode_attr(args):
-    """The r15 ATTRIBUTED path under the lens: per-stage timing of the
-    pre-attributed collect (decode of the in-kernel floored/elided CSR)
-    and the thin shared finalize, next to the retired host oracle
-    (_attribute_batch) for an apples-to-apples of what moved on device."""
+    """The flush under the lens: per-stage timing of the collect (decode
+    of the in-kernel floored/elided CSR) and the thin shared finalize."""
     from accord_tpu.primitives.deps import DepsBuilder
 
     store, dev, safe, keyspace, m = build_headline(args.n)
@@ -231,9 +226,7 @@ def mode_attr(args):
     tb, tj, tm, tq, ids, ivs, qnp2, q_m2, _qs = \
         phase("collect(attributed)",
               lambda: dev._batch_collect_attr(
-                  dev.deps_query_batch_begin(queries, immediate=True,
-                                             prune_floors=True,
-                                             attributed=True)))
+                  dev.deps_query_batch_begin(queries, immediate=True)))
     print(f"attributed entries: {len(tj)} "
           f"(elided t={dev.n_elided_transitive} d={dev.n_elided_decided})",
           file=sys.stderr)
@@ -246,18 +239,6 @@ def mode_attr(args):
     finalize()   # warm
     phase("finalize(attributed)", finalize)
 
-    # the retired oracle, for comparison: raw collect + the host
-    # attribute re-sort the kernels replaced
-    res = dev._batch_collect(dev.deps_query_batch_begin(queries))
-    b_idx, j_idx, overlap, ids0, ivs0, qnp0, qs0 = res
-
-    def oracle():
-        builders = [DepsBuilder() for _ in queries]
-        dev._attribute_batch(safe, b_idx, j_idx, overlap, ids0, ivs0,
-                             qnp0, qs0, builders)
-
-    oracle()   # warm
-    phase("oracle(_attribute_batch)", oracle)
     maybe_cprofile(args.cprofile, finalize, top=args.top or 25,
                    sort="cumulative")
     print_index(dev)
@@ -278,8 +259,7 @@ def mode_hot(args):
     with maybe_trace(args.trace):
         for bi, batch in enumerate(batches):
             t0 = time.time()
-            handle = dev.deps_query_batch_begin(batch, prune_floors=True,
-                                                attributed=True)
+            handle = dev.deps_query_batch_begin(batch)
             t1 = time.time()
             builders = [DepsBuilder() for _ in batch]
             dev.deps_query_batch_end_attributed(safe, handle, builders)
@@ -292,8 +272,7 @@ def mode_hot(args):
 
         def one():
             builders = [DepsBuilder() for _ in batches[0]]
-            h = dev.deps_query_batch_begin(batches[0], prune_floors=True,
-                                            attributed=True)
+            h = dev.deps_query_batch_begin(batches[0])
             dev.deps_query_batch_end_attributed(safe, h, builders)
 
         maybe_cprofile(args.cprofile, one, top=10)
