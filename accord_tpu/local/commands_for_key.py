@@ -38,6 +38,8 @@ import bisect
 import enum
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
+import numpy as np
+
 from ..primitives.timestamp import Kinds, Timestamp, TxnId
 from ..utils import invariants
 
@@ -100,7 +102,7 @@ class CommandsForKey:
 
     __slots__ = ("token", "_ids", "_infos", "prune_before",
                  "_committed_write_execs", "_n_unwitnessable",
-                 "_elide_version", "_packed_cw")
+                 "_elide_version", "_packed_cw", "_elide_sink")
 
     def __init__(self, token: int):
         self.token = token
@@ -124,34 +126,64 @@ class CommandsForKey:
         # executeAt moving (r14 find) keeps the length while changing the
         # pivot content
         self._elide_version = 0
-        self._packed_cw = None   # (_elide_version, (msb, lsb, node) i64/i32)
+        self._packed_cw = None   # (msb, lsb int64; node int32) of the list
+        # the dirty-token set of the DeviceState that indexes this key's
+        # pivots (device_index.DeviceState._attr_index attaches it when it
+        # first reads the key): every pivot mutation marks the token there
+        self._elide_sink = None
 
-    def _cw_mutated(self) -> None:
+    def _cw_mutated(self, packed=None) -> None:
+        """EVERY content mutation of _committed_write_execs ends here: the
+        packed cache follows (``packed``) or drops, and the token is marked
+        dirty in the attached attribution index — a mutation that skipped
+        this would leave a stale pivot list eliding live deps."""
         self._elide_version += 1
-        self._packed_cw = None
+        self._packed_cw = packed
+        if self._elide_sink is not None:
+            self._elide_sink.add(self.token)
+
+    def _cw_add(self, ts: Timestamp) -> None:
+        """Insert one pivot, in order; the packed cache follows by one
+        spliced COPY (a pack handed out is never written), so a hot key's
+        commit does not re-pack its whole list."""
+        i = bisect.bisect_right(self._committed_write_execs, ts)
+        self._committed_write_execs.insert(i, ts)
+        packed = self._packed_cw
+        if packed is not None:
+            from ..ops.packing import to_i64
+            packed = tuple(
+                np.concatenate([col[:i], (v,), col[i:]], dtype=col.dtype)
+                for col, v in zip(packed, (to_i64(ts.msb), to_i64(ts.lsb),
+                                           ts.node)))
+        self._cw_mutated(packed)
+
+    def _cw_drop(self, ts: Timestamp) -> None:
+        """Retract one pivot if the list holds it."""
+        i = bisect.bisect_left(self._committed_write_execs, ts)
+        if i < len(self._committed_write_execs) \
+                and self._committed_write_execs[i] == ts:
+            del self._committed_write_execs[i]
+            packed = self._packed_cw
+            if packed is not None:
+                packed = tuple(np.concatenate([col[:i], col[i + 1:]])
+                               for col in packed)
+            self._cw_mutated(packed)
 
     def packed_committed_execs(self):
         """The elision pivot list as three numpy columns (msb, lsb int64;
         node int32), ascending in the SAME order the Timestamp objects
         sort (unsigned on the packed words) — the per-key building block
-        of the batched elision index (device_index._attr_elide_index).
-        Cached per _elide_version; rebuild is O(n) over a per-key list."""
-        import numpy as np
-
+        of the batched elision index (device_index.DeviceState._attr_index).
+        Cached until the list mutates (_cw_add / _cw_drop keep the cache in
+        step); the arrays are never written after they were handed out."""
         from ..ops.packing import to_i64
-        hit = self._packed_cw
-        if hit is not None and hit[0] == self._elide_version:
-            return hit[1]
-        n = len(self._committed_write_execs)
-        m = np.empty(n, np.int64)
-        l = np.empty(n, np.int64)
-        nd = np.empty(n, np.int32)
-        for i, ts in enumerate(self._committed_write_execs):
-            m[i] = to_i64(ts.msb)
-            l[i] = to_i64(ts.lsb)
-            nd[i] = ts.node
-        packed = (m, l, nd)
-        self._packed_cw = (self._elide_version, packed)
+        packed = self._packed_cw
+        if packed is None:
+            execs = self._committed_write_execs
+            packed = self._packed_cw = (
+                np.array([to_i64(ts.msb) for ts in execs], np.int64),
+                np.array([to_i64(ts.lsb) for ts in execs], np.int64),
+                np.array([ts.node for ts in execs], np.int32))
         return packed
 
     # -- update path --------------------------------------------------------
@@ -175,8 +207,7 @@ class CommandsForKey:
                 self._n_unwitnessable += 1
             if InternalStatus.COMMITTED <= status <= InternalStatus.APPLIED \
                     and txn_id.kind().is_write():
-                bisect.insort(self._committed_write_execs, info.execute_at)
-                self._cw_mutated()
+                self._cw_add(info.execute_at)
         else:
             prev = info.status
             info.status = max(info.status, status)   # never regress
@@ -201,13 +232,8 @@ class CommandsForKey:
                     # _committed_write_execs and never inserted the new one —
                     # elision then pivots on a ghost timestamp.  Keep the
                     # pivot list in lockstep with the executeAt it indexes.
-                    i = bisect.bisect_left(self._committed_write_execs,
-                                           info.execute_at)
-                    if i < len(self._committed_write_execs) \
-                            and self._committed_write_execs[i] == info.execute_at:
-                        del self._committed_write_execs[i]
-                    bisect.insort(self._committed_write_execs, execute_at)
-                    self._cw_mutated()
+                    self._cw_drop(info.execute_at)
+                    self._cw_add(execute_at)
                 info.execute_at = execute_at
             if info.status is InternalStatus.INVALIDATED \
                     and InternalStatus.COMMITTED <= prev <= InternalStatus.APPLIED \
@@ -215,12 +241,7 @@ class CommandsForKey:
                 # illegal in a healthy run (commit_invalidate guards it) but
                 # a stale pivot from an invalidated write must never elide
                 # genuinely-live deps
-                i = bisect.bisect_left(self._committed_write_execs,
-                                       info.execute_at)
-                if i < len(self._committed_write_execs) \
-                        and self._committed_write_execs[i] == info.execute_at:
-                    del self._committed_write_execs[i]
-                    self._cw_mutated()
+                self._cw_drop(info.execute_at)
             if prev < InternalStatus.COMMITTED and (
                     info.status >= InternalStatus.COMMITTED):
                 # decided: elide from every missing array — recovery of a
@@ -229,8 +250,7 @@ class CommandsForKey:
                 self._elide_from_missing(txn_id)
                 if info.status is not InternalStatus.INVALIDATED \
                         and txn_id.kind().is_write():
-                    bisect.insort(self._committed_write_execs, info.execute_at)
-                    self._cw_mutated()
+                    self._cw_add(info.execute_at)
         if witnessed_deps is not None:
             # (re)freeze: a higher-ballot accept or the commit may carry a
             # different proposal — last-wins, recomputed vs the collection
@@ -317,12 +337,7 @@ class CommandsForKey:
                 # Retract it with the entry: conservative (more deps
                 # scanned), and the pivot list's invariant becomes simply
                 # "the decided writes present in the index".
-                i = bisect.bisect_left(self._committed_write_execs,
-                                       info.execute_at)
-                if i < len(self._committed_write_execs) \
-                        and self._committed_write_execs[i] == info.execute_at:
-                    del self._committed_write_execs[i]
-                    self._cw_mutated()
+                self._cw_drop(info.execute_at)
             del self._infos[txn_id]
             i = bisect.bisect_left(self._ids, txn_id)
             if i < len(self._ids) and self._ids[i] == txn_id:

@@ -1519,17 +1519,17 @@ def _changed(cols, order) -> np.ndarray:
 
 def _ts_byte_keys(msb, lsb, node) -> np.ndarray:
     """Pack (msb int64, lsb int64, node int32) columns into V20 byte keys
-    whose memcmp order IS the unsigned timestamp order (ts_lt): sign bits
-    flipped, big-endian.  One np.searchsorted over these keys replaces a
-    three-level lexicographic refinement — the host half of the in-kernel
-    rank trick (the device compares precomputed integer RANKS instead)."""
+    whose memcmp order IS the timestamp order (ts_lt): UNSIGNED on the two
+    packed words (their bits as they are, big-endian), then the node id
+    (signed: its sign bit flipped).  One np.searchsorted over these keys
+    replaces a three-level lexicographic refinement — the host half of
+    the in-kernel rank trick (the device compares precomputed integer
+    RANKS instead)."""
     n = len(msb)
     out = np.empty((n, 20), np.uint8)
-    out[:, 0:8] = (np.asarray(msb, np.int64).astype(np.uint64)
-                   ^ np.uint64(1 << 63)).astype(">u8")[:, None] \
+    out[:, 0:8] = np.asarray(msb, np.int64).astype(">u8")[:, None] \
         .view(np.uint8).reshape(n, 8)
-    out[:, 8:16] = (np.asarray(lsb, np.int64).astype(np.uint64)
-                    ^ np.uint64(1 << 63)).astype(">u8")[:, None] \
+    out[:, 8:16] = np.asarray(lsb, np.int64).astype(">u8")[:, None] \
         .view(np.uint8).reshape(n, 8)
     out[:, 16:20] = (np.asarray(node, np.int64).astype(np.int64)
                      .astype(np.uint32, casting="unsafe")
@@ -1559,75 +1559,125 @@ def _exact_ranks(sorted_unique: np.ndarray, keys: np.ndarray) -> np.ndarray:
 
 
 class _AttrIndexHost:
-    """One store's floor + elision index, host side: the numpy arrays the
-    host route's vectorized attribution reads directly, plus pow2-padded
-    copies that upload as ops.deps_kernel.AttrIndex (padding bounds the
-    jit shape count).  Built by DeviceState._attr_index from the
-    RedundantBefore segment map and the CFK committed-write pivot lists of
-    every registry token; cached until either source's version moves."""
+    """One store's floor + elision index as it stood at ONE flush's begin:
+    the packed RedundantBefore segment floors, and the committed-write
+    pivot list (CommandsForKey.packed_committed_execs) of every token that
+    has one, by token.  DeviceState._attr_index MAINTAINS it: a token is
+    marked dirty by the store's own notification (DeviceState.
+    _advance_status, a decided key-domain write on a point) and by every
+    CommandsForKey pivot mutation (CommandsForKey._cw_mutated, through the
+    sink _attr_index attaches); a flush re-reads the dirty tokens' lists
+    and nothing else, and hands out a NEW index that shares every
+    untouched list with its predecessor — an index is never written after
+    it was handed out, so a deferred collect or a fused harvest reads the
+    begin-time state whatever was committed since.
 
-    __slots__ = ("fbnd", "fmsb", "flsb", "fnode", "etok", "eptr",
-                 "erank", "exm", "exl", "exn", "uqkeys", "u",
-                 "pad", "_dev", "_repl", "_repl_key", "seq")
+    The host route's readers (floors_match, keep_floor, elide_decided)
+    answer from the floors and from the lists of the tokens the batch's
+    entries name, comparing the 128-bit executeAt triples directly.  What
+    only a device route needs — dense ranks over the unique executeAts of
+    ALL tokens, the concatenated CSR, the pow2-padded arrays that upload
+    as ops.deps_kernel.AttrIndex (padding bounds the jit shape count) — is
+    assembled from the same lists when a device route first asks (pad,
+    rank_bounds, device, device_replicated) and kept with the index.  The
+    rank compare is an order isomorphism of the direct compare, so every
+    route elides the same entries."""
+
+    __slots__ = ("fbnd", "fmsb", "flsb", "fnode", "rb_version", "toks",
+                 "packs", "n_execs", "seq", "_owner", "_image", "_dev",
+                 "_repl", "_repl_key")
 
     _SEQ = [0]
 
-    def __init__(self, floors, etok, eptr, exm, exl, exn):
+    def __init__(self, owner, floors, rb_version, toks, packs, n_execs):
         # monotone build id: cache keys over index IDENTITY must never
         # use id() (a rebuilt index can reuse a freed predecessor's
         # address and alias a stale cache entry)
         _AttrIndexHost._SEQ[0] += 1
         self.seq = _AttrIndexHost._SEQ[0]
+        self._owner = owner
         self.fbnd, self.fmsb, self.flsb, self.fnode = floors
-        self.etok = etok
-        self.eptr = eptr
-        self.exm, self.exl, self.exn = exm, exl, exn
-        # dense ranks over the UNIQUE exec triples: exec < bound compares
-        # become integer rank compares on device
+        self.rb_version = rb_version
+        # sorted tokens with a non-empty pivot list; packs[i] is toks[i]'s
+        # (msb, lsb int64; node int32) columns, ascending — the CFK's own
+        # cached arrays, never written in place
+        self.toks = toks
+        self.packs = packs
+        self.n_execs = n_execs
+        self._image = None
+        self._dev = None
+        self._repl = None
+        self._repl_key = None
+
+    @property
+    def floors(self):
+        return self.fbnd, self.fmsb, self.flsb, self.fnode
+
+    # -- the device image: assembled when a device route asks -------------
+    def _device_image(self):
+        """(uqkeys, pad): the UNIQUE exec triples, sorted — dense ranks
+        over them turn exec < bound compares into integer rank compares on
+        device — and the pow2-padded arrays (floors pad +INF / zero rows;
+        elidable tokens pad +INF; padded eptr segments are empty)."""
+        if self._image is not None:
+            return self._image
+        self._owner.n_attr_device_builds += 1
+        etok, packs = self.toks, self.packs
+        eptr = np.zeros(len(packs) + 1, np.int32)
+        if packs:
+            np.cumsum([len(p[0]) for p in packs], out=eptr[1:])
+            exm = np.concatenate([p[0] for p in packs])
+            exl = np.concatenate([p[1] for p in packs])
+            exn = np.concatenate([p[2] for p in packs])
+        else:
+            exm = np.zeros(0, np.int64)
+            exl = np.zeros(0, np.int64)
+            exn = np.zeros(0, np.int32)
         keys = _ts_byte_keys(exm, exl, exn)
-        self.uqkeys = np.unique(keys)
-        self.u = len(self.uqkeys)
-        rank = np.searchsorted(self.uqkeys, keys).astype(np.int64)
+        uqkeys = np.unique(keys)
+        u = len(uqkeys)
+        rank = np.searchsorted(uqkeys, keys).astype(np.int64)
         seg = np.repeat(np.arange(len(etok), dtype=np.int64),
                         np.diff(eptr))
-        self.erank = seg * np.int64(self.u + 1) + rank
-        # pow2-padded device images (floors pad +INF / zero rows; elidable
-        # tokens pad +INF; padded eptr segments are empty)
+        erank = seg * np.int64(u + 1) + rank
         fp = _pow2_at_least(max(len(self.fbnd), 1), 1)
         tp = _pow2_at_least(max(len(etok), 1), 1)
-        lp = _pow2_at_least(max(len(self.erank), 1), 1)
-        l_real = len(self.erank)
+        lp = _pow2_at_least(max(len(erank), 1), 1)
+        l_real = len(erank)
 
         def tail(a, n, fill, dtype):
             out = np.full(n, fill, dtype)
             out[: len(a)] = a
             return out
 
-        self.pad = (
+        pad = (
             tail(self.fbnd, fp, _I64_INF, np.int64),
             tail(self.fmsb, fp + 1, 0, np.int64),
             tail(self.flsb, fp + 1, 0, np.int64),
             tail(self.fnode, fp + 1, 0, np.int32),
             tail(etok, tp, _I64_INF, np.int64),
             tail(eptr, tp + 1, l_real, np.int32),
-            tail(self.erank, lp, _I64_INF, np.int64),
+            tail(erank, lp, _I64_INF, np.int64),
             tail(exm, lp, 0, np.int64),
             tail(exl, lp, 0, np.int64),
             tail(exn, lp, 0, np.int32),
-            np.int64(self.u + 1))
-        self._dev = None
-        self._repl = None
-        self._repl_key = None
+            np.int64(u + 1))
+        self._image = (uqkeys, pad)
+        return self._image
+
+    @property
+    def pad(self):
+        return self._device_image()[1]
 
     def rank_bounds(self, qnp: np.ndarray) -> np.ndarray:
         """Per-query rank of the started-before bound among the index's
         unique committed-write executeAts — the ``rankb`` column the
-        kernels (and the host route) compare in place of 128-bit
-        timestamps."""
-        if self.u == 0:
+        kernels compare in place of 128-bit timestamps."""
+        if self.n_execs == 0:
             return np.zeros(qnp.shape[0], np.int64)
+        uqkeys = self._device_image()[0]
         keys = _ts_byte_keys(qnp[:, 0], qnp[:, 1], qnp[:, 2])
-        return np.searchsorted(self.uqkeys, keys).astype(np.int64)
+        return np.searchsorted(uqkeys, keys).astype(np.int64)
 
     def device(self) -> "dk.AttrIndex":
         if self._dev is None:
@@ -1685,32 +1735,66 @@ class _AttrIndexHost:
         return bool((fm == t[0]).all() and (fl == t[1]).all()
                     and (fn == t[2]).all())
 
-    def elide_decided(self, tok, emsb, elsb, enode, rankb_b) -> np.ndarray:
+    def elide_decided(self, tok, emsb, elsb, enode, tb, qnp) -> np.ndarray:
         """Per-entry decided-elision mask for candidates ALREADY known to
         be decided (Committed..Applied with executeAt): does a committed
         write on the token execute strictly between the dep and the
-        bound?  The pivot search collapses to the UNIQUE (segment, bound
-        rank) composites — the hot regime has a handful of hot tokens and
-        bounds against tens of thousands of entries."""
-        t = len(self.etok)
-        seg = np.searchsorted(self.etok, tok)
-        seg_c = np.minimum(seg, t - 1)
-        seg_ok = self.etok[seg_c] == tok
-        c = seg_c.astype(np.int64) * np.int64(self.u + 1) + rankb_b
-        uc, inv = np.unique(c, return_inverse=True)
-        base_u = self.eptr[np.minimum(uc // np.int64(self.u + 1),
-                                      t - 1)].astype(np.int64)
-        cnt_u = np.searchsorted(self.erank, uc) - base_u
-        pidx_u = np.clip(base_u + cnt_u - 1, 0, max(len(self.exm) - 1, 0))
-        pm = self.exm[pidx_u][inv]
-        pl = self.exl[pidx_u][inv]
-        pn = self.exn[pidx_u][inv]
-        uem, upm = emsb.view(np.uint64), pm.view(np.uint64)
-        uel, upl = elsb.view(np.uint64), pl.view(np.uint64)
-        below = ((uem < upm) | ((uem == upm)
-                               & ((uel < upl)
-                                  | ((uel == upl) & (enode < pn)))))
-        return seg_ok & (cnt_u[inv] > 0) & below
+        bound of the entry's query (``qnp[tb]``)?  Reads only the lists of
+        the tokens the entries name; the pivot search — a lower bound of
+        the query's bound inside the token's ascending list — collapses to
+        the UNIQUE (token, query) pairs and runs for all of them at once
+        (the hot regime has a handful of hot tokens and bounds against
+        tens of thousands of entries)."""
+        out = np.zeros(len(tok), bool)
+        if self.n_execs == 0:
+            return out
+        at = np.minimum(np.searchsorted(self.toks, tok), len(self.toks) - 1)
+        ent = np.nonzero(self.toks[at] == tok)[0]
+        if not len(ent):
+            return out
+        nq = np.int64(qnp.shape[0])
+        uc, cinv = np.unique(at[ent] * nq + tb[ent], return_inverse=True)
+        tix, qb = uc // nq, uc % nq
+        # the touched tokens' lists, concatenated: the pairs are token-
+        # major, so pair i searches segment seg[i] of ``ptr``
+        first = np.ones(len(uc), bool)
+        first[1:] = tix[1:] != tix[:-1]
+        seg = np.cumsum(first) - 1
+        live = [self.packs[i] for i in tix[first].tolist()]
+        ptr = np.zeros(len(live) + 1, np.int64)
+        np.cumsum([len(p[0]) for p in live], out=ptr[1:])
+        xm = np.concatenate([p[0] for p in live]).view(np.uint64)
+        xl = np.concatenate([p[1] for p in live]).view(np.uint64)
+        xn = np.concatenate([p[2] for p in live])
+        bm = qnp[qb, 0].view(np.uint64)
+        bl = qnp[qb, 1].view(np.uint64)
+        bn = qnp[qb, 2]
+        base = ptr[seg]
+        lo, hi = base, ptr[seg + 1]
+        # binary search, all pairs at once; the first probe is the list's
+        # LAST pivot (a query's bound mostly lies above every pivot, and
+        # the search is then over)
+        probe = hi - 1
+        while True:
+            open_ = lo < hi
+            if not open_.any():
+                break
+            km, kl = xm[probe], xl[probe]
+            lt = open_ & ((km < bm) | ((km == bm) & (
+                (kl < bl) | ((kl == bl) & (xn[probe] < bn)))))
+            lo = np.where(lt, probe + 1, lo)
+            hi = np.where(open_ & ~lt, probe, hi)
+            probe = (lo + hi) >> 1
+            np.minimum(probe, len(xm) - 1, out=probe)
+        cnt = (lo - base)[cinv]
+        piv = np.maximum(lo - 1, 0)[cinv]
+        pm, pl, pn = xm[piv], xl[piv], xn[piv]
+        uem, uel = emsb[ent].view(np.uint64), elsb[ent].view(np.uint64)
+        below = ((uem < pm) | ((uem == pm)
+                              & ((uel < pl)
+                                 | ((uel == pl) & (enode[ent] < pn)))))
+        out[ent] = (cnt > 0) & below
+        return out
 
 
 class DeviceState:
@@ -1774,15 +1858,18 @@ class DeviceState:
         # floor with one dict hit instead of a segment walk
         self._floor_memo: Optional[tuple] = None
         # -- device-resident attribution (r15) --
-        # elision registry: tokens that ever carried a decided key-domain
-        # write (maintained by _advance_status); the batched elision index
-        # is built over exactly these tokens from the CFK truth
-        self._elide_pending: Set[int] = set()
-        self._elide_tokens = np.zeros(0, np.int64)
-        # cached elision/floor index: (signature, _AttrIndexHost)
-        self._aidx_cache = None
-        self._aidx_dev = None       # (np id of host index, dk.AttrIndex)
-        self._aidx_repl = None      # replicated under a mesh
+        # the attribution index (_attr_index) and the tokens whose pivot
+        # list it must re-read at the next flush: _advance_status marks a
+        # point token when a decided key-domain write is driven on it,
+        # and each CommandsForKey the index has read marks its own token
+        # on every pivot mutation (this very set is its _elide_sink)
+        self._attr_dirty: Set[int] = set()
+        self._aidx: Optional[_AttrIndexHost] = None
+        # flushes that found a dirty token, the tokens they re-read, and
+        # the times a device route had the device image assembled
+        self.n_attr_refreshes = 0
+        self.n_attr_tokens_refreshed = 0
+        self.n_attr_device_builds = 0
         # attributed-path counters (bench ``# index:`` line)
         self.n_elided_transitive = 0
         self.n_elided_decided = 0
@@ -1908,19 +1995,19 @@ class DeviceState:
             self.deps.enode[slot] = execute_at.node
             self.deps.eknown[slot] = True
             self.deps.mark_exec(slot)    # device attr columns + snapshot
-        # elision registry (r15): a decided (executeAt-known) key-domain
+        # attribution index (r15): a decided (executeAt-known) key-domain
         # WRITE is a potential elision pivot on each of its footprint
-        # points — record the tokens so the batched elision index knows
-        # which CommandsForKey pivot lists to include.  Superset semantics:
-        # the index build reads the CFK truth per token; a token registered
-        # here whose CFK has no committed writes simply contributes nothing
+        # points — mark the tokens dirty so the next flush reads their
+        # CommandsForKey pivot lists into the index.  Superset semantics:
+        # the refresh reads the CFK truth per token; a token marked here
+        # whose CFK has no committed writes simply contributes nothing
         if dk.SLOT_COMMITTED <= new <= dk.SLOT_APPLIED \
                 and self.deps.eknown[slot] and txn_id.kind().is_write() \
                 and txn_id.domain() == Domain.Key:
             row_lo, row_hi = self.deps.lo[slot], self.deps.hi[slot]
             pts = row_lo[(row_lo <= row_hi) & (row_lo == row_hi)]
             if len(pts):
-                self._elide_pending.update(int(t) for t in pts)
+                self._attr_dirty.update(pts.tolist())
         if new == dk.SLOT_INVALIDATED and cur != dk.SLOT_INVALIDATED:
             # de-index: the bucket path excludes invalidated entries
             # structurally (the dense path excludes them by status)
@@ -2571,64 +2658,107 @@ class DeviceState:
     # index every attributed route (kernels AND host) applies
     # ------------------------------------------------------------------
     def _attr_index(self) -> _AttrIndexHost:
-        """Build (or reuse) the store's attribution index: the packed
-        RedundantBefore segment floors plus, per elision-registry token,
-        the CFK committed-write pivot list.  The signature folds the
-        RedundantBefore version, the registry size and the SUM of the
-        touched CFKs' monotone _elide_versions — any pivot mutation moves
-        the sum, so staleness detection is one pass of dict hits, no
-        content hashing."""
-        d = self.deps
-        if self._elide_pending:
-            new = np.fromiter(self._elide_pending, np.int64,
-                              len(self._elide_pending))
-            self._elide_pending.clear()
-            self._elide_tokens = np.union1d(self._elide_tokens, new)
+        """The store's attribution index as of now: the packed
+        RedundantBefore segment floors plus, per token, the CFK
+        committed-write pivot list (_AttrIndexHost).  Maintained, not
+        rebuilt: a flush with no dirty token and an unmoved
+        RedundantBefore.version hands out the index it handed out last
+        time; otherwise the dirty tokens' lists are re-read from their
+        CommandsForKey (whose pivot mutations mark the token from then
+        on) into a new index sharing everything else.  Who marks a token:
+        _advance_status (the store drives a decided key-domain write on
+        the point) and CommandsForKey._cw_mutated (every content change
+        of the pivot list).  A marked token whose CommandsForKey the
+        store does not hold yet stays dirty until it does.  The device
+        image is no part of this: _AttrIndexHost assembles it when a
+        device route asks."""
+        import time as _time
+        _t0 = _time.perf_counter()
         rb = getattr(self.store, "redundant_before", None)
+        rb_version = rb.version if rb is not None else -1
+        cur = self._aidx
+        if cur is None or cur.rb_version != rb_version:
+            if rb is not None:
+                floors = rb.packed_floor_index()
+            else:
+                floors = (np.zeros(0, np.int64), np.zeros(1, np.int64),
+                          np.zeros(1, np.int64), np.zeros(1, np.int32))
+            lists = (np.zeros(0, np.int64), [], 0) if cur is None \
+                else (cur.toks, cur.packs, cur.n_execs)
+            cur = self._aidx = _AttrIndexHost(self, floors, rb_version,
+                                              *lists)
+        if self._attr_dirty:
+            cur = self._aidx = self._attr_refresh(cur)
+        self._ktime("host_attr_index", _t0)
+        return cur
+
+    @property
+    def n_attr_tokens(self) -> int:
+        """Tokens the attribution index holds a pivot list for."""
+        return 0 if self._aidx is None else len(self._aidx.toks)
+
+    def _attr_refresh(self, cur: _AttrIndexHost) -> _AttrIndexHost:
+        """Re-read the dirty tokens' pivot lists: ``cur`` itself when none
+        of them moved (a token is marked again at Stable and Applied, its
+        list as it was), else a new index that shares every other list
+        with ``cur`` — O(dirty) reads, whatever the index holds."""
+        dirty = self._attr_dirty
+        self.n_attr_refreshes += 1
+        self.n_attr_tokens_refreshed += len(dirty)
         cfk_map = getattr(self.store, "commands_for_key", None) or {}
-        toks = self._elide_tokens
-        cfks = [cfk_map.get(int(t)) for t in toks]
-        vsum = 0
-        for c in cfks:
-            if c is not None:
-                vsum += c._elide_version
-        sig = (rb.version if rb is not None else -1, len(toks), vsum)
-        if self._aidx_cache is not None and self._aidx_cache[0] == sig:
-            return self._aidx_cache[1]
-        if rb is not None:
-            floors = rb.packed_floor_index()
-        else:
-            floors = (np.zeros(0, np.int64), np.zeros(1, np.int64),
-                      np.zeros(1, np.int64), np.zeros(1, np.int32))
-        packs = []
-        keep_toks = []
-        for t, c in zip(toks.tolist(), cfks):
+        toks, packs, n_execs = cur.toks, cur.packs, cur.n_execs
+        marked = sorted(dirty)
+        at = np.searchsorted(toks, marked)
+        held = np.zeros(len(marked), bool)
+        if len(toks):
+            held = toks[np.minimum(at, len(toks) - 1)] == marked
+        dirty.clear()
+        moved = False
+        left, joined = [], {}
+        for t, i, h in zip(marked, at.tolist(), held.tolist()):
+            c = cfk_map.get(t)
+            p = None
             if c is None:
-                continue
-            p = c.packed_committed_execs()
-            if len(p[0]):
-                packs.append(p)
-                keep_toks.append(t)
-        if packs:
-            etok = np.asarray(keep_toks, np.int64)
-            lens = np.array([len(p[0]) for p in packs], np.int64)
-            eptr = np.zeros(len(packs) + 1, np.int32)
-            np.cumsum(lens, out=eptr[1:])
-            exm = np.concatenate([p[0] for p in packs])
-            exl = np.concatenate([p[1] for p in packs])
-            exn = np.concatenate([p[2] for p in packs])
-        else:
-            etok = np.zeros(0, np.int64)
-            eptr = np.zeros(1, np.int32)
-            exm = np.zeros(0, np.int64)
-            exl = np.zeros(0, np.int64)
-            exn = np.zeros(0, np.int32)
-        aidx = _AttrIndexHost(floors, etok, eptr, exm, exl, exn)
-        self._aidx_cache = (sig, aidx)
-        return aidx
+                dirty.add(t)          # asked again at the next flush
+            else:
+                c._elide_sink = dirty
+                p = c.packed_committed_execs()
+                if not len(p[0]):
+                    p = None
+            if not h:
+                if p is not None:
+                    joined[t] = p
+            elif packs[i] is not p:
+                if not moved:
+                    packs = list(packs)
+                    moved = True
+                n_execs -= len(packs[i][0])
+                if p is None:
+                    left.append(i)
+                else:
+                    packs[i] = p
+                    n_execs += len(p[0])
+        if left:
+            toks = np.delete(toks, left)
+            for i in reversed(left):
+                del packs[i]
+        if joined:
+            new = np.fromiter(joined, np.int64, len(joined))
+            at = np.searchsorted(toks, new)
+            toks = np.sort(np.concatenate([toks, new]))
+            if not moved:
+                packs = list(packs)
+                moved = True
+            for k, (i, p) in enumerate(zip(at.tolist(), joined.values())):
+                packs.insert(i + k, p)
+                n_execs += len(p[0])
+        if not moved:
+            return cur
+        return _AttrIndexHost(self, cur.floors, cur.rb_version, toks, packs,
+                              n_execs)
 
     def _attr_filter_entries(self, tb, tj, tm, tq, ids, ivs, aidx,
-                             rankb, floor_skip: bool = False) -> tuple:
+                             qnp, floor_skip: bool = False) -> tuple:
         """Apply the attributed kernels' in-kernel drops to a HOST-derived
         entry set (host route, fault fallback, shadow verify): per-token
         floors + elision on key-domain entries, over the flush's snapshot
@@ -2655,7 +2785,7 @@ class DeviceState:
             keep_floor = aidx.keep_floor(tok, msb_a[tj], lsb_a[tj],
                                          node_a[tj])
         el_dec = np.zeros(len(tj), bool)
-        if aidx.u:
+        if aidx.n_execs:
             dec = (key_dep & (status >= dk.SLOT_COMMITTED)
                    & (status <= dk.SLOT_APPLIED) & xk_a[tj])
             di = np.nonzero(dec)[0]
@@ -2663,7 +2793,7 @@ class DeviceState:
                 tji = tj[di]
                 tok_d = tok[di] if tok is not None else lo[tji, tm[di]]
                 el_dec[di] = aidx.elide_decided(
-                    tok_d, xm_a[tji], xl_a[tji], xn_a[tji], rankb[tb[di]])
+                    tok_d, xm_a[tji], xl_a[tji], xn_a[tji], tb[di], qnp)
         if keep_floor is None:
             keep = ~(el_trans | el_dec)
             n_trans = int(el_trans.sum())
@@ -2724,7 +2854,10 @@ class DeviceState:
             prune = (jnp.asarray(prune_np[0]), jnp.asarray(prune_np[1]),
                      jnp.asarray(prune_np[2]))
         aidx = self._attr_index()
-        rankb_np = aidx.rank_bounds(qnp)
+        # the bounds' ranks among ALL the index's executeAts: a device
+        # kind's ``rankb`` column, computed (and the index's device image
+        # assembled) by the first device part of the flush
+        rankb_np = None
         # when the exact per-token floors equal the structurally applied
         # batch floor everywhere the batch reaches, the per-entry floor leg
         # is provably a no-op — on the host route AND in the kernels (the
@@ -2733,7 +2866,7 @@ class DeviceState:
         # (static flags)
         floor_skip = aidx.floors_match(qnp, q_m, floor_id)
         k_floors = not floor_skip
-        k_elide = aidx.u > 0
+        k_elide = aidx.n_execs > 0
 
         def dispatch(kind, rows, qcols=None):
             """rows: np int64 array of query indices for this part, padded
@@ -2742,6 +2875,7 @@ class DeviceState:
             kind in the devprof slices and kernel_times); mesh kinds come
             back as ONE merged replicated block (d=1, entry buffer
             d_mesh * s)."""
+            nonlocal rankb_np
             import time as _time
             _t0 = _time.perf_counter()
             if kind == "host":
@@ -2783,6 +2917,8 @@ class DeviceState:
                                        "nq": b_pad, "q_m": q_m,
                                        "mq": m_t * q_m, "d_ent": 1,
                                        "immediate": immediate}
+            if rankb_np is None:
+                rankb_np = aidx.rank_bounds(qnp)
             rankb = jnp.asarray(rankb_np[rows_p])
             pz = prune if prune is not None else _prune_zeros()
             if kind == "sharded":
@@ -3040,7 +3176,7 @@ class DeviceState:
             # pipelined batches over an unmutated mirror share one
             ids, ivs, _kind = self.deps.snapshot_cols()
         fmeta = {"floor_id": floor_id, "probing": probing,
-                 "immediate": immediate, "aidx": aidx, "rankb": rankb_np,
+                 "immediate": immediate, "aidx": aidx,
                  "floor_skip": floor_skip}
         return (parts, ids, ivs, qnp, q_m, list(queries), fmeta)
 
@@ -3243,7 +3379,7 @@ class DeviceState:
             tb, tj, cm, cq = self.deps.host_pairs(
                 qnp, q_m, fmeta["floor_id"], snapshot=snapshot)
         tb, tj, tm, tq, n_t, n_d = self._attr_filter_entries(
-            tb, tj, cm, cq, ids, ivs, fmeta["aidx"], fmeta["rankb"],
+            tb, tj, cm, cq, ids, ivs, fmeta["aidx"], qnp,
             fmeta["floor_skip"])
         self.n_elided_transitive += n_t
         self.n_elided_decided += n_d
@@ -3447,9 +3583,10 @@ class DeviceState:
         dm = self.deps
         snap_stale = dm._snap is None or dm._snap[0] != dm.mut_version
         snap_elems = cap * (2 * dm.max_intervals + 10) if snap_stale else 0
-        # r15: fused launches run the ATTRIBUTED kernels — build (or
-        # reuse) this store's floor/elision index and the per-query bound
-        # ranks now, while the mirror is the begin-time state
+        # r15: fused launches run the ATTRIBUTED kernels — take this
+        # store's floor/elision index and the per-query bound ranks (which
+        # assemble its device image) now, while the mirror is the
+        # begin-time state
         aidx = self._attr_index()
         return {"dev": self, "queries": list(queries), "qnp": qnp,
                 "q_m": q_m, "floor_id": floor_id, "prune": prune_np,
@@ -3507,7 +3644,7 @@ class DeviceState:
         cb, cj, cm, cq = ent4
         tb, tj, tm, tq, n_t, n_d = self._attr_filter_entries(
             cb, cj, cm, cq, hint["ids"], hint["ivs"],
-            hint["aidx"], hint["rankb_np"], hint.get("floor_skip", False))
+            hint["aidx"], hint["qnp"], hint.get("floor_skip", False))
         self.n_elided_transitive += n_t
         self.n_elided_decided += n_d
         return tb, tj, tm, tq
@@ -3560,7 +3697,7 @@ class DeviceState:
                      jnp.asarray(pnp[2]))
                 wide = hint["wide"]
                 fl_, el_ = (not hint.get("floor_skip", False),
-                            hint["aidx"].u > 0)
+                            hint["aidx"].n_execs > 0)
                 if self.mesh is not None:
                     from ..parallel.sharded import sharded_flat_attr
                     hdr_dev, ent_dev = sharded_flat_attr(

@@ -428,7 +428,7 @@ class DeviceDispatcher:
             # trivial floor map / empty elision index just computes
             # nothing in the shared legs
             fl_ = any(not h.get("floor_skip", False) for h in hints)
-            el_ = any(h["aidx"].u > 0 for h in hints)
+            el_ = any(h["aidx"].n_execs > 0 for h in hints)
             if mesh is not None:
                 from ..parallel.sharded import sharded_fused_attr
                 attrs = [h["dev"].deps.device_attr_cols_sharded(mesh)

@@ -620,6 +620,7 @@ class NodeServer:
                 "flush_queries": d.n_flush_queries,
                 "fused_launches": d.n_fused_launches,
             })(getattr(getattr(proc, "node", None), "dispatcher", None)),
+            "device": self._device_stats(),
             "wire_bytes_tx": sum(l["bytes_tx"] for l in links.values()),
             "wire_bytes_rx": (self.frame_server.bytes_rx
                               if self.frame_server else 0),
@@ -644,6 +645,24 @@ class NodeServer:
             "chunks": dict(self._chunks.stats(),
                            streams_tx=self.n_chunk_streams_tx,
                            chunk_frames_tx=self.n_chunk_frames_tx),
+        }
+
+    def _device_stats(self) -> Optional[dict]:
+        """The attribution index's counters summed over this node's
+        DeviceStates (DeviceState._attr_index): tokens re-read / flushes
+        against ``attr_tokens`` (what the indexes hold) is how far the
+        maintenance engages; None on a node with the device path off."""
+        node = getattr(self.proc, "node", None) if self.proc else None
+        devs = [s.device for s in node.command_stores.stores
+                if s.device is not None] if node is not None else []
+        if not devs:
+            return None
+        return {
+            "attr_refreshes": sum(d.n_attr_refreshes for d in devs),
+            "attr_tokens_refreshed": sum(d.n_attr_tokens_refreshed
+                                         for d in devs),
+            "attr_device_builds": sum(d.n_attr_device_builds for d in devs),
+            "attr_tokens": sum(d.n_attr_tokens for d in devs),
         }
 
     def _coordination_stats(self) -> Optional[dict]:

@@ -283,6 +283,12 @@ INDEX_COUNTERS: List[Tuple[str, str]] = [
     # carried, and the bytes the cell and whole-table uploads sent
     ("bucket_cells_uploaded", "n_bucket_cells_uploaded"),
     ("bucket_upload_bytes", "bucket_upload_bytes"),
+    # the attribution index is maintained by token (DeviceState._attr_index):
+    # flushes that found a dirty token, the tokens they re-read, and the
+    # times a device route had the device image assembled
+    ("attr_refreshes", "n_attr_refreshes"),
+    ("attr_tokens_refreshed", "n_attr_tokens_refreshed"),
+    ("attr_device_builds", "n_attr_device_builds"),
 ]
 
 

@@ -91,6 +91,7 @@ async def _serve_and_drive(journal_root):
         retries = client.n_retries
         devs = [st.device for s in servers
                 for st in s.proc.node.command_stores.stores]
+        device_stats = [s.stats()["device"] for s in servers]
     finally:
         await client.close()
         for s in servers:
@@ -100,7 +101,7 @@ async def _serve_and_drive(journal_root):
             if s.frame_server is not None:
                 await asyncio.wait_for(s.close(), 30.0)
     return (verifier, answered, finals, before, after, failures, retries,
-            devs)
+            devs, device_stats)
 
 
 @pytest.fixture(scope="module")
@@ -160,3 +161,30 @@ def test_served_drain_ticks_are_swept_on_the_host_by_price(served_run):
     assert sum(d.n_priced_host_ticks for d in devs) == kinds["drain_tick_host"]
     assert sum(d.n_host_ticks + d.n_fused_ticks + d.n_device_faults
                for d in devs) == 0
+
+
+def test_attribution_index_is_refreshed_by_token_and_timed(served_run):
+    """Every flush crosses DeviceState._attr_index once, under its own
+    kernel_times kind; the index is maintained (far fewer token reads than
+    flushes x tokens held); and a run whose queries all priced to the host
+    never had a device image assembled.  stats()["device"] carries the
+    counters."""
+    devs, device_stats = served_run[7], served_run[8]
+
+    def calls(kind):
+        return sum(d.kernel_times.get(kind, (0, 0.0))[0] for d in devs)
+
+    on_device = sum(d.n_bucketed_queries + d.n_dense_queries
+                    + d.n_fused_queries + d.n_mesh_queries for d in devs)
+    assert on_device == 0, "the run's queries were to price to the host"
+    flushes = calls("dispatch_host")
+    assert flushes > 0 and calls("host_attr_index") == flushes
+    assert sum(d.n_attr_device_builds for d in devs) == 0
+    refreshes = sum(d.n_attr_refreshes for d in devs)
+    reads = sum(d.n_attr_tokens_refreshed for d in devs)
+    held = sum(d.n_attr_tokens for d in devs)
+    assert 0 < refreshes <= flushes and held > 0
+    assert reads < flushes * held // 10, (reads, flushes, held)
+    assert {k: sum(st[k] for st in device_stats) for k in device_stats[0]} \
+        == {"attr_refreshes": refreshes, "attr_tokens_refreshed": reads,
+            "attr_device_builds": 0, "attr_tokens": held}
