@@ -11,7 +11,7 @@ from typing import Dict, Optional
 from .. import api
 from ..messages.preaccept import PreAccept, PreAcceptNack, PreAcceptOk
 from ..primitives.deps import Deps
-from ..primitives.timestamp import Ballot, Timestamp, TxnId
+from ..primitives.timestamp import Ballot, Domain, Timestamp, TxnId
 from ..primitives.txn import Txn
 from ..obs import spans_of
 from ..utils import async_chain
@@ -100,8 +100,9 @@ class CoordinateTransaction(api.Callback):
             # the span's duration IS the preaccept quorum RTT in sim time
             self._spans.end(self._sp, oks=len(oks),
                             path="fast" if fast else "slow")
-            self._spans.decision(str(self.txn_id),
-                                 "fast" if fast else "slow")
+            self._spans.decision(
+                str(self.txn_id), "fast" if fast else "slow",
+                "range" if self.txn_id.domain() == Domain.Range else "key")
         if fast:
             # fast path: executeAt == txnId, deps from fast-path voters
             deps = Deps.merge([ok.deps for ok in oks

@@ -331,9 +331,7 @@ class DurableJournal(Journal):
         """Seed a fresh data store with the recovered appends (sorted by
         executeAt, deduped by TxnId — same monotone-union contract as
         install_snapshot)."""
-        for token, entries in self._restored_data.items():
-            entries.sort(key=lambda e: e[1])
-            data_store.log.setdefault(token, []).extend(entries)
+        data_store.install_snapshot(self._restored_data)
 
     # -- replay (journal/recover.py drives this) -----------------------------
     def apply_record(self, doc: dict) -> None:

@@ -5,7 +5,9 @@ that lets it forget.  ``write_snapshot`` serializes the journal's whole
 in-memory state (registers + message bodies + watermarks + HLC
 reservation + client-reply dedupe + data-store log) stamped with the WAL
 sequence it covers, using the same CRC frame as a segment record so a
-torn snapshot is detected exactly like a torn WAL tail.  Recovery loads
+torn snapshot is detected exactly like a torn WAL tail; a state larger
+than one record may be (``segment.MAX_RECORD``: a replica that holds 100 MB
+of records) is written as consecutive frames of one payload.  Recovery loads
 the NEWEST snapshot that validates (an older intact one backstops a torn
 newest — which is why the previous snapshot is kept until the next one
 lands) and replays only WAL records past its floor.
@@ -57,7 +59,8 @@ def write_snapshot(directory: str, floor_seq: int, state: dict,
     final = os.path.join(directory, f"snap-{floor_seq:016d}.snap")
     tmp = final + ".tmp"
     with open(tmp, "wb") as f:
-        f.write(frame(payload))
+        for at in range(0, len(payload), seg_mod.MAX_RECORD):
+            f.write(frame(payload[at:at + seg_mod.MAX_RECORD]))
         f.flush()
         os.fsync(f.fileno())
     os.replace(tmp, final)
@@ -76,6 +79,23 @@ def write_snapshot(directory: str, floor_seq: int, state: dict,
     return final
 
 
+def _join_frames(data: bytes) -> Optional[bytes]:
+    """The payload of a snapshot file: its frames, each under the segment
+    scanner's CRC discipline, joined; None when any is torn or corrupt."""
+    parts, off = [], 0
+    while off < len(data):
+        if len(data) - off < seg_mod._HDR.size:
+            return None
+        length, crc = seg_mod._HDR.unpack_from(data, off)
+        off += seg_mod._HDR.size
+        part = data[off: off + length]
+        if len(part) != length or zlib.crc32(part) != crc:
+            return None
+        parts.append(part)
+        off += length
+    return b"".join(parts) if parts else None
+
+
 def load_latest(directory: str) -> Tuple[int, Optional[dict]]:
     """Newest VALID snapshot as ``(floor_seq, state)``; ``(0, None)``
     when none validates (fresh directory, or every snapshot torn — the
@@ -87,12 +107,8 @@ def load_latest(directory: str) -> Tuple[int, Optional[dict]]:
             data = open(path, "rb").read()
         except OSError:
             continue
-        # one frame: reuse the segment scanner's CRC discipline by hand
-        if len(data) < seg_mod._HDR.size:
-            continue
-        length, crc = seg_mod._HDR.unpack_from(data, 0)
-        payload = data[seg_mod._HDR.size: seg_mod._HDR.size + length]
-        if len(payload) != length or zlib.crc32(payload) != crc:
+        payload = _join_frames(data)
+        if payload is None:
             continue   # torn/corrupt: fall back to the previous snapshot
         try:
             doc = rec_mod.decode_record(payload)

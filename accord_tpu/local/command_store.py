@@ -19,12 +19,15 @@ gathers map-reduce-consume tasks across intersecting stores
 
 from __future__ import annotations
 
+import bisect
+import time
 from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 from ..primitives.deps import PartialDeps
 from ..primitives.keys import Range, Ranges, RoutingKeys, Unseekables
 from ..primitives.timestamp import Kinds, Timestamp, TxnId
 from ..utils import async_chain, invariants
+from ..utils.interval_index import RangeIndex
 from ..utils.interval_map import ReducingRangeMap
 from .command import Command
 from .commands_for_key import CommandsForKey, InternalStatus
@@ -178,14 +181,16 @@ class CommandStore:
         self.ranges_for_epoch = RangesForEpoch()
         self.commands: Dict[TxnId, Command] = {}
         self.commands_for_key: Dict[int, CommandsForKey] = {}
+        # every token of commands_for_key, ascending: a range scan reads
+        # a bisect slice of it (cfk() is the one place a key is added)
+        self._cfk_tokens: List[int] = []
         # Range-domain txns indexed for the range scan path
         # (ref: InMemoryCommandStore.rangeCommands TreeMap scan :524).
-        # Mutate ONLY via put_range_command/drop_range_command: the interval
-        # index below is rebuilt lazily on version change.
+        # Mutate ONLY via put_range_command/drop_range_command, which keep
+        # the interval index below in step, one bisect an interval, from
+        # its first reader on (range_index(): None until then).
         self.range_commands: Dict[TxnId, Ranges] = {}
-        self._range_index = None
-        self._range_index_version = -1
-        self._range_version = 0
+        self._range_index: Optional[RangeIndex] = None
         self.max_conflicts = MaxConflicts()
         self.redundant_before = RedundantBefore()
         self.durable_before = DurableBefore()
@@ -380,27 +385,48 @@ class CommandStore:
 
     # -- range-txn interval index -------------------------------------------
     def put_range_command(self, txn_id: TxnId, ranges: Ranges) -> None:
-        if self.range_commands.get(txn_id) == ranges:
+        old = self.range_commands.get(txn_id)
+        if old == ranges:
             return   # re-registration on a status message: index unchanged
         self.range_commands[txn_id] = ranges
-        self._range_version += 1
+        index = self._range_index
+        if index is not None:
+            t0 = time.perf_counter()
+            for r in old or ():
+                index.remove(r.start, r.end, txn_id)
+            for r in ranges:
+                index.add(r.start, r.end, txn_id)
+            self._range_index_synced(t0)
 
     def drop_range_command(self, txn_id: TxnId) -> None:
-        if self.range_commands.pop(txn_id, None) is not None:
-            self._range_version += 1
+        old = self.range_commands.pop(txn_id, None)
+        index = self._range_index
+        if old is not None and index is not None:
+            t0 = time.perf_counter()
+            for r in old:
+                index.remove(r.start, r.end, txn_id)
+            self._range_index_synced(t0)
 
-    def range_index(self):
-        """Checkpointed interval index over the range-domain txns — the
-        CINTIA stabbing structure (ref: utils/SearchableRangeList.java:19-48),
-        rebuilt lazily after mutations (range txns mutate rarely — epoch
-        fences and durability rounds — while the PreAccept scan stabs it on
-        every keyed dep computation)."""
-        if self._range_index_version != self._range_version:
-            from ..utils.interval_index import SearchableRangeList
-            self._range_index = SearchableRangeList(
+    def _range_index_synced(self, t0: float) -> None:
+        # one timed kind with the device mirror's range registrations
+        # (DeviceState.register): what keeping range txns findable costs
+        if self.device is not None:
+            self.device._ktime("range_index_sync", t0)
+
+    def range_index(self) -> RangeIndex:
+        """Interval index over the range-domain txns (the role of ref:
+        utils/SearchableRangeList.java:19-48): with range scans as client
+        traffic nearly every registration mutates it, and the host
+        PreAccept scan stabs it on every keyed dep computation.  Built
+        from ``range_commands`` for its first reader and kept incrementally
+        from then on, so ONE structure is maintained per store: with the
+        device path on, the deps flush answers from the device mirror's
+        interval index (DeviceState.register), nothing reads this one and
+        nothing is paid for it."""
+        if self._range_index is None:
+            self._range_index = RangeIndex(
                 (r.start, r.end, tid)
                 for tid, rs in self.range_commands.items() for r in rs)
-            self._range_index_version = self._range_version
         return self._range_index
 
     # -- state helpers ------------------------------------------------------
@@ -408,7 +434,13 @@ class CommandStore:
         c = self.commands_for_key.get(token)
         if c is None:
             c = self.commands_for_key[token] = CommandsForKey(token)
+            bisect.insort(self._cfk_tokens, token)
         return c
+
+    def cfk_tokens_in(self, start: int, end: int) -> List[int]:
+        """The tokens in [start, end) that have a CommandsForKey."""
+        return self._cfk_tokens[bisect.bisect_left(self._cfk_tokens, start):
+                                bisect.bisect_left(self._cfk_tokens, end)]
 
     def command_if_present(self, txn_id: TxnId) -> Optional[Command]:
         return self.commands.get(txn_id)
@@ -549,10 +581,12 @@ class SafeCommandStore:
         owned = self.store.ranges_for_epoch.all()
         if isinstance(keys_or_ranges, Ranges):
             scan_ranges = keys_or_ranges.slice(owned)
-            for token, cfk in self.store.commands_for_key.items():
-                if scan_ranges.contains_token(token):
-                    acc = cfk.map_reduce_active(started_before, witnesses,
-                                                lambda tid, a, t=token: fn(t, tid, a), acc)
+            cfks = self.store.commands_for_key
+            for rng in scan_ranges:
+                for token in self.store.cfk_tokens_in(rng.start, rng.end):
+                    acc = cfks[token].map_reduce_active(
+                        started_before, witnesses,
+                        lambda tid, a, t=token: fn(t, tid, a), acc)
             acc = self._scan_range_commands_ranges(scan_ranges, started_before,
                                                    witnesses, fn, acc)
         else:

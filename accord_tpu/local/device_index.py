@@ -78,7 +78,9 @@ is CORRECTNESS-PRESERVING by construction:
    ``n_host_ticks`` or a fault counter; so that such a store still meets
    this ladder, its first tick and then one priced tick every
    ``TICK_AUDIT_MICROS`` of the node's clock go to the device all the
-   same: ``n_audit_ticks``);
+   same: ``n_audit_ticks``; a serving node asks every store for that
+   tick at start and once a period, ``audit_route``, so that a store whose
+   traffic arms nothing is audited too);
  - paranoia mode (utils.faults.PARANOIA or DeviceState.paranoia)
    shadow-verifies every device flush against the host route and treats a
    mismatch as a device fault — the detector for silent result corruption
@@ -1835,6 +1837,10 @@ class DeviceState:
         self.n_dense_queries = 0
         self.n_host_queries = 0
         self.n_mesh_bucketed_queries = 0
+        # queries of range-domain txns among n_queries, and those of them
+        # that a device route answered (the rest went to the host route)
+        self.n_range_queries = 0
+        self.n_range_device_queries = 0
         self.n_dispatches = 0       # kernel dispatches: n_queries /
         #                             n_dispatches = mean lived batch size
         # r08 launch coalescing (local.dispatch.DeviceDispatcher): flushes
@@ -1929,6 +1935,9 @@ class DeviceState:
         # (_audit_tick), and the node's clock at the last device tick
         self.n_audit_ticks = 0
         self._tick_dev_micros: Optional[int] = None
+        # audit_route() asked: the next tick audits, even if it finds
+        # nothing to drive
+        self._audit_asked = False
         # r21 store-sharded residency (parallel.store_shard): the spill
         # rung's StoreShards instance (None until the ladder activates it),
         # flush/byte counters, the per-slice quarantine tallies, and the
@@ -1966,12 +1975,17 @@ class DeviceState:
     def register(self, txn_id: TxnId, status: int, keys) -> None:
         """Witness/advance a txn in the deps index.  ``keys`` is the txn's
         sliced participation (Keys or Ranges) — its conflict footprint."""
+        import time as _time
+        t0 = _time.perf_counter()
         slot = self.deps.alloc(txn_id)
-        if keys is not None:
-            if isinstance(keys, Ranges):
-                self.deps.add_intervals(slot, (), list(keys))
-            else:
-                self.deps.add_intervals(slot, [k.token() for k in keys], ())
+        if isinstance(keys, Ranges):
+            # the mirror's interval index is what answers a range query on
+            # this path: keeping it is the timed kind range_index_sync (as
+            # CommandStore.put_range_command's, once that index has a reader)
+            self.deps.add_intervals(slot, (), list(keys))
+            self._ktime("range_index_sync", t0)
+        elif keys is not None:
+            self.deps.add_intervals(slot, [k.token() for k in keys], ())
         self._advance_status(txn_id, slot, status, None)
 
     def update_status(self, txn_id: TxnId, status: int,
@@ -2629,6 +2643,14 @@ class DeviceState:
             dev_cost += calib.get("c_shard", 0.0) * d * s_attr
         return "host" if host_cost < dev_cost else "device"
 
+    def _count_range_queries(self, queries) -> int:
+        """Count a flush's queries of range-domain txns into
+        n_range_queries; the caller adds them to n_range_device_queries
+        where a device route answers the flush."""
+        n = sum(1 for q in queries if q[0].domain() == Domain.Range)
+        self.n_range_queries += n
+        return n
+
     def _batch_floor(self, qnp: np.ndarray, q_m: int):
         """(floor_id, np prune triple) for a batch: the conservative
         batch-global RedundantBefore floor with the (rb.version, window)
@@ -3089,6 +3111,7 @@ class DeviceState:
             # slots from the host twin (a host_slice part)
             hybrid = sh.any_quarantined()
             self.n_store_sharded_flushes += 1
+        n_range = self._count_range_queries(queries)
         observed = forced or route
         if self.on_route is not None:
             self.on_route(observed, nq)
@@ -3138,8 +3161,11 @@ class DeviceState:
             parts.clear()
             self._device_fault(e, f"dispatch: {e}", sliced=True)
             self.n_fallback_queries += nq
+            route = "host"
             probing = False
             dispatch("host", all_rows)
+        if route != "host":
+            self.n_range_device_queries += n_range
         if immediate:
             # synchronous caller (deps_query, B=1): collect follows on the
             # next line with no interleaved mutation, so skip the snapshot
@@ -3666,6 +3692,7 @@ class DeviceState:
         import time as _time
         _t0 = _time.perf_counter()
         nq = hint["nq"]
+        n_range = self._count_range_queries(hint["queries"])
         if "host" in hint:           # launch already failed over to host
             self.n_host_queries += nq
             self.n_dispatches += 1
@@ -3785,6 +3812,7 @@ class DeviceState:
         self.n_dispatches += 1
         self.n_fused_flushes += 1
         self.n_fused_queries += nq
+        self.n_range_device_queries += n_range
         if self.mesh is not None:
             self.n_mesh_queries += nq
         else:
@@ -3925,25 +3953,44 @@ class DeviceState:
         now = getattr(getattr(self.store, "node", None), "now_micros", None)
         return None if now is None else now()
 
-    def _audit_tick(self) -> bool:
+    def _can_audit(self) -> bool:
+        """Never under a pin or the ladder's hold, nor on a store without
+        a clock."""
+        return self.route_override is None and not self.host_pinned \
+            and self._dev_quar_flushes <= 0 \
+            and self._node_micros() is not None
+
+    def _audit_tick(self, asked: bool) -> bool:
         """Asked by _tick of a tick the router priced to the host: True,
         and counted, when this store's last device tick is
         ``TICK_AUDIT_MICROS`` old or it never had one (so a store meets the
-        tick's program in its first tick, where a served node warms up, and
-        not seconds into its traffic), so this tick is the store's audit of
-        the device route: the whole tick on the device, solo, with the
-        ladder under it as under any device tick.  Never under a pin, nor
-        on a store without a clock."""
-        if self.route_override is not None:
+        tick's program in its first tick, where a served node starts, and
+        not seconds into its traffic), or when the serving node's timer
+        ``asked`` (audit_route), so this tick is the store's audit of the
+        device route: the whole tick on the device, solo, with the ladder
+        under it as under any device tick."""
+        if not self._can_audit():
             return False
-        now = self._node_micros()
-        if now is None:
-            return False
-        if self._tick_dev_micros is not None \
-                and now - self._tick_dev_micros < self.TICK_AUDIT_MICROS:
+        if not asked and self._tick_dev_micros is not None \
+                and self._node_micros() - self._tick_dev_micros \
+                < self.TICK_AUDIT_MICROS:
             return False
         self.n_audit_ticks += 1
         return True
+
+    def audit_route(self) -> None:
+        """A serving node's timer (NodeServer.start: at start, then every
+        ``TICK_AUDIT_MICROS``): this store's next tick is its audit of the
+        device route, whether or not it finds a row to drive.  The audit
+        otherwise rides the ticks that traffic schedules, and traffic whose
+        txns do not wait on each other (range scans over rare inserts)
+        schedules almost none: such a store would load the tick's program
+        on the serving loop whenever its first tick came, and the ladder
+        would not hear of a device that died until the first deep drain
+        needed it.  A tick that drives nothing sweeps the empty frontier:
+        launch, download and the ladder are what it exercises."""
+        self._audit_asked = True
+        self.schedule_tick()
 
     # Coalescing quantum for drain ticks (simulated/real micros): many dep
     # transitions land per tick, so the per-tick adjacency upload + kernel
@@ -3973,7 +4020,9 @@ class DeviceState:
         self._tick_scheduled = False
         self.n_ticks += 1
         sweep_due = self.n_ticks % 8 == 0
-        if not self.drain.active.any():
+        asked, self._audit_asked = self._audit_asked, False
+        if not self.drain.active.any() \
+                and not (asked and self._can_audit()):
             if sweep_due:
                 self.drain.sweep_free()
             return
@@ -4002,7 +4051,7 @@ class DeviceState:
                 except faults.DEVICE_EXCEPTIONS as e:
                     fused.poison(e)
             elif self._drain_wavefront <= 1 and self._host_tick_pays() \
-                    and not self._audit_tick():
+                    and not self._audit_tick(asked):
                 # priced to the host: nothing is uploaded or launched.  A
                 # choice of the router and no fault, so it counts in no
                 # ladder counter and not in n_host_ticks.  A widened

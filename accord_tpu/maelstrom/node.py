@@ -12,10 +12,22 @@ bodies, replies correlated by Maelstrom ``msg_id``/``in_reply_to``.
 
 The workload is Maelstrom's list-append ``txn``: ops ``["r", k, null]`` and
 ``["append", k, v]``; keys (ints or strings) hash onto the token ring.
+
+One op beyond Maelstrom's: ``["scan", [lo, hi], null]``, answered
+``["scan", [lo, hi], [[k, [v, ...]], ...]]``: every key that holds
+something in the half-open TOKEN range ``[lo, hi)``, in ascending token
+order (an integer key is its own token; a string key is answered by its
+token).  A txn with a scan is a range-domain Read: its footprint is the
+scanned ranges, with each ``"r"`` of the same txn as the width-1 range of
+its token, so it is ordered against every insert into the range (no
+phantoms).  A txn that mixes a scan with an ``append`` is refused with error
+code 10: a range-domain txn with a key-domain write has no footprint the
+protocol knows.  An insert is an ``append`` to a key that holds nothing.
 """
 
 from __future__ import annotations
 
+import bisect
 import hashlib
 import heapq
 from typing import Callable, Dict, List, Optional, Tuple
@@ -29,7 +41,8 @@ from ..primitives.datum import datum_from_json, datum_to_json
 from ..primitives.keys import IntKey, Keys, Range, Ranges
 from ..primitives.txn import Txn
 from ..primitives.timestamp import TxnKind
-from ..sim.kvstore import KVDataStore, KVQuery, KVRead, KVUpdate
+from ..sim.kvstore import (KVDataStore, KVQuery, KVRangeRead, KVRead,
+                           KVUpdate)
 from ..topology.shard import Shard
 from ..topology.topology import Topology
 from ..utils.random_source import RandomSource
@@ -374,6 +387,8 @@ class MaelstromProcess:
         self._names_by_id: Dict[int, str] = {}
         self._client_msg_id = 0
         self._sweeper = None
+        # records sent to clients in scan replies
+        self.n_scan_rows = 0
 
     def durable_journal(self):
         """The armed on-disk journal, or None (also None once its group
@@ -710,10 +725,22 @@ class MaelstromProcess:
 
     def _coordinate_txn(self, src: str, msg_id: int, ops,
                         release_once) -> None:
+        def refuse(text: str) -> None:
+            release_once(False, record=False)
+            self._reply_client(src, msg_id, {
+                "type": "error", "code": 10, "text": text})
+
         read_tokens: List[int] = []
         appends: Dict[int, tuple] = {}
+        scans: List[Range] = []
         for op in ops:
             f, k = op[0], op[1]
+            if f == "scan":
+                lo, hi = k
+                if not 0 <= lo < hi <= TOKEN_SPACE:
+                    return refuse(f"scan of no token range [{lo}, {hi})")
+                scans.append(Range(lo, hi))
+                continue
             t = token_of(k)
             if f == "r":
                 read_tokens.append(t)
@@ -722,17 +749,22 @@ class MaelstromProcess:
                 # long/double are native JSON; {"hash": n} becomes DatumHash
                 appends[t] = appends.get(t, ()) + (datum_from_json(op[2]),)
             else:
-                release_once(False, record=False)
-                self._reply_client(src, msg_id, {
-                    "type": "error", "code": 10,
-                    "text": f"unsupported op {f}"})
-                return
-        all_tokens = sorted(set(read_tokens) | set(appends))
-        keys = Keys([IntKey(t) for t in all_tokens])
-        kind = TxnKind.Write if appends else TxnKind.Read
-        txn = Txn(kind, keys,
-                  KVRead(Keys([IntKey(t) for t in sorted(set(read_tokens))])),
-                  KVUpdate(appends) if appends else None, KVQuery())
+                return refuse(f"unsupported op {f}")
+        if scans and appends:
+            return refuse("unsupported op mix: scan with append in one txn")
+        if scans:
+            # a range-domain Read: point reads ride as width-1 ranges
+            ranges = Ranges(scans + [Range(t, t + 1) for t in read_tokens])
+            txn = Txn(TxnKind.Read, ranges, KVRangeRead(ranges), None,
+                      KVQuery())
+        else:
+            all_tokens = sorted(set(read_tokens) | set(appends))
+            keys = Keys([IntKey(t) for t in all_tokens])
+            kind = TxnKind.Write if appends else TxnKind.Read
+            txn = Txn(kind, keys,
+                      KVRead(Keys([IntKey(t)
+                                   for t in sorted(set(read_tokens))])),
+                      KVUpdate(appends) if appends else None, KVQuery())
 
         def on_done(result, failure):
             # the released duration IS the txn root span (admission ->
@@ -746,8 +778,17 @@ class MaelstromProcess:
                 return
             out_ops = []
             appended_so_far: Dict[int, list] = {}
+            scanned = sorted(result.reads) if scans else ()
             for op in ops:
                 f, k = op[0], op[1]
+                if f == "scan":
+                    lo = bisect.bisect_left(scanned, k[0])
+                    hi = bisect.bisect_left(scanned, k[1])
+                    rows = [[t, [datum_to_json(v) for v in result.reads[t]]]
+                            for t in scanned[lo:hi] if result.reads[t]]
+                    self.n_scan_rows += len(rows)
+                    out_ops.append(["scan", k, rows])
+                    continue
                 t = token_of(k)
                 if f == "r":
                     pre = [datum_to_json(v)

@@ -346,7 +346,10 @@ async def open_loop(client: ClusterClient, rate: float, duration: float,
                     txn_timeout: float = 8.0) -> LoadPointResult:
     """Open-loop Poisson load at ``rate`` txn/s for ``duration`` seconds.
     Arrivals never wait for completions; every arrival is submitted once
-    (no retry — the shed/timeout census IS the measurement)."""
+    (no retry — the shed/timeout census IS the measurement).  A latency
+    runs from the instant the request was DUE, not from when its task got
+    the loop: a generator that runs late charges its lateness to the
+    request, as a client would feel it."""
     rng = random.Random(seed)
     counter = [0]
     res = LoadPointResult(rate, duration)
@@ -355,13 +358,12 @@ async def open_loop(client: ClusterClient, rate: float, duration: float,
     t0 = loop.time()
     t_next = t0
 
-    async def one(ops):
+    async def one(ops, due):
         res.sent += 1
-        start = loop.time()
         try:
             await client.submit(ops, timeout=txn_timeout)
             res.ok += 1
-            res.latencies_ms.append((loop.time() - start) * 1e3)
+            res.latencies_ms.append((loop.time() - due) * 1e3)
         except Overloaded:
             res.shed += 1
         except asyncio.TimeoutError:
@@ -376,7 +378,8 @@ async def open_loop(client: ClusterClient, rate: float, duration: float,
             break
         if t_next > now:
             await asyncio.sleep(t_next - now)
-        tasks.append(loop.create_task(one(_mk_ops(rng, counter, n_keys))))
+        tasks.append(loop.create_task(one(_mk_ops(rng, counter, n_keys),
+                                          t_next)))
     if tasks:
         await asyncio.wait(tasks, timeout=txn_timeout + 5.0)
     for t in tasks:

@@ -628,6 +628,7 @@ class NodeServer:
                                     for l in links.values()),
             "client_replies": self.n_client_replies,
             "coordination": self._coordination_stats(),
+            "data": self._data_stats(),
             "unroutable": self.n_unroutable,
             "reply_drops": self.n_reply_drops,
             "frame_errors": (self.frame_server.n_frame_errors
@@ -663,6 +664,9 @@ class NodeServer:
                                          for d in devs),
             "attr_device_builds": sum(d.n_attr_device_builds for d in devs),
             "attr_tokens": sum(d.n_attr_tokens for d in devs),
+            "range_queries": sum(d.n_range_queries for d in devs),
+            "range_device_queries": sum(d.n_range_device_queries
+                                        for d in devs),
         }
 
     def _coordination_stats(self) -> Optional[dict]:
@@ -678,7 +682,23 @@ class NodeServer:
             "slow": obs.metrics.peek_counter("txn_path", path="slow"),
             "recoveries": obs.metrics.counter_totals(
                 "recoveries", by="event").get("attempt", 0),
+            # the decided txns by TxnId.domain(), and the records this
+            # node's scan replies carried to clients
+            "range_txns": obs.metrics.peek_counter("txn_domain",
+                                                   domain="range"),
+            "key_txns": obs.metrics.peek_counter("txn_domain",
+                                                 domain="key"),
+            "scan_rows": self.proc.n_scan_rows,
         }
+
+    def _data_stats(self) -> Optional[dict]:
+        """Calls of, and host clock inside, this replica's data store's
+        range read (KVDataStore.read_range); None before start()."""
+        node = getattr(self.proc, "node", None) if self.proc else None
+        if node is None:
+            return None
+        return {"scan_calls": node.data_store.scan_calls,
+                "scan_host_s": node.data_store.scan_host_s}
 
     # -- lifecycle ------------------------------------------------------------
     async def start(self) -> None:
@@ -804,6 +824,20 @@ class NodeServer:
                     print(f"[{self.name}] snapshot tick failed: {exc!r}",
                           file=sys.stderr)
             scheduler.recurring(2_000_000, snap_tick)
+        if any(s.device is not None
+               for s in self.proc.node.command_stores.stores):
+            # every store audits its device tick route now (the tick's
+            # program is loaded before the first client, not by a store's
+            # first tick in traffic) and once a period from then on,
+            # whether or not its traffic schedules ticks
+            from ..local.device_index import DeviceState
+
+            def audit_routes():
+                for s in self.proc.node.command_stores.stores:
+                    if s.device is not None:
+                        s.device.audit_route()
+            audit_routes()
+            scheduler.recurring(DeviceState.TICK_AUDIT_MICROS, audit_routes)
         print(f"[{self.name}] serving on {self.host}:{self.port} "
               f"peers={sorted(self.peers)} pid={os.getpid()} "
               f"journal={'on' if self.journal is not None else 'off'} "

@@ -289,6 +289,11 @@ INDEX_COUNTERS: List[Tuple[str, str]] = [
     ("attr_refreshes", "n_attr_refreshes"),
     ("attr_tokens_refreshed", "n_attr_tokens_refreshed"),
     ("attr_device_builds", "n_attr_device_builds"),
+    # deps queries of range-domain txns (an interval as footprint), and
+    # those of them a device route answered: the router's verdict on range
+    # traffic, apart from the key-domain queries in the same flushes
+    ("range_queries", "n_range_queries"),
+    ("range_device_queries", "n_range_device_queries"),
 ]
 
 

