@@ -114,6 +114,7 @@ every BENCH artifact.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
@@ -139,13 +140,6 @@ def _pow2_at_least(n: int, floor: int = _MIN_INTERVALS) -> int:
     while out < n:
         out *= 2
     return out
-
-
-# the fused dirty-row scatter jits moved to ops.deps_kernel (r21): the
-# per-slice store-shard sync dispatches the same programs once per slice
-# device, so one implementation serves both residencies
-_scatter_rows = dk.scatter_table_rows
-_scatter_attr_rows = dk.scatter_attr_cols
 
 
 _PZ = None
@@ -229,17 +223,65 @@ _BUCKET_REC = np.dtype([("lo", np.int64), ("hi", np.int64),
                         ("node", np.int32), ("kind", np.int32)])
 _BUCKET_PAD = np.array((dk.PAD_LO, dk.PAD_HI, -1, 0, 0, 0, 0, 0),
                        _BUCKET_REC)
-# a pending cell on its way to the device: the record + its (row, pos) pair
+# a pending cell on its way to the device: the record + its flat cell index
 _CELL_BYTES = _BUCKET_REC.itemsize + 8
+# the record as the staging buffer carries it: six int64 words, the int32
+# pairs (slot, col) and (node, kind) each in one word, low half first
+_CELL_WORDS = _BUCKET_REC.itemsize // 8
 # floor of the padded pending-cell count: a served store's few-cell syncs
 # and a 64-txn flush's ~1k cells share ONE compiled scatter shape
 _MIN_CELLS = 2048
 
 
-@jax.jit
-def _scatter_bucket_cells(dev, rows, pos, vals):
-    """Fused pending-cell update for the eight bucket-entry arrays."""
-    return tuple(a.at[rows, pos].set(v) for a, v in zip(dev, vals))
+@functools.partial(jax.jit, static_argnames=("n_rows", "n_cells"))
+def _sync_tables(table, acols, bdev, stage, n_rows, n_cells):
+    """The single-device table sync as ONE program over ONE staging buffer
+    (``_DepsMirror.sync_device`` packs it): int64 words, field-major —
+    ``n_rows`` dirty slot rows (their index, then msb, lsb, node, status;
+    kind, lo[M], hi[M] when the slot ``table`` takes them; domain and the
+    executeAt columns when ``acols`` does), then ``n_cells`` bucket cells
+    (flat index, then the _BUCKET_REC words).  A table that takes nothing
+    is passed as None and comes back as None."""
+    at = 0
+
+    def take(n):
+        nonlocal at
+        at += n
+        return stage[at - n:at]
+
+    def i32(a):
+        return a.astype(jnp.int32)
+
+    if n_rows:
+        idx = i32(take(n_rows))
+        msb, lsb = take(n_rows), take(n_rows)
+        node, status = i32(take(n_rows)), i32(take(n_rows))
+        if table is not None:
+            m = table.lo.shape[1]
+            kind = i32(take(n_rows))
+            lo = take(m * n_rows).reshape(m, n_rows).T
+            hi = take(m * n_rows).reshape(m, n_rows).T
+            table = dk.DepsTable(*(
+                a.at[idx].set(v) for a, v in zip(
+                    table, (msb, lsb, node, kind, status, lo, hi))))
+        if acols is not None:
+            dom = i32(take(n_rows))
+            emsb, elsb = take(n_rows), take(n_rows)
+            enode, eknown = i32(take(n_rows)), take(n_rows) != 0
+            acols = dk.AttrCols(*(
+                a.at[idx].set(v) for a, v in zip(
+                    acols, (dom, status, msb, lsb, node, emsb, elsb, enode,
+                            eknown))))
+    if n_cells:
+        cell = i32(take(n_cells))
+        k = bdev[0].shape[1]
+        lo, hi, slot_col, msb, lsb, node_kind = \
+            take(_CELL_WORDS * n_cells).reshape(_CELL_WORDS, n_cells)
+        vals = (lo, hi, i32(slot_col), i32(slot_col >> 32), msb, lsb,
+                i32(node_kind), i32(node_kind >> 32))
+        bdev = tuple(a.at[cell // k, cell % k].set(v)
+                     for a, v in zip(bdev, vals))
+    return table, acols, bdev
 
 
 def _host_index_of(status, lo_a, hi_a, msb, lsb, node, fkey):
@@ -539,38 +581,10 @@ class _DepsMirror:
         return hit[1]
 
     def bucket_device(self) -> "dk.BucketTable":
-        """Sync the bucket index to the (single) device — the cells written
-        since the last sync, or the whole table when that is no more bytes
-        (the rule of device_table's "mostly dirty") — and return the
-        BucketTable."""
-        n = len(self._bpend)
-        if self._bdev is None or n:
-            faults.check("transfer", "bucket upload")
-            import time as _time
-            t0 = _time.perf_counter()
-            padded = _pow2_at_least(n, _MIN_CELLS)
-            nbytes = padded * _CELL_BYTES
-            if self._bdev is None or nbytes >= self._brec.nbytes:
-                kind, n, nbytes = "sync_bucket_full", 0, self._brec.nbytes
-                self._bdev = tuple(jnp.asarray(a) for a in self._bhost)
-            else:
-                # padded with the last cell (an idempotent scatter): one
-                # compilation per pow2
-                kind = "sync_bucket_cells"
-                cells = np.fromiter(self._bpend, np.int32, n)
-                cells = np.concatenate(
-                    [cells, np.full(padded - n, cells[-1], np.int32)])
-                vals = self._bflat[cells]
-                rows, pos = np.divmod(cells, np.int32(self.BUCKET_K))
-                self._bdev = _scatter_bucket_cells(
-                    self._bdev, rows, pos,
-                    tuple(np.ascontiguousarray(vals[f])
-                          for f in _BUCKET_REC.names))
-            self._bpend.clear()
-            if self.owner is not None:
-                self.owner._ktime(kind, t0)
-                self.owner.n_bucket_cells_uploaded += n
-                self.owner.bucket_upload_bytes += nbytes
+        """The BucketTable on the (single) device, after sync_device has
+        brought it (and the slot table and the attribution columns) level
+        with the host."""
+        self.sync_device(bucket=True)
         whost = self._sync_wide_host(16)
         wkey = (self.wide_version, whost[0].shape[0])
         if self._wdev is None or self._wdev_key != wkey:
@@ -764,27 +778,9 @@ class _DepsMirror:
                 self.emsb, self.elsb, self.enode, self.eknown)
 
     def device_attr_cols(self) -> "dk.AttrCols":
-        """Single-device attribution columns, dirty-row scatter-updated in
-        lockstep with device_table()."""
-        if self._attr_dev is None or self._attr_dirty:
-            faults.check("transfer", "attr column upload")
-        if self._attr_dev is None:
-            self._attr_dev = dk.AttrCols(
-                *(jnp.asarray(a) for a in self._attr_host_cols()))
-            self._attr_dirty.clear()
-        elif self._attr_dirty:
-            rows = np.array(sorted(self._attr_dirty), np.int32)
-            if len(rows) * 2 >= self.capacity:
-                self._attr_dev = None
-                return self.device_attr_cols()
-            padded = _pow2_at_least(len(rows), 8)
-            rows = np.concatenate([rows, np.full(padded - len(rows),
-                                                 rows[-1], np.int32)])
-            idx = jnp.asarray(rows)
-            host = self._attr_host_cols()
-            self._attr_dev = _scatter_attr_rows(
-                self._attr_dev, idx, *(a[rows] for a in host))
-            self._attr_dirty.clear()
+        """Single-device attribution columns, synced in lockstep with
+        device_table() (sync_device)."""
+        self.sync_device()
         return self._attr_dev
 
     def device_attr_cols_replicated(self, mesh) -> "dk.AttrCols":
@@ -1041,35 +1037,121 @@ class _DepsMirror:
         self._device_sh_key = key
         return self._device_sh
 
+    def _slot_host_cols(self):
+        return (self.msb, self.lsb, self.node, self.kind, self.status,
+                self.lo, self.hi)
+
     def device_table(self) -> dk.DepsTable:
-        if self._device is None or self._dirty:
-            faults.check("transfer", "slot upload")
-        if self._device is None:
-            self._device = dk.DepsTable(
-                jnp.asarray(self.msb), jnp.asarray(self.lsb),
-                jnp.asarray(self.node), jnp.asarray(self.kind),
-                jnp.asarray(self.status), jnp.asarray(self.lo),
-                jnp.asarray(self.hi))
-            self._dirty.clear()
-        elif self._dirty:
-            rows = np.array(sorted(self._dirty), np.int32)
-            if len(rows) * 2 >= self.capacity:
-                # mostly dirty: a full upload is cheaper than a scatter
-                self._device = None
-                return self.device_table()
-            # pad to a power-of-two bucket (repeating the last row: scatter
-            # of identical values is idempotent) so jit caches one
-            # compilation per bucket instead of one per dirty-count
-            padded = _pow2_at_least(len(rows), 8)
-            rows = np.concatenate([rows, np.full(padded - len(rows),
-                                                 rows[-1], np.int32)])
-            self._device = _scatter_rows(
-                self._device, jnp.asarray(rows),
-                self.msb[rows], self.lsb[rows], self.node[rows],
-                self.kind[rows], self.status[rows],
-                self.lo[rows], self.hi[rows])
-            self._dirty.clear()
+        self.sync_device()
         return self._device
+
+    def sync_device(self, bucket: bool = False) -> None:
+        """Bring the single-device copies level with the host: the slot
+        table, the attribution columns and the bucket index (``bucket``
+        asks for its first upload; once resident, every sync keeps it
+        level).  Per table, as ever: a copy that is absent, of another
+        shape or mostly dirty goes up whole (``jnp.asarray`` of the columns;
+        the bucket index when its pending cells would cost as many bytes as
+        the table).  Whatever else is dirty crosses as ONE staging buffer
+        into ONE launch of _sync_tables: the union of the dirty slot and
+        attribution rows (padded to one pow2 for both tables by repeating
+        the last row, an idempotent scatter) and the pending cells (padded
+        likewise, floor _MIN_CELLS).  device_table(), device_attr_cols()
+        and bucket_device() all come here, so the first of them a flush
+        asks leaves the others nothing to do."""
+        full_slots = self._device is None \
+            or len(self._dirty) * 2 >= self.capacity
+        full_attrs = self._attr_dev is None \
+            or len(self._attr_dirty) * 2 >= self.capacity
+        n_pend = len(self._bpend)     # empty while the index is not resident
+        n_cells = _pow2_at_least(n_pend, _MIN_CELLS) if n_pend else 0
+        full_cells = bucket if self._bdev is None \
+            else n_cells * _CELL_BYTES >= self._brec.nbytes
+        slots = full_slots or bool(self._dirty)
+        attrs = full_attrs or bool(self._attr_dirty)
+        cells = full_cells or n_pend > 0
+        if not (slots or attrs or cells):
+            return
+        if slots:
+            faults.check("transfer", "slot upload")
+        if attrs:
+            faults.check("transfer", "attr column upload")
+        if cells:
+            faults.check("transfer", "bucket upload")
+        import time as _time
+        t0 = _time.perf_counter()
+        owner, uploads = self.owner, 0
+        if full_slots:
+            self._device = dk.DepsTable(
+                *(jnp.asarray(a) for a in self._slot_host_cols()))
+            self._dirty.clear()
+            uploads += 7
+        if full_attrs:
+            self._attr_dev = dk.AttrCols(
+                *(jnp.asarray(a) for a in self._attr_host_cols()))
+            self._attr_dirty.clear()
+            uploads += 9
+        if full_cells:
+            t1 = _time.perf_counter()
+            self._bdev = tuple(jnp.asarray(a) for a in self._bhost)
+            self._bpend.clear()
+            n_pend = n_cells = 0
+            uploads += 8
+            if owner is not None:
+                owner._ktime("sync_bucket_full", t1)
+                owner.bucket_upload_bytes += self._brec.nbytes
+        # the staging buffer: int64 words, field-major (_sync_tables)
+        pieces = []
+        table = acols = bdev = None
+        rows = self._dirty | self._attr_dirty
+        n_rows = _pow2_at_least(len(rows), 8) if rows else 0
+        if rows:
+            # a resident table rides along even when only the other has
+            # dirty rows (its rows are rewritten with what they hold): the
+            # program family stays one per (rows, cells) shape
+            table = None if full_slots else self._device
+            acols = None if full_attrs else self._attr_dev
+            rows = np.sort(np.fromiter(rows, np.int64, len(rows)))
+            rows = np.concatenate(
+                [rows, np.full(n_rows - len(rows), rows[-1])])
+            pieces += [rows, self.msb[rows], self.lsb[rows],
+                       self.node[rows], self.status[rows]]
+            if table is not None:
+                pieces += [self.kind[rows], self.lo[rows].T.ravel(),
+                           self.hi[rows].T.ravel()]
+            if acols is not None:
+                pieces += [self.domain[rows], self.emsb[rows],
+                           self.elsb[rows], self.enode[rows],
+                           self.eknown[rows]]
+        if n_pend:
+            t1 = _time.perf_counter()
+            bdev = self._bdev
+            at = np.fromiter(self._bpend, np.int64, n_pend)
+            at = np.concatenate([at, np.full(n_cells - n_pend, at[-1])])
+            pieces += [at, self._bflat[at].view(np.int64).reshape(
+                n_cells, _CELL_WORDS).T.ravel()]
+            if owner is not None:
+                owner._ktime("sync_bucket_cells", t1)
+                owner.n_bucket_cells_uploaded += n_pend
+                owner.bucket_upload_bytes += n_cells * _CELL_BYTES
+        if pieces:
+            table, acols, bdev = _sync_tables(
+                table, acols, bdev, np.concatenate(pieces, dtype=np.int64),
+                n_rows=n_rows, n_cells=n_cells)
+            uploads += 1
+            if table is not None:
+                self._device = table
+            if acols is not None:
+                self._attr_dev = acols
+            if bdev is not None:
+                self._bdev = bdev
+            self._dirty.clear()
+            self._attr_dirty.clear()
+            self._bpend.clear()
+        if owner is not None:
+            owner._ktime("sync_tables", t0)
+            owner.n_sync_uploads += uploads
+            owner.n_sync_launches += bool(pieces)
 
 
 @jax.jit
@@ -1884,11 +1966,18 @@ class DeviceState:
         # kind -> [calls, seconds]; dispatch_* covers host pack + upload +
         # enqueue, wait_* the download join, host_* the host-side passes
         self.kernel_times: Dict[str, List[float]] = {}
-        # _DepsMirror.bucket_device: kernel_times' sync_bucket_cells /
-        # sync_bucket_full count its syncs by path; these the pending cells
-        # the cells path carried and the bytes either path sent
+        # _DepsMirror.sync_device's bucket index: kernel_times'
+        # sync_bucket_cells / sync_bucket_full count its syncs by path (the
+        # cells' packing, the whole upload: both inside sync_tables); these
+        # the pending cells the cells path carried and the bytes either sent
         self.n_bucket_cells_uploaded = 0
         self.bucket_upload_bytes = 0
+        # _DepsMirror.sync_device (kernel_times' sync_tables is its HOST
+        # clock): programs its table syncs launched and arrays they handed
+        # to the device; a steady flush reads 1 and 1, a whole upload 0 and
+        # one per column
+        self.n_sync_launches = 0
+        self.n_sync_uploads = 0
         # -- device-fault tolerance (module docstring: degradation ladder) --
         # shadow-verify every device flush against the host route when True
         # (or when utils.faults.PARANOIA is set process-wide)

@@ -652,7 +652,8 @@ class NodeServer:
         """The attribution index's counters summed over this node's
         DeviceStates (DeviceState._attr_index): tokens re-read / flushes
         against ``attr_tokens`` (what the indexes hold) is how far the
-        maintenance engages; None on a node with the device path off."""
+        maintenance engages; with them the range queries' routes and the
+        table syncs' crossings.  None on a node with the device path off."""
         node = getattr(self.proc, "node", None) if self.proc else None
         devs = [s.device for s in node.command_stores.stores
                 if s.device is not None] if node is not None else []
@@ -667,6 +668,10 @@ class NodeServer:
             "range_queries": sum(d.n_range_queries for d in devs),
             "range_device_queries": sum(d.n_range_device_queries
                                         for d in devs),
+            # table syncs of the device routes (_DepsMirror.sync_device):
+            # programs launched, arrays handed over
+            "sync_launches": sum(d.n_sync_launches for d in devs),
+            "sync_uploads": sum(d.n_sync_uploads for d in devs),
         }
 
     def _coordination_stats(self) -> Optional[dict]:

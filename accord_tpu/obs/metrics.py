@@ -294,6 +294,11 @@ INDEX_COUNTERS: List[Tuple[str, str]] = [
     # traffic, apart from the key-domain queries in the same flushes
     ("range_queries", "n_range_queries"),
     ("range_device_queries", "n_range_device_queries"),
+    # the single-device table sync (_DepsMirror.sync_device): programs it
+    # launched and arrays it handed to the device; over the device flushes,
+    # 1 and 1 where the dirty rows and cells cross as one staging buffer
+    ("sync_launches", "n_sync_launches"),
+    ("sync_uploads", "n_sync_uploads"),
 ]
 
 

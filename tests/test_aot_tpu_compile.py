@@ -133,19 +133,24 @@ def test_bucketed_attributed_scan_compiles(topo, no_persistent_cache):
     _fits(compiled)
 
 
-@pytest.mark.parametrize("cells", [2048, 32768])
-def test_bucket_cell_scatter_compiles(topo, no_persistent_cache, cells):
-    """The pending-cell sync of the bucket index at the store cells' sizes:
-    15,625 buckets in 16,384 rows; a 64-txn flush pads to the 2,048-cell
-    floor, a 2,048-txn flush to 32,768 cells."""
-    from accord_tpu.local.device_index import (_BUCKET_REC,
-                                               _scatter_bucket_cells)
+@pytest.mark.parametrize("rows,cells", [(128, 2048), (4096, 32768)])
+def test_table_sync_compiles(topo, no_persistent_cache, rows, cells):
+    """The one table-sync program at the store cells' sizes: 131,072 slots
+    of 8 intervals, 15,625 buckets in 16,384 rows.  A 64-txn flush leaves
+    128 dirty rows and pads its cells to the 2,048 floor; a 2,048-txn flush
+    4,096 rows and 32,768 cells."""
+    from accord_tpu.local.device_index import (_BUCKET_REC, _CELL_WORDS,
+                                               _sync_tables)
     one = SingleDeviceSharding(topo.devices[0])
-    dtypes = [_BUCKET_REC[f] for f in _BUCKET_REC.names]
-    compiled = _scatter_bucket_cells.lower(
-        tuple(_sds((16384, 128), dt, one) for dt in dtypes),
-        _sds((cells,), jnp.int32, one), _sds((cells,), jnp.int32, one),
-        tuple(_sds((cells,), dt, one) for dt in dtypes)).compile()
+    # idx, msb, lsb, node, status; kind, lo[M], hi[M]; dom, emsb, elsb,
+    # enode, eknown; then a cell's index and its record
+    words = rows * (5 + 1 + 2 * M + 5) + cells * (1 + _CELL_WORDS)
+    compiled = _sync_tables.lower(
+        _table(N, M, one, one), _attr_cols(N, one),
+        tuple(_sds((16384, 128), _BUCKET_REC[f], one)
+              for f in _BUCKET_REC.names),
+        _sds((words,), jnp.int64, one), n_rows=rows,
+        n_cells=cells).compile()
     _fits(compiled)
 
 

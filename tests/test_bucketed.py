@@ -257,11 +257,14 @@ def test_bucket_device_uploads_cells_or_the_whole_table(path):
     table_bytes = deps._brec.nbytes
     assert table_bytes == 64 * deps.BUCKET_K * 48
     assert {k: c for k, (c, _s) in dev.kernel_times.items()} == \
-        {"sync_bucket_full": 1}
+        {"sync_bucket_full": 1, "sync_tables": 1}
     assert (dev.n_bucket_cells_uploaded, dev.bucket_upload_bytes) == \
         (0, table_bytes)
+    # the first sync sent every column of the three tables, by no program
+    assert (dev.n_sync_launches, dev.n_sync_uploads) == (0, 7 + 9 + 8)
     _check_device(deps)                 # nothing pending: no sync at all
     assert dev.bucket_upload_bytes == table_bytes
+    assert dev.kernel_times["sync_tables"][0] == 1
     if path == "cells":
         for tid in first[:5]:
             dev.free(tid)
@@ -271,6 +274,9 @@ def test_bucket_device_uploads_cells_or_the_whole_table(path):
         _check_device(deps)
         assert dev.kernel_times["sync_bucket_cells"][0] == 1
         assert dev.kernel_times["sync_bucket_full"][0] == 1
+        # ... with the dirty slot and attribution rows, as one staging
+        # buffer into one program
+        assert (dev.n_sync_launches, dev.n_sync_uploads) == (1, 24 + 1)
         assert dev.n_bucket_cells_uploaded == n
         assert dev.bucket_upload_bytes == \
             table_bytes + _MIN_CELLS * _CELL_BYTES
@@ -281,9 +287,179 @@ def test_bucket_device_uploads_cells_or_the_whole_table(path):
         _check_device(deps)
         assert "sync_bucket_cells" not in dev.kernel_times
         assert dev.kernel_times["sync_bucket_full"][0] == 2
+        # 1,100 dirty rows of the 2,048 are mostly dirty too: no program
+        assert (dev.n_sync_launches, dev.n_sync_uploads) == (0, 2 * 24)
         assert (dev.n_bucket_cells_uploaded, dev.bucket_upload_bytes) == \
             (0, 2 * table_bytes)
     _check_rows(deps)
+
+
+_ACCESSORS = ("device_table", "device_attr_cols", "bucket_device")
+
+
+def _check_synced(dev, first):
+    """Ask the three accessors, ``first`` first: at most one program is
+    launched for them all, nothing stays dirty, and the device's slot
+    table, attribution columns and bucket arrays equal the host's."""
+    deps = dev.deps
+    launches = dev.n_sync_launches
+    got = {a: getattr(deps, a)()
+           for a in (first, *(a for a in _ACCESSORS if a != first))}
+    assert dev.n_sync_launches - launches <= 1
+    assert not (deps._dirty or deps._attr_dirty or deps._bpend)
+    for dev_cols, host_cols in (
+            (got["device_table"], deps._slot_host_cols()),
+            (got["device_attr_cols"], deps._attr_host_cols()),
+            (got["bucket_device"][:8], deps._bhost)):
+        assert len(dev_cols) == len(host_cols)
+        for dev_a, host_a in zip(dev_cols, host_cols):
+            assert dev_a.dtype == host_a.dtype
+            assert np.array_equal(np.asarray(dev_a), host_a)
+
+
+def _mutate(rng, dev, live, hlc, keyspace, max_keys):
+    """One random mutation of the store: register, widen a footprint
+    (add_intervals on a held slot), status move, executeAt write,
+    invalidate, free."""
+    from accord_tpu.primitives.timestamp import Timestamp
+    r = rng.random()
+    if r < 0.45 or len(live) < 8:
+        kind = TxnKind.Write if rng.random() < 0.7 else TxnKind.Read
+        node = 1 + int(rng.integers(0, 5))
+        if rng.random() < 0.5:
+            tid = TxnId.create(1, hlc, kind, Domain.Key, node)
+            keys = Keys([IntKey(int(t)) for t in rng.choice(
+                keyspace, int(rng.integers(1, max_keys + 1)), replace=False)])
+        else:
+            tid = TxnId.create(1, hlc, kind, Domain.Range, node)
+            s = int(rng.integers(0, keyspace - 80))
+            keys = Ranges.of(Range(s, s + int(rng.integers(1, 80))))
+        dev.register(tid, int(InternalStatus.PREACCEPTED), keys)
+        live.append(tid)
+        return
+    tid = live[int(rng.integers(0, len(live)))]
+    if r < 0.55 and tid.domain() == Domain.Key:
+        # the same txn witnessed on more keys: its footprint is the union
+        dev.register(tid, int(InternalStatus.PREACCEPTED),
+                     Keys([IntKey(int(t)) for t in rng.choice(
+                         keyspace, 2, replace=False)]))
+    elif r < 0.65:
+        dev.update_status(tid, int(InternalStatus.ACCEPTED))
+    elif r < 0.78:
+        dev.update_status(
+            tid, int(InternalStatus.COMMITTED),
+            Timestamp.from_values(1, hlc + int(rng.integers(0, 50)),
+                                  1 + int(rng.integers(0, 5))))
+    elif r < 0.84:
+        dev.update_status(tid, int(InternalStatus.INVALIDATED))
+    else:
+        live.remove(tid)
+        dev.free(tid)
+
+
+@pytest.mark.parametrize("path", ["cells", "full"])
+@pytest.mark.parametrize("first", _ACCESSORS)
+def test_one_sync_keeps_every_device_copy_level(first, path):
+    """Random register / add_intervals / status move / executeAt write /
+    invalidate / free sequences, through a capacity grow (64 -> 128 slots
+    and on) and an interval grow (4 -> 8 and on): after EVERY sync the device
+    copies equal the host truth, whichever accessor asks first.  ``cells``
+    syncs every few mutations (dirty rows and pending cells through the one
+    program), ``full`` after bursts that leave every table mostly dirty
+    (whole uploads, no program)."""
+    rng = np.random.default_rng(7)
+    keyspace = 64 << _DepsMirror.BSHIFT         # 64 buckets: no row grow
+    store, dev, safe = _mk_state()
+    deps = dev.deps
+    live, hlc = [], 1
+    rounds, burst, max_keys = (70, 9, 6) if path == "cells" else (5, 700, 8)
+    rising = [InternalStatus.ACCEPTED, InternalStatus.COMMITTED,
+              InternalStatus.STABLE, InternalStatus.APPLIED]
+    caps, widths = {deps.capacity}, {deps.max_intervals}
+    whole = 0
+    for r in range(rounds):
+        for _ in range(burst):
+            _mutate(rng, dev, live, hlc, keyspace, max_keys)
+            hlc += 1
+        if path == "full":
+            # every held txn moves, and the next round's keys reach twice
+            # the buckets (the row arrays grow): all three tables go whole
+            for tid in live:
+                dev.update_status(tid, int(rising[min(r, 3)]))
+            keyspace *= 2
+        before = (dev.n_sync_launches, dev.n_sync_uploads)
+        _check_synced(dev, first)
+        whole += (dev.n_sync_launches, dev.n_sync_uploads) == \
+            (before[0], before[1] + 7 + 9 + 8)
+        caps.add(deps.capacity)
+        widths.add(deps.max_intervals)
+    assert len(caps) >= 2 and len(widths) >= 2
+    _check_rows(deps)
+    if path == "cells":
+        # one program a round but for the first sync and the grows
+        assert dev.n_sync_launches >= rounds - 6
+        assert dev.kernel_times["sync_bucket_cells"][0] >= rounds - 6
+        assert rounds <= dev.kernel_times["sync_tables"][0] <= rounds + 3
+    else:
+        assert whole >= rounds - 1 and dev.n_sync_launches <= 1
+    # the state all of that left answers as the dense kernel does
+    qs = _queries(rng, 16, keyspace, hlc)
+    got = _raw_deps(dev, qs)
+    dev.BUCKETED = False
+    assert got == _raw_deps(dev, qs)
+
+
+def test_each_table_sync_draws_its_transfer_fault():
+    """The slot, attribution and bucket uploads are three fault points of
+    the one sync: each fires when its turn in the draw comes, a sync that
+    faulted has sent nothing, and a flush that meets any of them fails over
+    to the host route and quarantines."""
+    from accord_tpu.utils import faults
+
+    class Nth:
+        """A fault source that fires on its n-th draw only."""
+
+        def __init__(self, n):
+            self.left = n
+
+        def decide(self, _p):
+            self.left -= 1
+            return self.left == 0
+
+    rng = np.random.default_rng(11)
+    keyspace = 64 << _DepsMirror.BSHIFT
+    store, dev, safe = _mk_state()
+    deps = dev.deps
+    live = []
+    for hlc in range(1, 40):
+        _mutate(rng, dev, live, hlc, keyspace, 3)
+    _check_synced(dev, "bucket_device")
+    qs = _queries(rng, 8, keyspace, 100)
+    for n, point in enumerate(("slot upload", "attr column upload",
+                               "bucket upload"), 1):
+        for hlc in range(100 * n, 100 * n + 6):
+            _mutate(rng, dev, live, hlc, keyspace, 3)
+        tid = TxnId.create(1, 100 * n + 50, TxnKind.Write, Domain.Key, 1)
+        dev.register(tid, int(InternalStatus.PREACCEPTED),
+                     Keys([IntKey(3), IntKey(700)]))
+        live.append(tid)
+        assert deps._dirty and deps._attr_dirty and deps._bpend
+        dirty = (set(deps._dirty), set(deps._attr_dirty), set(deps._bpend))
+        uploads = dev.n_sync_uploads
+        with faults.device_fault("transfer", 1.0, Nth(n)):
+            with pytest.raises(faults.TransferFault, match=point):
+                deps.device_table()
+        assert dirty == (deps._dirty, deps._attr_dirty, deps._bpend)
+        assert dev.n_sync_uploads == uploads
+        # the same draw inside a flush: host answer, one quarantine
+        faulted = dev.n_device_faults
+        with faults.device_fault("transfer", 1.0, Nth(n)):
+            got = _raw_deps(dev, qs)
+        assert dev.n_device_faults == faulted + 1
+        assert dev.n_fallback_queries == n * len(qs)
+        dev._dev_quar_flushes = dev._dev_backoff = 0    # lift the quarantine
+        _check_synced(dev, "device_table")
+        assert got == _raw_deps(dev, qs)
 
 
 @pytest.mark.parametrize("shape", ["spread", "hot", "wide", "mixed"])

@@ -192,4 +192,6 @@ def test_attribution_index_is_refreshed_by_token_and_timed(served_run):
     assert {k: sum(st[k] for st in device_stats) for k in device_stats[0]} \
         == {"attr_refreshes": refreshes, "attr_tokens_refreshed": reads,
             "attr_device_builds": 0, "attr_tokens": held,
-            "range_queries": 0, "range_device_queries": 0}
+            "range_queries": 0, "range_device_queries": 0,
+            # no flush went to the device, so no table was ever synced
+            "sync_launches": 0, "sync_uploads": 0}
