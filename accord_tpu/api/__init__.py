@@ -210,6 +210,13 @@ class MessageSink(abc.ABC):
         from ..messages.base import FailureReply
         self.reply(to, reply_context, FailureReply(failure))
 
+    def is_known_down(self, to: int) -> bool:
+        """Does the transport KNOW that ``to`` is gone (ref: the host's
+        failure detector behind Cassandra's messaging)?  A sink that says
+        so fails callbacks to it at once; whoever picks ONE replica to ask
+        skips it.  A sink without such knowledge says no."""
+        return False
+
 
 # ---------------------------------------------------------------------------
 # Topology epoch source

@@ -1,13 +1,22 @@
 """The liveness engine: per-store progress log driving recovery and fetch.
 
 Rebuild of ref: accord-core/src/main/java/accord/impl/SimpleProgressLog.java:77-714.
-Two state machines per store:
+Three state machines per store:
 
 - HomeState (this node is a home-shard replica for the txn): every tracked
   txn cycles Expected -> NoProgress -> Investigating on a periodic scan; an
   Investigating txn runs MaybeRecover (CheckStatus probe, escalating to full
   Recover).  Progress observed remotely resets to Expected with the new
   ProgressToken; a terminal outcome retires the entry.
+
+- NonHomeState (this node is a replica of the txn, but not of its home
+  shard): a txn witnessed here that stays undecided for two scans is handed
+  to the BlockedState machine as if a local txn waited on it: the fetch
+  either learns its outcome or tells the home shard of it (InformOfTxnId),
+  whose replicas then track and recover it.  A coordinator that dies after
+  its PreAccept reached non-home replicas only leaves a txn that no home
+  replica has heard of; without this nobody looks at it until a later txn
+  on its keys waits for it, and then waits out the whole chase.
 
 - BlockedState (any store): a local txn is waiting on a dependency whose
   Commit/Apply this node missed.  The scan runs FetchData for the blocker,
@@ -71,6 +80,17 @@ class _HomeEntry:
         self.countdown = self.backoff
 
 
+class _NonHomeEntry:
+    """(ref: SimpleProgressLog NonHomeState: Unsafe -> StillUnsafe ->
+    informs the home shard)."""
+    __slots__ = ("txn_id", "route", "countdown")
+
+    def __init__(self, txn_id: TxnId, route):
+        self.txn_id = txn_id
+        self.route = route
+        self.countdown = 2   # scans undecided before it counts as blocked
+
+
 class _BlockedEntry:
     __slots__ = ("txn_id", "participants", "progress", "countdown", "backoff",
                  "empty_fetches")
@@ -100,6 +120,7 @@ class SimpleProgressLog(api.ProgressLog):
         self.store = store
         self.scan_delay_micros = scan_delay_micros
         self.home: Dict[TxnId, _HomeEntry] = {}
+        self.non_home: Dict[TxnId, _NonHomeEntry] = {}
         self.blocked: Dict[TxnId, _BlockedEntry] = {}
         self._scheduled = None
         # stand-down signals dropped because a past epoch's topology never
@@ -108,7 +129,8 @@ class SimpleProgressLog(api.ProgressLog):
 
     # -- scheduling ----------------------------------------------------------
     def _arm(self) -> None:
-        if self._scheduled is None and (self.home or self.blocked):
+        if self._scheduled is None and (self.home or self.blocked
+                                        or self.non_home):
             node = self.store.node
             # stagger scans per node/store so home replicas of the same txn
             # do not investigate (and mutually preempt) in lock-step
@@ -137,6 +159,17 @@ class SimpleProgressLog(api.ProgressLog):
             if entry.countdown <= 0:
                 entry.progress = _Progress.Investigating
                 self._investigate(entry)
+        for nh in list(self.non_home.values()):
+            nh.countdown -= 1
+            if nh.countdown <= 0:
+                # still undecided here: from now on the blocked machine's
+                # (fetch, or tell the home shard, then back off)
+                del self.non_home[nh.txn_id]
+                self.waiting(nh.txn_id, 0, nh.route, nh.route.participants)
+                blocked = self.blocked.get(nh.txn_id)
+                if blocked is not None:
+                    # it has waited its two scans: fetched in this one
+                    blocked.countdown = min(blocked.countdown, 1)
         for entry in list(self.blocked.values()):
             if entry.progress is _Progress.Investigating:
                 continue
@@ -145,6 +178,7 @@ class SimpleProgressLog(api.ProgressLog):
                 entry.progress = _Progress.Investigating
                 self._fetch(entry)
         self._arm()
+
 
     # -- home-shard recovery -------------------------------------------------
     def _investigate(self, entry: _HomeEntry) -> None:
@@ -339,15 +373,23 @@ class SimpleProgressLog(api.ProgressLog):
         inform_home_of_txn(self.store.node, txn_id, route)
 
     # -- helpers -------------------------------------------------------------
-    def _track_home(self, safe, txn_id: TxnId) -> None:
+    def _track_home(self, safe, txn_id: TxnId,
+                    undecided: bool = False) -> None:
+        """``undecided``: the caller's hook fires below the commit, so a
+        replica outside the home shard watches the txn too, to tell the home
+        shard of it should it stay there."""
         cmd = safe.get(txn_id)
         if cmd.route is None:
             return
         node = self.store.node
-        if not node.is_home_shard_replica(txn_id, cmd.route):
+        if node.is_home_shard_replica(txn_id, cmd.route):
+            if txn_id not in self.home:
+                self.home[txn_id] = _HomeEntry(txn_id, cmd.route)
+        elif undecided and cmd.route.home_key is not None:
+            if txn_id not in self.non_home:
+                self.non_home[txn_id] = _NonHomeEntry(txn_id, cmd.route)
+        else:
             return
-        if txn_id not in self.home:
-            self.home[txn_id] = _HomeEntry(txn_id, cmd.route)
         self._arm()
 
     def _refresh(self, safe, txn_id: TxnId) -> None:
@@ -377,16 +419,18 @@ class SimpleProgressLog(api.ProgressLog):
         self._track_home(safe, txn_id)
 
     def pre_accepted(self, safe, txn_id: TxnId) -> None:
-        self._track_home(safe, txn_id)
+        self._track_home(safe, txn_id, undecided=True)
 
     def accepted(self, safe, txn_id: TxnId) -> None:
-        self._track_home(safe, txn_id)
+        self._track_home(safe, txn_id, undecided=True)
         self._refresh(safe, txn_id)
 
     def precommitted(self, safe, txn_id: TxnId) -> None:
+        self.non_home.pop(txn_id, None)
         self._refresh(safe, txn_id)
 
     def stable(self, safe, txn_id: TxnId) -> None:
+        self.non_home.pop(txn_id, None)
         self._track_home(safe, txn_id)
         self._refresh(safe, txn_id)
         # do NOT pop blocked here: a dep that reached Stable locally can
@@ -407,6 +451,7 @@ class SimpleProgressLog(api.ProgressLog):
 
     def durable(self, safe, txn_id: TxnId) -> None:
         self.home.pop(txn_id, None)
+        self.non_home.pop(txn_id, None)
         self.blocked.pop(txn_id, None)
 
     def waiting(self, blocked_by: TxnId, blocked_until: int, route,
@@ -420,6 +465,7 @@ class SimpleProgressLog(api.ProgressLog):
 
     def clear(self, txn_id: TxnId) -> None:
         self.home.pop(txn_id, None)
+        self.non_home.pop(txn_id, None)
         self.blocked.pop(txn_id, None)
 
 

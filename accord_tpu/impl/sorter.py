@@ -61,8 +61,13 @@ def pick_read_nodes(node, trackers, topology) -> set:
     """One replica per execution shard: self where possible, otherwise the
     replica covering the most of the topology — so one node can serve many
     shards and the read fan-out stays small (ref: ReadTracker's initial
-    contact ordering via the TopologySorter)."""
+    contact ordering via the TopologySorter).  A replica the sink knows to
+    be down is not chosen while the shard has another: a shard gets ONE
+    read, and one sent to a dead replica costs a failure and a second ask
+    before any data moves (counted in ``node.n_reads_to_down_replica`` where
+    every replica of a shard is down and one is asked all the same)."""
     scores = SizeOfIntersectionSorter.scores(topology)
+    down = node.message_sink.is_known_down
     chosen: set = set()
     for t in trackers:
         shard = t.shard
@@ -70,6 +75,10 @@ def pick_read_nodes(node, trackers, topology) -> set:
             continue
         if node.node_id in shard.nodes:
             chosen.add(node.node_id)
-        else:
-            chosen.add(min(shard.nodes, key=lambda n: (-scores.get(n, 0), n)))
+            continue
+        live = [n for n in shard.nodes if not down(n)]
+        if not live:
+            node.n_reads_to_down_replica += 1
+        chosen.add(min(live or shard.nodes,
+                       key=lambda n: (-scores.get(n, 0), n)))
     return chosen

@@ -91,6 +91,9 @@ class Node:
         self.command_stores = CommandStores(self, num_stores)
         self.journal = journal
         self.alive = True
+        # reads sent to a replica the sink knew to be down, for want of a
+        # live one in its shard (impl/sorter.pick_read_nodes)
+        self.n_reads_to_down_replica = 0
         self._hlc = 0
         self._hlc_reserved = 0
         if journal is not None:

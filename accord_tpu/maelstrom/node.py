@@ -116,7 +116,17 @@ class MaelstromSink(api.MessageSink):
     a completed request must not leave a dead callback reachable for the
     full timeout horizon, and per-request deterministic jitter (dedicated
     stream, protocol RNG untouched) desynchronizes co-scheduled timeouts
-    so they cannot fire as one retry storm."""
+    so they cannot fire as one retry storm.
+
+    A peer the transport KNOWS is gone (``process.peer_known_down``: its
+    link lost the connection and was refused on the re-dial) is not waited
+    for (ref: Cassandra's messaging fails requests to an endpoint its
+    failure detector has down): a callback to it fails at the next
+    scheduler hop (a ``Timeout``, only sooner) and the callbacks pending on
+    it fail when its link reports the loss (``fail_peer``).  What is still
+    emitted towards it its link drops and counts.  A reply that still
+    arrives finds no pending entry and is dropped, as after a timeout.  A
+    peer that dies without its link noticing is still the sweeper's."""
 
     def __init__(self, process: "MaelstromProcess",
                  jitter: Optional[RandomSource] = None):
@@ -126,6 +136,13 @@ class MaelstromSink(api.MessageSink):
         self._timeouts: List[List] = []   # [deadline, msg_id] min-heap
         self._tombstones = 0              # resolved entries still heaped
         self._jitter = jitter
+        # does the transport know a peer is down (a process without links
+        # knows no such thing)
+        self._peer_down = getattr(process, "peer_known_down", None)
+        # how callbacks failed (obs.metrics.PEER_COUNTERS names them)
+        self.n_failed_at_once = 0         # peer known down at the send
+        self.n_failed_by_drop = 0         # pending when its link dropped
+        self.n_timed_out = 0              # the sweeper's
 
     def _msg_id(self) -> int:
         self._next_msg_id += 1
@@ -133,6 +150,9 @@ class MaelstromSink(api.MessageSink):
 
     def _emit(self, to: int, body: dict) -> None:
         self.process.emit_packet(to, body)
+
+    def is_known_down(self, to: int) -> bool:
+        return self._peer_down is not None and self._peer_down(to)
 
     def _is_self(self, to: int) -> bool:
         node = getattr(self.process, "node", None)
@@ -178,6 +198,12 @@ class MaelstromSink(api.MessageSink):
                         "payload": self._encode_request(request)})
 
     def send_with_callback(self, to: int, request, callback) -> None:
+        if self.is_known_down(to):
+            # deferred, never reentrant: the sender is still fanning out
+            self.n_failed_at_once += 1
+            self.process.scheduler.now(lambda: callback.on_failure(
+                to, Timeout(msg=f"peer {to} is down")))
+            return
         msg_id = self._msg_id()
         timeout = self.process.request_timeout_micros
         # barrier reads (commit-fused reads, WaitOnCommit) reply only when
@@ -221,6 +247,20 @@ class MaelstromSink(api.MessageSink):
             if self._tombstones > 64 and self._tombstones > len(self.pending):
                 self._compact_timeouts()
         return p
+
+    def fail_peer(self, to: int) -> None:
+        """The link to ``to`` reports it gone: every callback pending on it
+        fails now, not at its deadline.  One that raises is the node's
+        failure, not the others' and not the caller's."""
+        for msg_id in [m for m, p in self.pending.items() if p.to == to]:
+            p = self._resolve(msg_id)
+            if p is None:       # an earlier callback resolved it
+                continue
+            self.n_failed_by_drop += 1
+            try:
+                p.callback.on_failure(to, Timeout(msg=f"peer {to} went down"))
+            except Exception as e:  # noqa: BLE001
+                self.process.failures.append(e)
 
     def _compact_timeouts(self) -> None:
         self._timeouts = [q.entry for q in self.pending.values()]
@@ -290,6 +330,7 @@ class MaelstromSink(api.MessageSink):
             p = self.pending.pop(msg_id, None)
             if p is None:
                 continue
+            self.n_timed_out += 1
             p.callback.on_failure(p.to, Timeout(msg=f"timeout to {p.to}"))
 
     # -- inbound ------------------------------------------------------------
@@ -380,6 +421,9 @@ class MaelstromProcess:
         # where unknown (non-protocol) bodies go — the TCP server routes
         # them back into its control plane (batch-envelope riders)
         self.control_fallback = None
+        # name -> bool: does the transport know this peer is down (the TCP
+        # server answers from its links; None = nobody knows such a thing)
+        self.link_down: Optional[Callable[[str], bool]] = None
         self.name: Optional[str] = None
         self.node: Optional[Node] = None
         self.sink: Optional[MaelstromSink] = None
@@ -398,6 +442,10 @@ class MaelstromProcess:
                 or j.commit.failed:
             return None
         return j
+
+    def peer_known_down(self, to: int) -> bool:
+        return self.link_down is not None \
+            and self.link_down(self._names_by_id.get(to, to))
 
     def note_peer(self, name: str) -> None:
         """Register a peer name->id mapping learned AFTER init (a node

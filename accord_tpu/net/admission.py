@@ -16,12 +16,15 @@ degrade loudly, never die):
 - **Latency-aware AIMD controller** — the gate observes every admitted
   txn's completion latency (the txn ROOT SPAN duration: the observation
   window is admission -> client reply, the same boundaries the r09 span
-  tree stamps for ``txn``, measured here directly so the controller also
-  works under ``ACCORD_TPU_OBS=off``).  When the sliding-window p99
-  exceeds ``target_p99_micros`` the dynamic budget shrinks
-  multiplicatively; while p99 sits comfortably below target it recovers
-  additively — classic AIMD, converging to the deepest pipeline the
-  latency target allows.
+  tree stamps for ``txn``, measured here directly and exactly, so the
+  controller also works under ``ACCORD_TPU_OBS=off``).  When the p99 of
+  the completions since the last cut exceeds ``target_p99_micros`` the
+  dynamic budget shrinks multiplicatively; while p99 sits comfortably
+  below target it recovers additively — classic AIMD, converging to the
+  deepest pipeline the latency target allows.  A cut consumes its
+  evidence (the next one needs completions of its own), and a p99 never
+  rests on the single largest sample of its window: one slow txn is an
+  outlier, not a percentile.
 - **Degradation-ladder composition** — ``device_health`` (wired by the
   server to the r07 quarantine state of the node's stores) scales the
   budget DOWN while any store is quarantined or OOM-degraded: a sick
@@ -61,42 +64,62 @@ class SpanPhaseP99:
     from the span instrumentation (and able to flag a single ballooning
     phase, e.g. a replica-side ``deps_wait``, before the root mean moves).
 
+    ``root`` names the phase to leave out: the serving node leaves out the
+    root ``txn`` span, which the gate measures itself, exactly, where a log2
+    bucket is up to 2x coarse.  A window's read is clamped by what the
+    WINDOW's samples were (``Histogram.take_tops``), never by a maximum from
+    before it: one slow txn in a node's life must not make every later p99
+    in its bucket read as that bucket's upper bound.
+
     Returns None when the spans are disabled (``ACCORD_TPU_OBS=off``) or
-    the window holds too few samples — the gate then falls back to its
-    own root-span measurement, exactly the r12 behaviour."""
+    the window holds too few samples — the gate then rests on its own
+    root-span measurement alone, exactly the r12 behaviour."""
 
     MIN_SAMPLES = 8
 
-    def __init__(self, metrics, name: str = "phase_micros"):
+    def __init__(self, metrics, name: str = "phase_micros",
+                 root: Optional[str] = None):
         self.metrics = metrics
         self.name = name
+        self.root = root
         self._prev: Dict[Tuple, Dict[int, int]] = {}
 
     def read(self) -> Optional[int]:
-        from ..obs.metrics import Histogram
         worst = None
         for (n, labels), h in sorted(self.metrics._m.items()):
-            if n != self.name or not hasattr(h, "buckets"):
+            if n != self.name or not hasattr(h, "buckets") \
+                    or ("phase", self.root) in labels:
                 continue
             prev = self._prev.get(labels, {})
             delta = {b: c - prev.get(b, 0) for b, c in h.buckets.items()
                      if c - prev.get(b, 0) > 0}
             self._prev[labels] = dict(h.buckets)
+            tops = h.take_tops()
             count = sum(delta.values())
             if count < self.MIN_SAMPLES:
                 continue
-            # reuse the registry histogram's percentile (its min/max
-            # clamp keeps the log2 bucket's up-to-2x upper-bound bias
-            # out of the controller: a steady true p99 just over a
-            # power of two must not read as nearly double the target)
-            w = Histogram()
-            w.buckets = delta
-            w.count = count
-            w.vmin, w.vmax = h.vmin, h.vmax
-            p99 = w.percentile(0.99)
-            if p99 is not None and (worst is None or p99 > worst):
-                worst = p99
+            # the bucket that holds the window's p99 rank, read as the
+            # largest value that bucket took IN the window (the log2
+            # bucket's up-to-2x upper bound stays out of the controller:
+            # a steady true p99 just over a power of two must not read
+            # as nearly double the target)
+            need, seen = p99_rank(count) + 1, 0
+            for b in sorted(delta):
+                seen += delta[b]
+                if seen >= need:
+                    p99 = tops.get(b, (1 << b) - 1 if b > 0 else 0)
+                    if worst is None or p99 > worst:
+                        worst = p99
+                    break
         return worst
+
+
+def p99_rank(n: int) -> int:
+    """0-based rank of the 99th percentile among ``n`` sorted samples, and
+    never the largest of them while there is another: below some hundred
+    samples the 99th percentile IS the maximum, and a controller that cuts
+    on one slow sample in 32 steers by the p97, upwards only."""
+    return min(max(0, n - 2), (n * 99) // 100)
 
 
 class AdmissionGate:
@@ -108,10 +131,9 @@ class AdmissionGate:
     comparisons and an increment.
 
     When ``phase_p99`` is wired (a :class:`SpanPhaseP99` reader over the
-    obs registry), the controller's latency signal comes from the span
-    trees' per-phase histograms instead; the root-span sliding window is
-    kept as the fallback so the gate still works under
-    ``ACCORD_TPU_OBS=off``.
+    obs registry), the controller's latency signal is the larger of the
+    gate's own root measurement and the span trees' worst per-phase read;
+    the root alone keeps the gate working under ``ACCORD_TPU_OBS=off``.
     """
 
     # controller shape: recompute every ADJUST_EVERY completions; cut the
@@ -138,6 +160,7 @@ class AdmissionGate:
         self.inflight = 0
         self.dyn_budget = float(max_inflight)
         self._lat = deque(maxlen=window)
+        self._fresh = 0   # completions recorded since the last cut
         self._since_adjust = 0
         self._p99: Optional[int] = None
         self._p99_source = "root"
@@ -151,10 +174,14 @@ class AdmissionGate:
     def sliding_p99(self) -> Optional[int]:
         """p99 over the completion window (recomputed lazily at adjust
         points; this forces a fresh read)."""
-        if not self._lat:
+        return self._p99_of_last(len(self._lat))
+
+    def _p99_of_last(self, n: int) -> Optional[int]:
+        n = min(n, len(self._lat))
+        if n <= 0:
             return None
-        xs = sorted(self._lat)
-        return xs[min(len(xs) - 1, (len(xs) * 99) // 100)]
+        xs = sorted(list(self._lat)[-n:])
+        return xs[p99_rank(n)]
 
     def health(self) -> float:
         if self.device_health is None:
@@ -218,29 +245,31 @@ class AdmissionGate:
         if duration_micros is None:
             return
         self._lat.append(int(duration_micros))
+        self._fresh += 1
         self._since_adjust += 1
         if self._since_adjust >= self.ADJUST_EVERY:
             self._since_adjust = 0
             self._adjust()
 
     def _adjust(self) -> None:
-        p99 = None
+        # the root, exactly, over the completions since the last cut (those
+        # before it were the evidence of that cut), and the span-tree feed
+        # (ROADMAP item 4 remainder): the worst per-phase p99 of the window
+        # between adjust points, None with obs off or too few samples
+        p99 = self._p99_of_last(self._fresh)
         self._p99_source = "root"
         if self.phase_p99 is not None:
-            # span-tree feed (ROADMAP item 4 remainder): worst per-phase
-            # p99 of the window between adjust points; None (obs off /
-            # too few samples) falls through to the root measurement
-            p99 = self.phase_p99()
-            if p99 is not None:
+            spans = self.phase_p99()
+            if spans is not None and (p99 is None or spans > p99):
+                p99 = spans
                 self._p99_source = "spans"
-        if p99 is None:
-            p99 = self.sliding_p99()
         self._p99 = p99
         if p99 is None:
             return
         if p99 > self.target_p99_micros:
             self.dyn_budget = max(float(self.min_budget),
                                   self.dyn_budget * self.CUT)
+            self._fresh = 0
             self.n_latency_cuts += 1
             if self.metrics is not None:
                 self.metrics.counter("admission_latency_cuts").inc()

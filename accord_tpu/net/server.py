@@ -70,15 +70,22 @@ class AsyncioScheduler(api.Scheduler):
 
     def __init__(self, loop: asyncio.AbstractEventLoop):
         self.loop = loop
+        self.stopped = False
+
+    def stop(self) -> None:
+        """Nothing scheduled through here runs from now on, and nothing
+        more is taken (a crash-stopped node's timers die with it)."""
+        self.stopped = True
 
     def now(self, run: Callable[[], None]) -> None:
-        self.loop.call_soon(run)
+        if not self.stopped:
+            self.loop.call_soon(run)
 
     def once(self, delay_micros: int, run: Callable[[], None]) -> api.Scheduled:
         sched = _Scheduled()
 
         def fire():
-            if not sched.cancelled:
+            if not sched.cancelled and not self.stopped:
                 run()
         sched.handle = self.loop.call_later(delay_micros / 1e6, fire)
         return sched
@@ -88,7 +95,7 @@ class AsyncioScheduler(api.Scheduler):
         sched = _Scheduled()
 
         def fire():
-            if sched.cancelled:
+            if sched.cancelled or self.stopped:
                 return
             try:
                 run()
@@ -190,6 +197,8 @@ class NodeServer:
         self.batch_sizes: Dict[int, int] = {}   # envelope occupancy census
         self.n_unbatched_envelopes = 0  # envelopes received
         self.n_fast_sheds = 0          # sheds decided pre-body-decode
+        self.scheduler: Optional[AsyncioScheduler] = None
+        self.crashed = False           # crash_stop() ran: nothing in or out
 
     def now_micros(self) -> int:
         return (time.monotonic_ns() - self._start_ns) // 1_000
@@ -299,6 +308,8 @@ class NodeServer:
             ent[1].append(frame)
 
     def _emit(self, dest, body: dict) -> None:
+        if self.crashed:
+            return
         if dest in self.links:
             # peer fan-out: batch within this event-loop tick — N ops'
             # protocol messages to one peer become one envelope, one
@@ -392,6 +403,9 @@ class NodeServer:
             # catch-up/gossip trigger (epochless pre-r17 hellos and
             # mixed-epoch streams interoperate: the field is optional)
             self._peer_hello[src] = body
+            link = self.links.get(src)
+            if link is not None:
+                link.poke()   # it dials us: our link to it need not wait
             v = body.get("version", 0)
             if v and v not in wire_codec.SUPPORTED_VERSIONS:
                 print(f"[{self.name}] peer {src} announced unsupported "
@@ -526,8 +540,38 @@ class NodeServer:
         # crc32 is not — the backoff schedule must be reproducible
         jitter = RandomSource(
             0x7C9 ^ zlib.crc32(f"{self.name}->{peer}".encode()))
-        return PeerLink(self.name, peer, host, port, jitter,
+        link = PeerLink(self.name, peer, host, port, jitter,
                         hello_frame=self._hello_frame)
+        link.on_state = self._on_link_state
+        return link
+
+    def _link_down(self, peer: str) -> bool:
+        link = self.links.get(peer)
+        return link is not None and link.down
+
+    def _on_link_state(self, link: PeerLink, down: bool) -> None:
+        """A link learned that its peer is gone (connection lost, re-dial
+        refused) or back (its next hello left).  Gone: every callback
+        pending on it fails at the next scheduler hop
+        (MaelstromSink.fail_peer; never inside the link's own task), which
+        is all a coordination needs to go on with the replicas that are
+        left; what this tick still holds for it the link drops at the
+        flush.  Back: nothing to do, the sink asks the link at every send."""
+        from ..maelstrom.node import node_name_to_id
+        event = "peer_down" if down else "peer_up"
+        print(f"[{self.name}] {event}: {link.peer}", file=sys.stderr)
+        proc = self.proc
+        if proc is None or proc.sink is None:
+            return
+        obs = proc.obs
+        if obs is not None:
+            obs.metrics.counter("peer_link", node=self.name,
+                                event=event).inc()
+            if obs.flight is not None:
+                obs.flight.record(proc.node.node_id, event, peer=link.peer)
+        if down:
+            peer = node_name_to_id(link.peer)
+            proc.scheduler.now(lambda: proc.sink.fail_peer(peer))
 
     def ensure_link(self, peer: str, host: str, port: int) -> bool:
         """Dial-on-join: create (and start, when the loop is live) an
@@ -629,6 +673,7 @@ class NodeServer:
             "client_replies": self.n_client_replies,
             "coordination": self._coordination_stats(),
             "data": self._data_stats(),
+            "peer_failures": self._peer_failure_stats(),
             "unroutable": self.n_unroutable,
             "reply_drops": self.n_reply_drops,
             "frame_errors": (self.frame_server.n_frame_errors
@@ -696,6 +741,23 @@ class NodeServer:
             "scan_rows": self.proc.n_scan_rows,
         }
 
+    def _peer_failure_stats(self) -> Optional[dict]:
+        """How this node's request callbacks failed (at once on a link known
+        down, with the link's drop while pending, by the sweeper's timeout),
+        the frames its links did not take for a down peer, the reads whose
+        first choice of replica was down, and the links' ``peer_down`` /
+        ``peer_up`` events; None before start()."""
+        from ..obs.metrics import PEER_COUNTERS
+        proc = self.proc
+        if proc is None or proc.sink is None:
+            return None
+        out = {k: getattr(proc.sink, attr) for k, attr in PEER_COUNTERS}
+        out["down_drops"] = sum(l.n_down_drops for l in self.links.values())
+        out["reads_to_down_replica"] = proc.node.n_reads_to_down_replica
+        out["peer_down_events"] = sum(l.n_downs for l in self.links.values())
+        out["peer_up_events"] = sum(l.n_ups for l in self.links.values())
+        return out
+
     def _data_stats(self) -> Optional[dict]:
         """Calls of, and host clock inside, this replica's data store's
         range read (KVDataStore.read_range); None before start()."""
@@ -723,7 +785,7 @@ class NodeServer:
         self.loop = asyncio.get_event_loop()
         faults.arm_socket_faults_from_env()
         faults.arm_disk_faults_from_env()
-        scheduler = AsyncioScheduler(self.loop)
+        scheduler = self.scheduler = AsyncioScheduler(self.loop)
         obs = Observability(now=self.now_micros)
         if self.journal_dir:
             # durable journal (r13): recover-or-create BEFORE the node
@@ -739,7 +801,8 @@ class NodeServer:
 
             self.journal = open_journal(
                 self.journal_dir,
-                defer=lambda delay_s, fn: self.loop.call_later(delay_s, fn),
+                defer=lambda delay_s, fn: self.loop.call_later(
+                    delay_s, lambda: None if self.crashed else fn()),
                 window_micros=self.journal_window_us,
                 snapshot_every=self.journal_snapshot_every,
                 segment_bytes=self.journal_segment_bytes,
@@ -765,17 +828,19 @@ class NodeServer:
             journal=self.journal)
         self.proc.reconfig = self.reconfig
         self.proc.control_fallback = self._control_fallback
+        self.proc.link_down = self._link_down
         if self.request_timeout_ms is not None:
             self.proc.request_timeout_micros = self.request_timeout_ms * 1000
         # admission gate in front of coordinate, composed with the r07
         # device ladder (quarantine lowers the budget) AND the r17
         # rebalance factor (a store mid-bootstrap prices the budget DOWN
         # — the join/leave load spike is absorbed as a cut, never a
-        # collapse); when the r09 span trees are live their per-phase
-        # p99 drives the AIMD signal (root-span fallback keeps
+        # collapse); when the r09 span trees are live the worst
+        # per-phase p99 below the root joins the gate's own root
+        # measurement in the AIMD signal (the root alone keeps
         # ACCORD_TPU_OBS=off working)
         from .admission import SpanPhaseP99
-        phase_feed = (SpanPhaseP99(obs.metrics).read
+        phase_feed = (SpanPhaseP99(obs.metrics, root="txn").read
                       if obs.spans is not None else None)
         self.gate = AdmissionGate(
             max_inflight=self.admit_max,
@@ -850,6 +915,27 @@ class NodeServer:
               f"coalesce_us={coalesce_window_micros()}",
               file=sys.stderr, flush=True)
 
+    def crash_stop(self) -> None:
+        """Stop as a killed process stops, in a process that hosts other
+        nodes too: the listening socket and every connection, in and out,
+        are reset with no goodbye, what was queued is never sent, every
+        timer (sweeper, progress logs, audit, snapshot, journal window) is
+        dead, nothing is flushed and the journal is left as it lies.  An
+        EMULATION: the object's memory stays, a restart is a new NodeServer
+        on the same address.  Synchronous: when it returns, the node is
+        silent."""
+        self.crashed = True
+        if self.scheduler is not None:
+            self.scheduler.stop()
+        if self.frame_server is not None:
+            self.frame_server.abort()
+        for link in self.links.values():
+            link.abort()
+        self._peer_pend.clear()
+        self._client_pend.clear()
+        if self.proc is not None and self.proc.node is not None:
+            self.proc.node.alive = False
+
     async def close(self) -> None:
         # nothing in and nothing out (cancelling a link waits on the loop,
         # not on its peer), then the final flush, and only then the wait on
@@ -861,8 +947,13 @@ class NodeServer:
             await link.close()
         if self.journal is not None:
             try:
-                self.journal.close()   # final flush (graceful exit only —
-            except OSError:            # kill -9 relies on recovery)
+                if self.crashed:
+                    # the files only: what the group commit still held is
+                    # lost with the process
+                    self.journal.wal.close()
+                else:
+                    self.journal.close()   # final flush (graceful exit only
+            except OSError:                # — kill -9 relies on recovery)
                 pass
         if self.frame_server is not None:
             await self.frame_server.close()

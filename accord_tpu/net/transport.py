@@ -14,6 +14,15 @@ and never replays on reconnect — so a reply racing a reconnect can only
 arrive zero or one times, and the sink's pending-table pop makes dispatch
 idempotent even against a reply racing its own timeout.
 
+Peer state: a link is DOWN from the moment it has lost a connection and its
+re-dial was refused or failed, and up again once its next hello has gone out.
+Nothing is configured: it is what the link knows anyway.  While down it
+keeps re-dialing on its backoff, takes no frame (``send`` counts and drops:
+whoever waits for an answer has been told, see ``on_state``) and holds none.
+A link that never had a connection is not down: its peer may be starting.
+A peer that goes silent without closing its socket is not down either: the
+sink's request timeout owns that case.
+
 Write coalescing (r16): frames queued on a link within one event-loop
 tick leave in ONE joined write — the r12 transport paid one ``write`` +
 ``drain`` round per frame, which at a dozen protocol frames per txn was a
@@ -150,10 +159,21 @@ class PeerLink:
         self._jitter = jitter
         self._queue: asyncio.Queue = asyncio.Queue(maxsize=max_queue)
         self._task: Optional[asyncio.Task] = None
+        self._writer: Optional[asyncio.StreamWriter] = None
+        self._wake = asyncio.Event()   # poke(): cut the backoff short
         self._hello = hello_frame
         self._linger_s = (coalesce_window_micros()
                           if linger_micros is None else linger_micros) / 1e6
         self.connected = False
+        # the peer is known gone: a connection was lost and the re-dial
+        # after it refused or failed; cleared by the next hello that leaves
+        self.down = False
+        # told (link, down) at every change of ``down``, on the loop
+        self.on_state: Optional[Callable[["PeerLink", bool], None]] = None
+        self.n_downs = 0
+        self.n_ups = 0
+        self.n_enqueued = 0        # frames ``send`` took
+        self.n_down_drops = 0      # frames refused or let go while down
         self.n_connects = 0        # successful dials (first + re-)
         self.n_reconnects = 0      # successful dials after the first
         self.n_dial_failures = 0
@@ -188,9 +208,40 @@ class PeerLink:
             except (asyncio.CancelledError, Exception):
                 pass
 
+    def poke(self) -> None:
+        """The peer was heard from (its own link's hello came in): a link
+        that waits out a backoff re-dials now, and starts its backoff over
+        if that dial fails too.  (A link learns of its peer's death while
+        idle and has backed off far by the time the peer is back.)"""
+        if not self.connected:
+            self._wake.set()
+
+    async def _pause(self, micros: int) -> bool:
+        """Sleep ``micros``, or until poked (True)."""
+        try:
+            await asyncio.wait_for(self._wake.wait(), micros / 1e6)
+        except asyncio.TimeoutError:
+            return False
+        self._wake.clear()
+        return True
+
+    def abort(self) -> None:
+        """Stop as a killed process stops: the connection is reset, what
+        was queued is never sent, nothing says goodbye.  The link is dead
+        afterwards (no re-dial)."""
+        if self._task is not None:
+            self._task.cancel()
+        if self._writer is not None:
+            self._writer.transport.abort()
+
     def send(self, frame: bytes) -> None:
         """Enqueue one frame (drop-oldest beyond the bound: the transport
-        never buffers unboundedly — the sink's timeout owns recovery)."""
+        never buffers unboundedly — the sink's timeout owns recovery).  A
+        link that knows its peer is down takes none."""
+        if self.down:
+            self.n_down_drops += 1
+            return
+        self.n_enqueued += 1
         while True:
             try:
                 self._queue.put_nowait(frame)
@@ -210,11 +261,16 @@ class PeerLink:
                     self.host, self.port)
             except (OSError, asyncio.TimeoutError):
                 self.n_dial_failures += 1
-                await asyncio.sleep(
-                    backoff_micros(attempt, self._jitter) / 1e6)
-                attempt += 1
+                if self.n_connects and not self.down:
+                    # it had a connection, and cannot have it back
+                    self._set_down(True)
+                poked = await self._pause(
+                    backoff_micros(attempt, self._jitter))
+                attempt = 0 if poked else attempt + 1
                 continue
             self.connected = True
+            self._wake.clear()
+            self._writer = writer
             self.n_connects += 1
             if self.n_connects > 1:
                 self.n_reconnects += 1
@@ -226,18 +282,58 @@ class PeerLink:
                     writer.write(self._hello)
                     self.bytes_tx += len(self._hello)
                     await writer.drain()
-                await self._pump(writer)
+                if self.down:
+                    self._set_down(False)
+                await self._serve(reader, writer)
             except (ConnectionError, OSError, asyncio.IncompleteReadError):
                 pass
             finally:
                 self.connected = False
+                self._writer = None
                 try:
                     writer.close()
                 except Exception:
                     pass
             # brief jittered pause even on a clean drop so a flapping
             # acceptor isn't hammered at loop speed
-            await asyncio.sleep(backoff_micros(0, self._jitter) / 1e6)
+            await self._pause(backoff_micros(0, self._jitter))
+
+    def _set_down(self, down: bool) -> None:
+        self.down = down
+        if down:
+            self.n_downs += 1
+            # what was queued for the peer goes with it (never replayed:
+            # the owner fails whatever waited on these frames)
+            while not self._queue.empty():
+                self._queue.get_nowait()
+                self.n_down_drops += 1
+        else:
+            self.n_ups += 1
+        if self.on_state is not None:
+            self.on_state(self, down)
+
+    async def _serve(self, reader: asyncio.StreamReader,
+                     writer: asyncio.StreamWriter) -> None:
+        """Pump the queue until the connection ends: a write fails, or the
+        peer closes its end.  Nothing ever comes back on an outbound link,
+        so the end of its read side IS the peer's close, and an idle link
+        learns of it here and not at its next write."""
+        loop = asyncio.get_event_loop()
+        tasks = (loop.create_task(self._pump(writer)),
+                 loop.create_task(self._until_peer_closes(reader)))
+        try:
+            await asyncio.wait(tasks, return_when=asyncio.FIRST_COMPLETED)
+        finally:
+            for t in tasks:
+                t.cancel()
+            # collects what the two ended with (a reset, a cancellation);
+            # a cancellation of THIS task still propagates
+            await asyncio.gather(*tasks, return_exceptions=True)
+
+    @staticmethod
+    async def _until_peer_closes(reader: asyncio.StreamReader) -> None:
+        while await reader.read(4096):
+            pass
 
     def _drain_batch(self, batch: List[bytes], budget: int) -> int:
         """Greedily move every queued frame into ``batch`` up to the byte
@@ -301,6 +397,9 @@ class PeerLink:
 
     def stats(self) -> dict:
         return {"peer": self.peer, "connected": self.connected,
+                "down": self.down, "downs": self.n_downs,
+                "ups": self.n_ups, "enqueued": self.n_enqueued,
+                "down_drops": self.n_down_drops,
                 "connects": self.n_connects,
                 "reconnects": self.n_reconnects,
                 "dial_failures": self.n_dial_failures,
@@ -350,6 +449,14 @@ class FrameServer:
             self._server.close()
         for writer in self._writers:
             writer.close()
+
+    def abort(self) -> None:
+        """stop(), and what was accepted is reset, not closed: unsent
+        replies are lost and the other ends read a reset, as from a killed
+        process."""
+        self.stop()
+        for writer in self._writers:
+            writer.transport.abort()
 
     async def close(self) -> None:
         self.stop()

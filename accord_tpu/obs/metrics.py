@@ -48,12 +48,16 @@ class Histogram:
     (bucket i covers [2^(i-1), 2^i - 1]; 0 lands in bucket 0).  Integer
     arithmetic only, so same-seed sim-time observations snapshot
     byte-identically.  Exact min/max ride along to tighten the percentile
-    read-out at the distribution's edges."""
+    read-out at the distribution's edges; ``tops`` holds the largest value
+    each bucket took since ``take_tops()`` was last called, for the one
+    reader that diffs the buckets over a window (the admission gate) and
+    must not clamp a window's read by a maximum from before it."""
 
-    __slots__ = ("buckets", "count", "total", "vmin", "vmax")
+    __slots__ = ("buckets", "count", "total", "vmin", "vmax", "tops")
 
     def __init__(self):
         self.buckets: Dict[int, int] = {}
+        self.tops: Dict[int, int] = {}
         self.count = 0
         self.total = 0
         self.vmin: Optional[int] = None
@@ -69,6 +73,13 @@ class Histogram:
             self.vmin = v
         if self.vmax is None or v > self.vmax:
             self.vmax = v
+        if v > self.tops.get(b, -1):
+            self.tops[b] = v
+
+    def take_tops(self) -> Dict[int, int]:
+        """bucket -> the largest value it took since the last call."""
+        tops, self.tops = self.tops, {}
+        return tops
 
     def percentile(self, q: float):
         """The upper bound of the first bucket whose cumulative count
@@ -299,6 +310,16 @@ INDEX_COUNTERS: List[Tuple[str, str]] = [
     # 1 and 1 where the dirty rows and cells cross as one staging buffer
     ("sync_launches", "n_sync_launches"),
     ("sync_uploads", "n_sync_uploads"),
+]
+
+
+# (stats key, MaelstromSink attribute): how a served node's request
+# callbacks failed (NodeServer.stats()["peer_failures"]; plain ints on the
+# sink's hot path)
+PEER_COUNTERS: List[Tuple[str, str]] = [
+    ("failed_at_once", "n_failed_at_once"),    # peer known down at the send
+    ("failed_by_drop", "n_failed_by_drop"),    # pending when the link dropped
+    ("timed_out", "n_timed_out"),              # the sweeper's request timeout
 ]
 
 

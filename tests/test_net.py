@@ -430,6 +430,65 @@ def test_admission_sliding_p99_reads_window():
     assert 95 <= g.sliding_p99() <= 99
 
 
+def test_admission_one_slow_completion_is_no_p99():
+    """A p99 never rests on the single largest sample of its window: one
+    txn over target among 32 cuts nothing, two do."""
+    g = AdmissionGate(max_inflight=64, target_p99_micros=1_000_000)
+    for i in range(g.ADJUST_EVERY):
+        g.try_admit()
+        g.release(1_500_000 if i == 7 else 600_000)
+    assert g.n_latency_cuts == 0 and g.dyn_budget == 64
+    for i in range(g.ADJUST_EVERY):
+        g.try_admit()
+        g.release(1_500_000 if i in (3, 20) else 600_000)
+    assert g.n_latency_cuts == 1
+
+
+def test_admission_a_cut_consumes_its_evidence():
+    """The slow completions that caused a cut stay in the sliding window
+    for hundreds of completions more; they are no reason to cut again, or a
+    budget that does not bind drifts to its floor on one bad second."""
+    g = AdmissionGate(max_inflight=64, target_p99_micros=1_000_000)
+    for _ in range(g.ADJUST_EVERY):
+        g.try_admit()
+        g.release(2_000_000)
+    assert g.n_latency_cuts == 1
+    cut = g.dyn_budget
+    for _ in range(8 * g.ADJUST_EVERY):
+        g.try_admit()
+        g.release(800_000)        # inside the hysteresis band: no move
+    assert g.n_latency_cuts == 1 and g.dyn_budget == cut
+    assert g.sliding_p99() == 2_000_000   # the read-out keeps the truth
+    for _ in range(g.ADJUST_EVERY):
+        g.try_admit()
+        g.release(2_000_000)      # fresh evidence cuts again
+    assert g.n_latency_cuts == 2
+
+
+def test_span_phase_p99_is_clamped_by_its_window_not_by_the_lifetime():
+    """One sample past a power of two, once in a node's life, must not make
+    every later window whose p99 falls in that bucket read as the bucket's
+    upper bound (1,048,575 us against a 1 s target)."""
+    from accord_tpu.net.admission import SpanPhaseP99
+    from accord_tpu.obs.metrics import MetricsRegistry
+    m = MetricsRegistry()
+    reader = SpanPhaseP99(m, root="txn")
+    h = m.histogram("phase_micros", phase="read")
+    for v in [1_200_000, 1_300_000] + [600_000] * 30:
+        h.observe(v)
+    assert reader.read() == 1_300_000      # its bucket's largest IN the
+    for v in [700_000, 640_000] + [300_000] * 30:
+        h.observe(v)
+    assert reader.read() == 700_000        # window; not 1,048,575 at 1 s
+    # the root is the gate's own to measure: left out where it is named
+    root = m.histogram("phase_micros", phase="txn")
+    for _ in range(32):
+        root.observe(5_000_000)
+        h.observe(100_000)
+    assert reader.read() == 100_000
+    assert SpanPhaseP99(m).read() == 5_000_000
+
+
 def test_device_health_of_counts_quarantined_stores():
     class Dev:
         host_pinned = False
