@@ -313,6 +313,25 @@ def _compact_rows(valid: jnp.ndarray, codes: jnp.ndarray, s: int, k: int):
     return counts, row_end, ent
 
 
+def _entry_rows(row_end: jnp.ndarray, s: int) -> jnp.ndarray:
+    """The query row of each cell of a compacted entry buffer, DERIVED from
+    the rows' ends (``row_end`` [B], non-decreasing: the prefix sum of the
+    rows' counts) instead of searched for.  Returns int32[s].
+
+    Identity: ``searchsorted(row_end, p, side="right")`` is the number of
+    rows whose end is <= p, so one mark per row at its end (an empty row
+    shares its end with its predecessor: the marks add up, which is what
+    ``side="right"`` means) and a prefix sum over the cells give every
+    cell's row at once — a B-point scatter-add and one int32 scan, where
+    the search is a log2(B)-deep loop of s-wide gathers out of an
+    emulated-int64 table (28 ms against 0.2 ms on a v5e at B = 2048,
+    s = 163,840: PERF.md, PR 34).  Ends at or beyond the buffer's (an
+    exactly full or overflowed budget) mark no cell."""
+    marks = jnp.zeros(s, jnp.int32).at[row_end.astype(jnp.int32)].add(
+        1, mode="drop")
+    return jnp.cumsum(marks)
+
+
 def _flat_phase1(table: DepsTable, qmat: jnp.ndarray, m: int, k: int,
                  prune=None):
     """Shared phase 1 of the dense flat kernels: exact mask -> per-row
@@ -872,8 +891,7 @@ def _attr_post(tlo, attr: AttrCols, aidx: AttrIndex, rankb: jnp.ndarray,
     mq = m_t * m
     slot = jnp.clip(code // mq, 0)
     col = jnp.clip(code % mq // m, 0, m_t - 1)
-    row_of = jnp.searchsorted(row_end, pos, side="right")
-    row_of = jnp.minimum(row_of, b - 1)
+    row_of = jnp.minimum(_entry_rows(row_end, s), b - 1)
     key_dep = attr.dom[slot] == 0
     status = attr.status[slot]
     if tok is None:

@@ -28,7 +28,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.deps_kernel import (SLOT_APPLIED, SLOT_COMMITTED, SLOT_FREE,
                                SLOT_INVALIDATED, SLOT_STABLE, BucketTable,
-                               DepsQuery, DepsTable, calculate_deps)
+                               DepsQuery, DepsTable, _entry_rows,
+                               calculate_deps)
 from ..ops.drain_kernel import DrainState
 from ..ops.packing import masked_ts_max, ts_lt
 
@@ -254,8 +255,7 @@ def _merge_shard_blocks(hdrs, ents, b: int, s: int, codespace: int,
     totals = hdrs[:, 0].astype(jnp.int64)
     row_end = hdrs[:, 5:].astype(jnp.int64)                    # [d, B]
     pos = jnp.arange(s, dtype=jnp.int64)
-    row_of = jax.vmap(lambda re: jnp.searchsorted(re, pos, side="right"))(
-        row_end)                                               # [d, s]
+    row_of = jax.vmap(lambda re: _entry_rows(re, s))(row_end)   # [d, s]
     live = pos[None, :] < totals[:, None]
     inf = jnp.int64(np.iinfo(np.int64).max)
     code = ents.astype(jnp.int64)
