@@ -141,21 +141,42 @@ class Recover(api.Callback):
 
     @staticmethod
     def recover(node, txn_id: TxnId, route: Route,
-                txn: Optional[Txn] = None) -> async_chain.AsyncChain:
+                txn: Optional[Txn] = None, cause: str = "asked",
+                idle_micros: Optional[int] = None) -> async_chain.AsyncChain:
+        """``cause``: who asked (``home.Expected`` / ``home.NoProgress``:
+        the progress log's scan, by the state it found the txn in;
+        ``watchdog``: the coordinator's own; ``adopt``: a fence-rejected
+        retry; ``asked``: Node.recover); ``idle_micros``: how long the txn
+        had not progressed by the asker's clock.  Both ride the ``recover``
+        PHASE span this opens on the txn's tree (so ``phase_micros{phase=
+        recover}`` exists and the flight recorder sees it), closed with the
+        outcome when the recovery settles."""
         result = async_chain.AsyncResult()
+        sp = spans_of(node)
+        if sp is not None:
+            attrs = {"cause": cause}
+            if idle_micros is not None:
+                attrs["idle_micros"] = idle_micros
+            span = sp.begin(str(txn_id), "recover", node=node.node_id,
+                            **attrs)
+            result.begin(lambda value, failure: sp.end(
+                span, outcome=(type(failure).__name__ if failure is not None
+                               else value[0])))
         if txn is not None:
-            Recover(node, txn_id, txn, route, result)._start()
+            Recover(node, txn_id, txn, route, result, cause)._start()
         else:
-            _fetch_definition_then_recover(node, txn_id, route, result)
+            _fetch_definition_then_recover(node, txn_id, route, result,
+                                           cause)
         return result
 
     def __init__(self, node, txn_id: TxnId, txn: Txn, route: Route,
-                 result: async_chain.AsyncResult):
+                 result: async_chain.AsyncResult, cause: str = "asked"):
         self.node = node
         self.txn_id = txn_id
         self.txn = txn
         self.route = route
         self.result = result
+        self.cause = cause
         self.ballot = Ballot(*_next_ballot_bits(node))
         self.topologies = node.topology().for_epoch(route.participants,
                                                     txn_id.epoch())
@@ -164,7 +185,7 @@ class Recover(api.Callback):
         self.done = False
 
     def _start(self) -> None:
-        _count_recovery(self.node, "attempt")
+        _count_recovery(self.node, "attempt", cause=self.cause)
         sp = spans_of(self.node)
         if sp is not None:
             # one recovery HOP on the txn's span tree (recovery may run on
@@ -307,21 +328,22 @@ class Recover(api.Callback):
                 _count_recovery(self.node, "invalidated"),
                 self.result.set_success(("invalidated", None))),
             on_redundant=lambda: Recover(self.node, self.txn_id, self.txn,
-                                         self.route, self.result)._start(),
+                                         self.route, self.result,
+                                         self.cause)._start(),
             on_failed=self.result.set_failure)
 
 
-def _count_recovery(node, event: str) -> None:
-    """Recovery lifecycle counters (r14): attempts and terminal outcomes,
-    labeled per node, on the shared obs registry — the burn's
-    recovery-under-chaos nemesis and the bench ``recovery_rate`` row read
-    them back via ``counter_totals("recoveries", by="event")``.  Pure
-    counting: no randomness, no protocol effect (one getattr when a node
-    carries no registry)."""
+def _count_recovery(node, event: str, **labels) -> None:
+    """Recovery lifecycle counters (r14): attempts (``cause=``: who asked,
+    Recover.recover) and terminal outcomes, labeled per node, on the shared
+    obs registry — the burn's recovery-under-chaos nemesis and the bench
+    ``recovery_rate`` row read them back via ``counter_totals("recoveries",
+    by="event")``.  Pure counting: no randomness, no protocol effect (one
+    getattr when a node carries no registry)."""
     o = getattr(node, "obs", None)
     if o is not None:
         o.metrics.counter("recoveries", node=node.node_id,
-                          event=event).inc()
+                          event=event, **labels).inc()
 
 
 def _next_ballot_bits(node):
@@ -438,7 +460,8 @@ def _await_commits(node, deps: Deps, done) -> None:
 
 
 def _fetch_definition_then_recover(node, txn_id: TxnId, route: Route,
-                                   result: async_chain.AsyncResult) -> None:
+                                   result: async_chain.AsyncResult,
+                                   cause: str = "asked") -> None:
     """Recovery without the txn definition: CheckStatus(All) a quorum first
     (ref: RecoverWithRoute / FetchData)."""
 
@@ -449,7 +472,7 @@ def _fetch_definition_then_recover(node, txn_id: TxnId, route: Route,
         if merged is not None and merged.partial_txn is not None:
             txn = merged.partial_txn  # PartialTxn is a Txn; re-sliced per replica
             use_route = merged.route if merged.route is not None else route
-            Recover(node, txn_id, txn, use_route, result)._start()
+            Recover(node, txn_id, txn, use_route, result, cause)._start()
             return
         if merged is not None and merged.save_status.status is Status.Invalidated:
             result.set_success(("invalidated", None))
@@ -466,7 +489,7 @@ def _fetch_definition_then_recover(node, txn_id: TxnId, route: Route,
                 _count_recovery(node, "invalidated"),
                 result.set_success(("invalidated", None))),
             on_redundant=lambda: _fetch_definition_then_recover(
-                node, txn_id, route, result),
+                node, txn_id, route, result, cause),
             on_failed=result.set_failure)
 
     _check_status_quorum(node, txn_id, route.participants, txn_id.epoch(),
@@ -479,10 +502,12 @@ def _fetch_definition_then_recover(node, txn_id: TxnId, route: Route,
 
 def maybe_recover(node, txn_id: TxnId, route: Route,
                   prev: ProgressToken,
-                  txn: Optional[Txn] = None) -> async_chain.AsyncChain:
+                  txn: Optional[Txn] = None, cause: str = "asked",
+                  idle_micros: Optional[int] = None
+                  ) -> async_chain.AsyncChain:
     """Cheap CheckStatus probe; escalate to Recover only if nothing has
     progressed past ``prev``.  Settles with ("progressed", token) or the
-    Recover outcome."""
+    Recover outcome.  ``cause`` / ``idle_micros``: Recover.recover's."""
     result = async_chain.AsyncResult()
 
     def on_done(merged: Optional[CheckStatusOk], failure):
@@ -501,7 +526,8 @@ def maybe_recover(node, txn_id: TxnId, route: Route,
         # no observable progress — including complete-but-never-durable txns,
         # whose recovery re-persists and re-sends InformDurable so the home
         # progress log can finally retire the entry
-        Recover.recover(node, txn_id, route, txn).begin(result.settle)
+        Recover.recover(node, txn_id, route, txn, cause,
+                        idle_micros).begin(result.settle)
 
     _check_status_quorum(node, txn_id, route.participants, txn_id.epoch(),
                          IncludeInfo.Route, on_done)

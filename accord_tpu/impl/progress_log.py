@@ -59,20 +59,25 @@ _BLOCKED_BACKOFF_CAP = 128
 
 
 class _HomeEntry:
-    __slots__ = ("txn_id", "route", "progress", "token", "countdown", "backoff")
+    __slots__ = ("txn_id", "route", "progress", "token", "countdown",
+                 "backoff", "since")
 
-    def __init__(self, txn_id: TxnId, route):
+    def __init__(self, txn_id: TxnId, route, now: int):
         self.txn_id = txn_id
         self.route = route
         self.progress = _Progress.Expected
         self.token = ProgressToken.none()
         self.countdown = 2   # scans before investigating
         self.backoff = 2     # doubled on each fruitless investigation
+        # the node's clock when the txn last progressed (or was first
+        # tracked): a recovery's ``idle_micros`` counts from here
+        self.since = now
 
-    def observed_progress(self) -> None:
+    def observed_progress(self, now: int) -> None:
         self.progress = _Progress.Expected
         self.countdown = 2
         self.backoff = 2
+        self.since = now
 
     def no_progress(self) -> None:
         self.progress = _Progress.NoProgress
@@ -153,12 +158,13 @@ class SimpleProgressLog(api.ProgressLog):
             if entry.txn_id in node._coordinating:
                 # a live local coordinator is driving this txn — don't
                 # preempt ourselves (ref: progress log skips local owner)
-                entry.observed_progress()
+                entry.observed_progress(node.now_micros())
                 continue
             entry.countdown -= 1
             if entry.countdown <= 0:
+                asked = entry.progress
                 entry.progress = _Progress.Investigating
-                self._investigate(entry)
+                self._investigate(entry, asked)
         for nh in list(self.non_home.values()):
             nh.countdown -= 1
             if nh.countdown <= 0:
@@ -181,7 +187,10 @@ class SimpleProgressLog(api.ProgressLog):
 
 
     # -- home-shard recovery -------------------------------------------------
-    def _investigate(self, entry: _HomeEntry) -> None:
+    def _investigate(self, entry: _HomeEntry, asked: _Progress) -> None:
+        """``asked``: the state the scan found the entry in (Expected: its
+        first two scans without progress; NoProgress: a backoff ran out):
+        the ``cause`` of the recovery this may start."""
         from ..coordinate.recover import maybe_recover
         node = self.store.node
         txn_id = entry.txn_id
@@ -210,7 +219,7 @@ class SimpleProgressLog(api.ProgressLog):
                              entry.token.status_phase)
                         entry.token = entry.token.merge(info)
                         if organic:
-                            entry.observed_progress()
+                            entry.observed_progress(node.now_micros())
                         else:
                             entry.no_progress()
                     else:
@@ -220,7 +229,10 @@ class SimpleProgressLog(api.ProgressLog):
                     self.home.pop(txn_id, None)
             self._arm()
 
-        maybe_recover(node, txn_id, entry.route, entry.token).begin(on_done)
+        maybe_recover(node, txn_id, entry.route, entry.token,
+                      cause="home." + asked.name,
+                      idle_micros=node.now_micros() - entry.since
+                      ).begin(on_done)
 
     # -- blocked-dependency fetch -------------------------------------------
     def _local_knowledge_maximal(self, txn_id: TxnId) -> bool:
@@ -384,7 +396,8 @@ class SimpleProgressLog(api.ProgressLog):
         node = self.store.node
         if node.is_home_shard_replica(txn_id, cmd.route):
             if txn_id not in self.home:
-                self.home[txn_id] = _HomeEntry(txn_id, cmd.route)
+                self.home[txn_id] = _HomeEntry(txn_id, cmd.route,
+                                               node.now_micros())
         elif undecided and cmd.route.home_key is not None:
             if txn_id not in self.non_home:
                 self.non_home[txn_id] = _NonHomeEntry(txn_id, cmd.route)
@@ -412,7 +425,7 @@ class SimpleProgressLog(api.ProgressLog):
             entry.token = entry.token.merge(ProgressToken(
                 int(cmd.durability), int(cmd.save_status.status.phase),
                 cmd.promised, entry.token.accepted))
-            entry.observed_progress()
+            entry.observed_progress(self.store.node.now_micros())
 
     # -- ProgressLog hooks ---------------------------------------------------
     def unwitnessed(self, safe, txn_id: TxnId) -> None:

@@ -60,6 +60,7 @@ import tempfile
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
+from ..obs import devprof
 from .wal import WriteAheadLog
 
 # window = clamp(WINDOW_FACTOR * probed_fsync, MIN, MAX) micros
@@ -171,6 +172,10 @@ class GroupCommit:
         self.window_micros = (window_micros if window_micros is not None
                               else priced_window_micros(wal.directory))
         self.metrics = metrics
+        # the owner's span table (a serving node's loop_times): a flush
+        # cycle is one ``srv.journal.sync`` span (drain, fsync, account;
+        # an offloaded fsync is a span on the worker's own thread)
+        self.times: Optional[dict] = None
         # r16: optional drain hook run at the top of every flush — the
         # durable journal parks latest-wins facts (register rows) here so
         # one window's worth of transitions serializes ONCE, inside the
@@ -312,6 +317,16 @@ class GroupCommit:
         own lag-horizon flush (crash-equivalent — a latest-wins fact
         deferred to the flush it would have died with changes no
         recoverable state)."""
+        with devprof.span("srv.journal.sync", self.times):
+            tail = self._sync_batch(sync, drain)
+        # the waiters' callbacks (replies leaving) are not the journal's
+        # time: they run after the span
+        if tail is not None:
+            self._release(tail)
+
+    def _sync_batch(self, sync: bool, drain: bool) -> Optional[int]:
+        """flush()'s journal half: the seq everything up to which may be
+        released now, or None when an offloaded fsync took the batch."""
         if drain and self.pre_flush is not None:
             try:
                 # drain deferred latest-wins records INTO this batch (the
@@ -322,15 +337,13 @@ class GroupCommit:
                 print(f"[journal] pre_flush failed: {exc!r}",
                       file=sys.stderr)
         if self.failed:
-            self._release(self.wal.tail_seq)
-            return
+            return self.wal.tail_seq
         pending = self.wal.tail_seq - self.wal.durable_seq
         if pending <= 0:
-            self._release(self.wal.durable_seq)
-            return
+            return self.wal.durable_seq
         if self._offload_pays and not sync:
             self._flush_async()
-            return
+            return None
         # inline path (sync=True, or no worker wired).  If a worker batch
         # is in flight its files were removed from the dirty set — fsync
         # them HERE TOO before claiming their records durable (concurrent
@@ -342,11 +355,10 @@ class GroupCommit:
             self.wal.sync_files(files + self._inflight_files)
         except OSError as exc:
             self._degrade(f"fsync failed: {exc!r}")
-            self._release(self.wal.tail_seq)
-            return
+            return self.wal.tail_seq
         self.wal.complete_sync(tail, reap=not self._sync_inflight)
         self._account(pending, (time.perf_counter_ns() - t0) // 1_000)
-        self._release(tail)
+        return tail
 
     def _flush_async(self) -> None:
         if self._sync_inflight:
@@ -357,13 +369,17 @@ class GroupCommit:
         tail, files = self.wal.begin_sync()
         self._inflight_files = files
         t0 = time.perf_counter_ns()
+        worked: dict = {}     # the worker's span, folded in by done()
 
         def work():
-            self.wal.sync_files(files)
+            with devprof.span("srv.journal.sync", worked):
+                self.wal.sync_files(files)
 
         def done(exc) -> None:
             self._sync_inflight = False
             self._inflight_files = []
+            if self.times is not None:
+                devprof.merge(self.times, worked)
             if exc is not None:
                 # ValueError = file closed under the worker (shutdown
                 # race): same degrade path as a failed fsync, never an

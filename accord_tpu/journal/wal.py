@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import os
 import re
+from time import perf_counter
 from typing import Dict, List, Optional
 
 from . import record as rec_mod
@@ -68,6 +69,13 @@ class WriteAheadLog:
         self.record_codec = (record_codec if record_codec is not None
                              else rec_mod.default_codec())
         os.makedirs(directory, exist_ok=True)
+        # the owner's span table (a serving node's loop_times).  An append
+        # is counted there as ``srv.journal.append`` by a bare clock pair,
+        # no obs.devprof.span: at 27 a txn a span each was the loop's
+        # largest tracing cost, so the profiler's trace does not show an
+        # append (its time is its caller's self time there); None =
+        # nobody's, and no clock
+        self.times: Optional[dict] = None
         # counters (mirrored into obs by the owning journal)
         self.n_appended = 0
         self.n_bytes = 0
@@ -184,6 +192,8 @@ class WriteAheadLog:
     def append(self, doc: dict) -> int:
         """Stamp + frame + write one record; returns its sequence number.
         NOT durable until ``sync`` — the group commit owns that window."""
+        times = self.times
+        t0 = perf_counter() if times is not None else 0.0
         seq = self.tail_seq + 1
         doc = dict(doc)
         doc["s"] = seq
@@ -196,6 +206,12 @@ class WriteAheadLog:
         self.tail_seq = seq
         self.n_appended += 1
         self.n_bytes += len(payload)
+        if times is not None:
+            cell = times.get("srv.journal.append")
+            if cell is None:
+                cell = times["srv.journal.append"] = [0, 0.0]
+            cell[0] += 1
+            cell[1] += perf_counter() - t0
         return seq
 
     def _roll(self, next_seq: int) -> None:

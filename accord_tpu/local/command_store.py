@@ -20,9 +20,9 @@ gathers map-reduce-consume tasks across intersecting stores
 from __future__ import annotations
 
 import bisect
-import time
 from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
+from ..obs import devprof
 from ..primitives.deps import PartialDeps
 from ..primitives.keys import Range, Ranges, RoutingKeys, Unseekables
 from ..primitives.timestamp import Kinds, Timestamp, TxnId
@@ -258,14 +258,16 @@ class CommandStore:
             # journal would await the reads here before scheduling fn.
             self._load_context(context)
             safe = SafeCommandStore(self, context)
-            try:
-                result = fn(safe)
-            except BaseException as e:  # noqa: BLE001
+            with devprof.span("srv.handler",
+                              getattr(self.node, "loop_times", None)):
+                try:
+                    result = fn(safe)
+                except BaseException as e:  # noqa: BLE001
+                    safe.complete()
+                    out.set_failure(e)
+                    return
                 safe.complete()
-                out.set_failure(e)
-                return
-            safe.complete()
-            out.set_success(result)
+                out.set_success(result)
 
         self._queue.append(task)
         self._schedule_drain()
@@ -282,18 +284,23 @@ class CommandStore:
             self._queue.clear()   # the process died with this work pending
             self._draining = False
             return
-        if _STORE_GROUP:
-            self._drain_grouped()
-        else:
-            while self._queue:
-                task = self._queue.pop(0)
-                try:
-                    task()
-                except BaseException as e:  # noqa: BLE001
-                    self.node.agent.on_uncaught_exception(e)
-        self._draining = False
-        if self.paged_limit is not None:
-            self._maybe_page_out()
+        # one drain of the queue: what it spends outside the handler
+        # bodies (contexts merged, the SafeCommandStore, pendings flushed,
+        # paging) is the store set-up of net/profiling.stage_of
+        with devprof.span("srv.store_setup",
+                          getattr(self.node, "loop_times", None)):
+            if _STORE_GROUP:
+                self._drain_grouped()
+            else:
+                while self._queue:
+                    task = self._queue.pop(0)
+                    try:
+                        task()
+                    except BaseException as e:  # noqa: BLE001
+                        self.node.agent.on_uncaught_exception(e)
+            self._draining = False
+            if self.paged_limit is not None:
+                self._maybe_page_out()
 
     def _drain_grouped(self) -> None:
         """Run every same-tick queued op under ONE SafeCommandStore.
@@ -315,21 +322,25 @@ class CommandStore:
                 for context, _fn, _out in batch:
                     self._load_context(context)
             safe = SafeCommandStore(self, _merge_contexts(batch))
-            for _context, fn, out in batch:
-                try:
-                    result = fn(safe)
-                except BaseException as e:  # noqa: BLE001
+            # the batch's handler bodies and their chains' continuations
+            # (replies built and sent): one span a batch
+            with devprof.span("srv.handler",
+                              getattr(self.node, "loop_times", None)):
+                for _context, fn, out in batch:
+                    try:
+                        result = fn(safe)
+                    except BaseException as e:  # noqa: BLE001
+                        safe.flush_pending()
+                        try:
+                            out.set_failure(e)
+                        except BaseException as e2:  # noqa: BLE001
+                            self.node.agent.on_uncaught_exception(e2)
+                        continue
                     safe.flush_pending()
                     try:
-                        out.set_failure(e)
-                    except BaseException as e2:  # noqa: BLE001
-                        self.node.agent.on_uncaught_exception(e2)
-                    continue
-                safe.flush_pending()
-                try:
-                    out.set_success(result)
-                except BaseException as e:  # noqa: BLE001
-                    self.node.agent.on_uncaught_exception(e)
+                        out.set_success(result)
+                    except BaseException as e:  # noqa: BLE001
+                        self.node.agent.on_uncaught_exception(e)
             safe.complete()   # no-op: every op's pendings already flushed
 
     # -- journal-backed paging ----------------------------------------------
@@ -391,27 +402,26 @@ class CommandStore:
         self.range_commands[txn_id] = ranges
         index = self._range_index
         if index is not None:
-            t0 = time.perf_counter()
-            for r in old or ():
-                index.remove(r.start, r.end, txn_id)
-            for r in ranges:
-                index.add(r.start, r.end, txn_id)
-            self._range_index_synced(t0)
+            with self._range_index_span():
+                for r in old or ():
+                    index.remove(r.start, r.end, txn_id)
+                for r in ranges:
+                    index.add(r.start, r.end, txn_id)
 
     def drop_range_command(self, txn_id: TxnId) -> None:
         old = self.range_commands.pop(txn_id, None)
         index = self._range_index
         if old is not None and index is not None:
-            t0 = time.perf_counter()
-            for r in old:
-                index.remove(r.start, r.end, txn_id)
-            self._range_index_synced(t0)
+            with self._range_index_span():
+                for r in old:
+                    index.remove(r.start, r.end, txn_id)
 
-    def _range_index_synced(self, t0: float) -> None:
+    def _range_index_span(self):
         # one timed kind with the device mirror's range registrations
         # (DeviceState.register): what keeping range txns findable costs
-        if self.device is not None:
-            self.device._ktime("range_index_sync", t0)
+        dev = self.device
+        return dev._span("range_index_sync") if dev is not None \
+            else devprof.span("range_index_sync")
 
     def range_index(self) -> RangeIndex:
         """Interval index over the range-domain txns (the role of ref:

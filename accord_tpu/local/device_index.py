@@ -991,14 +991,22 @@ class _DepsMirror:
         flush.  Consumers must treat the arrays as frozen."""
         s = self._snap
         if s is None or s[0] != self.mut_version:
-            ids = (self.msb.copy(), self.lsb.copy(), self.node.copy(),
-                   self.obj.copy(), self.status.copy(), self.emsb.copy(),
-                   self.elsb.copy(), self.enode.copy(),
-                   self.eknown.copy())
-            ivs = (self.lo.copy(), self.hi.copy(), self.domain.copy())
-            s = self._snap = (self.mut_version, ids, ivs,
-                              self.kind.copy())
+            with self._span("snapshot_cols"):
+                ids = (self.msb.copy(), self.lsb.copy(), self.node.copy(),
+                       self.obj.copy(), self.status.copy(),
+                       self.emsb.copy(), self.elsb.copy(),
+                       self.enode.copy(), self.eknown.copy())
+                ivs = (self.lo.copy(), self.hi.copy(), self.domain.copy())
+                s = self._snap = (self.mut_version, ids, ivs,
+                                  self.kind.copy())
         return s[1], s[2], s[3]
+
+    def _span(self, kind: str):
+        """A host span of the owning DeviceState's (its ``kernel_times``),
+        or a bare one on a mirror nobody owns."""
+        owner = self.owner
+        return owner._span(kind) if owner is not None \
+            else devprof.span(kind)
 
     # -- device sync --------------------------------------------------------
     def device_table_sharded(self, mesh) -> dk.DepsTable:
@@ -1078,78 +1086,75 @@ class _DepsMirror:
             faults.check("transfer", "attr column upload")
         if cells:
             faults.check("transfer", "bucket upload")
-        import time as _time
-        t0 = _time.perf_counter()
         owner, uploads = self.owner, 0
-        if full_slots:
-            self._device = dk.DepsTable(
-                *(jnp.asarray(a) for a in self._slot_host_cols()))
-            self._dirty.clear()
-            uploads += 7
-        if full_attrs:
-            self._attr_dev = dk.AttrCols(
-                *(jnp.asarray(a) for a in self._attr_host_cols()))
-            self._attr_dirty.clear()
-            uploads += 9
-        if full_cells:
-            t1 = _time.perf_counter()
-            self._bdev = tuple(jnp.asarray(a) for a in self._bhost)
-            self._bpend.clear()
-            n_pend = n_cells = 0
-            uploads += 8
-            if owner is not None:
-                owner._ktime("sync_bucket_full", t1)
-                owner.bucket_upload_bytes += self._brec.nbytes
-        # the staging buffer: int64 words, field-major (_sync_tables)
-        pieces = []
-        table = acols = bdev = None
-        rows = self._dirty | self._attr_dirty
-        n_rows = _pow2_at_least(len(rows), 8) if rows else 0
-        if rows:
-            # a resident table rides along even when only the other has
-            # dirty rows (its rows are rewritten with what they hold): the
-            # program family stays one per (rows, cells) shape
-            table = None if full_slots else self._device
-            acols = None if full_attrs else self._attr_dev
-            rows = np.sort(np.fromiter(rows, np.int64, len(rows)))
-            rows = np.concatenate(
-                [rows, np.full(n_rows - len(rows), rows[-1])])
-            pieces += [rows, self.msb[rows], self.lsb[rows],
-                       self.node[rows], self.status[rows]]
-            if table is not None:
-                pieces += [self.kind[rows], self.lo[rows].T.ravel(),
-                           self.hi[rows].T.ravel()]
-            if acols is not None:
-                pieces += [self.domain[rows], self.emsb[rows],
-                           self.elsb[rows], self.enode[rows],
-                           self.eknown[rows]]
-        if n_pend:
-            t1 = _time.perf_counter()
-            bdev = self._bdev
-            at = np.fromiter(self._bpend, np.int64, n_pend)
-            at = np.concatenate([at, np.full(n_cells - n_pend, at[-1])])
-            pieces += [at, self._bflat[at].view(np.int64).reshape(
-                n_cells, _CELL_WORDS).T.ravel()]
-            if owner is not None:
-                owner._ktime("sync_bucket_cells", t1)
-                owner.n_bucket_cells_uploaded += n_pend
-                owner.bucket_upload_bytes += n_cells * _CELL_BYTES
-        if pieces:
-            table, acols, bdev = _sync_tables(
-                table, acols, bdev, np.concatenate(pieces, dtype=np.int64),
-                n_rows=n_rows, n_cells=n_cells)
-            uploads += 1
-            if table is not None:
-                self._device = table
-            if acols is not None:
-                self._attr_dev = acols
-            if bdev is not None:
-                self._bdev = bdev
-            self._dirty.clear()
-            self._attr_dirty.clear()
-            self._bpend.clear()
+        with self._span("sync_tables"):
+            if full_slots:
+                self._device = dk.DepsTable(
+                    *(jnp.asarray(a) for a in self._slot_host_cols()))
+                self._dirty.clear()
+                uploads += 7
+            if full_attrs:
+                self._attr_dev = dk.AttrCols(
+                    *(jnp.asarray(a) for a in self._attr_host_cols()))
+                self._attr_dirty.clear()
+                uploads += 9
+            if full_cells:
+                with self._span("sync_bucket_full"):
+                    self._bdev = tuple(jnp.asarray(a) for a in self._bhost)
+                self._bpend.clear()
+                n_pend = n_cells = 0
+                uploads += 8
+                if owner is not None:
+                    owner.bucket_upload_bytes += self._brec.nbytes
+            # the staging buffer: int64 words, field-major (_sync_tables)
+            pieces = []
+            table = acols = bdev = None
+            rows = self._dirty | self._attr_dirty
+            n_rows = _pow2_at_least(len(rows), 8) if rows else 0
+            if rows:
+                # a resident table rides along even when only the other has
+                # dirty rows (its rows are rewritten with what they hold): the
+                # program family stays one per (rows, cells) shape
+                table = None if full_slots else self._device
+                acols = None if full_attrs else self._attr_dev
+                rows = np.sort(np.fromiter(rows, np.int64, len(rows)))
+                rows = np.concatenate(
+                    [rows, np.full(n_rows - len(rows), rows[-1])])
+                pieces += [rows, self.msb[rows], self.lsb[rows],
+                           self.node[rows], self.status[rows]]
+                if table is not None:
+                    pieces += [self.kind[rows], self.lo[rows].T.ravel(),
+                               self.hi[rows].T.ravel()]
+                if acols is not None:
+                    pieces += [self.domain[rows], self.emsb[rows],
+                               self.elsb[rows], self.enode[rows],
+                               self.eknown[rows]]
+            if n_pend:
+                with self._span("sync_bucket_cells"):
+                    bdev = self._bdev
+                    at = np.fromiter(self._bpend, np.int64, n_pend)
+                    at = np.concatenate(
+                        [at, np.full(n_cells - n_pend, at[-1])])
+                    pieces += [at, self._bflat[at].view(np.int64).reshape(
+                        n_cells, _CELL_WORDS).T.ravel()]
+                if owner is not None:
+                    owner.n_bucket_cells_uploaded += n_pend
+                    owner.bucket_upload_bytes += n_cells * _CELL_BYTES
+            if pieces:
+                table, acols, bdev = _sync_tables(
+                    table, acols, bdev, np.concatenate(pieces, dtype=np.int64),
+                    n_rows=n_rows, n_cells=n_cells)
+                uploads += 1
+                if table is not None:
+                    self._device = table
+                if acols is not None:
+                    self._attr_dev = acols
+                if bdev is not None:
+                    self._bdev = bdev
+                self._dirty.clear()
+                self._attr_dirty.clear()
+                self._bpend.clear()
         if owner is not None:
-            owner._ktime("sync_tables", t0)
             owner.n_sync_uploads += uploads
             owner.n_sync_launches += bool(pieces)
 
@@ -1963,9 +1968,14 @@ class DeviceState:
         self.n_elided_decided = 0
         self.attr_download_bytes = 0
         # per-kernel wall timing (SURVEY §5: structured per-kernel timing):
-        # kind -> [calls, seconds]; dispatch_* covers host pack + upload +
-        # enqueue, wait_* the download join, host_* the host-side passes
+        # kind -> [calls, seconds], written by _span alone; dispatch_*
+        # covers host pack + upload + enqueue, wait_* the download join,
+        # host_* the host-side passes
         self.kernel_times: Dict[str, List[float]] = {}
+        # (node, store): the Chrome-trace row of this store's spans
+        self._span_ids = (
+            getattr(getattr(store, "node", None), "node_id", 0) or 0,
+            getattr(store, "store_id", 0) or 0)
         # _DepsMirror.sync_device's bucket index: kernel_times'
         # sync_bucket_cells / sync_bucket_full count its syncs by path (the
         # cells' packing, the whole upload: both inside sync_tables); these
@@ -2064,18 +2074,21 @@ class DeviceState:
     def register(self, txn_id: TxnId, status: int, keys) -> None:
         """Witness/advance a txn in the deps index.  ``keys`` is the txn's
         sliced participation (Keys or Ranges) — its conflict footprint."""
-        import time as _time
-        t0 = _time.perf_counter()
-        slot = self.deps.alloc(txn_id)
-        if isinstance(keys, Ranges):
-            # the mirror's interval index is what answers a range query on
-            # this path: keeping it is the timed kind range_index_sync (as
-            # CommandStore.put_range_command's, once that index has a reader)
-            self.deps.add_intervals(slot, (), list(keys))
-            self._ktime("range_index_sync", t0)
-        elif keys is not None:
-            self.deps.add_intervals(slot, [k.token() for k in keys], ())
-        self._advance_status(txn_id, slot, status, None)
+        with self._span("register"):
+            if isinstance(keys, Ranges):
+                # the mirror's interval index is what answers a range query
+                # on this path: keeping it is the timed kind
+                # range_index_sync (as CommandStore.put_range_command's,
+                # once that index has a reader)
+                with self._span("range_index_sync"):
+                    slot = self.deps.alloc(txn_id)
+                    self.deps.add_intervals(slot, (), list(keys))
+            else:
+                slot = self.deps.alloc(txn_id)
+                if keys is not None:
+                    self.deps.add_intervals(
+                        slot, [k.token() for k in keys], ())
+            self._advance_status(txn_id, slot, status, None)
 
     def update_status(self, txn_id: TxnId, status: int,
                       execute_at: Optional[Timestamp] = None) -> None:
@@ -2409,10 +2422,13 @@ class DeviceState:
         if not batch:
             return
         try:
-            handle = self.deps_query_batch_begin(
-                [q for q, _b, _d in batch], immediate=True)
-            self.deps_query_batch_end_attributed(
-                safe, handle, [b for _q, b, _d in batch])
+            # the flush itself, the kernel_times kinds its children; the
+            # callbacks below are the handlers' continuations
+            with self._flush_span():
+                handle = self.deps_query_batch_begin(
+                    [q for q, _b, _d in batch], immediate=True)
+                self.deps_query_batch_end_attributed(
+                    safe, handle, [b for _q, b, _d in batch])
         except BaseException as e:  # noqa: BLE001
             for _q, _b, d in batch:
                 d(e, None)
@@ -2783,24 +2799,22 @@ class DeviceState:
         store does not hold yet stays dirty until it does.  The device
         image is no part of this: _AttrIndexHost assembles it when a
         device route asks."""
-        import time as _time
-        _t0 = _time.perf_counter()
-        rb = getattr(self.store, "redundant_before", None)
-        rb_version = rb.version if rb is not None else -1
-        cur = self._aidx
-        if cur is None or cur.rb_version != rb_version:
-            if rb is not None:
-                floors = rb.packed_floor_index()
-            else:
-                floors = (np.zeros(0, np.int64), np.zeros(1, np.int64),
-                          np.zeros(1, np.int64), np.zeros(1, np.int32))
-            lists = (np.zeros(0, np.int64), [], 0) if cur is None \
-                else (cur.toks, cur.packs, cur.n_execs)
-            cur = self._aidx = _AttrIndexHost(self, floors, rb_version,
-                                              *lists)
-        if self._attr_dirty:
-            cur = self._aidx = self._attr_refresh(cur)
-        self._ktime("host_attr_index", _t0)
+        with self._span("host_attr_index"):
+            rb = getattr(self.store, "redundant_before", None)
+            rb_version = rb.version if rb is not None else -1
+            cur = self._aidx
+            if cur is None or cur.rb_version != rb_version:
+                if rb is not None:
+                    floors = rb.packed_floor_index()
+                else:
+                    floors = (np.zeros(0, np.int64), np.zeros(1, np.int64),
+                              np.zeros(1, np.int64), np.zeros(1, np.int32))
+                lists = (np.zeros(0, np.int64), [], 0) if cur is None \
+                    else (cur.toks, cur.packs, cur.n_execs)
+                cur = self._aidx = _AttrIndexHost(self, floors, rb_version,
+                                                  *lists)
+            if self._attr_dirty:
+                cur = self._aidx = self._attr_refresh(cur)
         return cur
 
     @property
@@ -2948,11 +2962,8 @@ class DeviceState:
         if not (prune_floors and attributed):
             raise TypeError("deps_query_batch_begin has one flush path: "
                             "prune_floors and attributed are always on")
-        q_m = _pow2_at_least(max(len(t[3]) + len(t[4]) for t in queries))
-        packed = [(sb, wit, toks, rngs, tid)
-                  for (tid, sb, wit, toks, rngs) in queries]
         nq = len(queries)
-        qnp = dk.pack_query_matrix(packed, q_m)
+        q_m, qnp = self._pack_queries(queries)
         parts: List[Dict[str, object]] = []
         # conservative batch-global RedundantBefore floor, applied ON
         # DEVICE (the exact floors still run in attribution): in durable-
@@ -2983,163 +2994,160 @@ class DeviceState:
             """rows: np int64 array of query indices for this part, padded
             to a pow2 batch by repeating the last row (pads map to -1).
             Every device kind launches its ATTRIBUTED kernel (``attr_`` +
-            kind in the devprof slices and kernel_times); mesh kinds come
+            kind in the spans' names and kernel_times); mesh kinds come
             back as ONE merged replicated block (d=1, entry buffer
             d_mesh * s)."""
             nonlocal rankb_np
-            import time as _time
-            _t0 = _time.perf_counter()
-            if kind == "host":
-                # the host route computes its exact emit entries right
-                # here — no device box, no download thread; the
-                # floor/elision drops run at collect over the same
-                # snapshot the builders read
-                parts.append({"kind": "host",
-                              "ent": self.deps.host_pairs(qnp, q_m,
-                                                          floor_id)})
-                self.n_host_queries += len(rows)
+            kname = kind if kind in ("host", "host_slice") \
+                else "attr_" + kind
+            with self._span("dispatch_" + kname):
+                if kind == "host":
+                    # the host route computes its exact emit entries right
+                    # here — no device box, no download thread; the
+                    # floor/elision drops run at collect over the same
+                    # snapshot the builders read
+                    parts.append({"kind": "host",
+                                  "ent": self.deps.host_pairs(qnp, q_m,
+                                                              floor_id)})
+                    self.n_host_queries += len(rows)
+                    self.n_dispatches += 1
+                    return
+                if kind == "host_slice":
+                    # r21 hybrid twin part: while slices are quarantined the
+                    # assembled sharded table masks their slots to SLOT_FREE,
+                    # and this part answers for EXACTLY those slots from the
+                    # host mirror — disjoint from the device part's slot set
+                    # by construction, so the concatenated entries finalize
+                    # byte-identically to an all-device answer
+                    cb, cj, cm, cq = self.deps.host_pairs(qnp, q_m, floor_id)
+                    keep = self.store_shards.quarantined_slot_mask(cj)
+                    parts.append({"kind": "host_slice",
+                                  "ent": (cb[keep], cj[keep], cm[keep],
+                                          cq[keep])})
+                    self.n_dispatches += 1
+                    return
+                dk.launch_check(kind)
+                b_pad = _pow2_at_least(len(rows), 1)
+                rows_p = np.concatenate(
+                    [rows, np.full(b_pad - len(rows), rows[-1], np.int64)])
+                gmap = np.concatenate(
+                    [rows, np.full(b_pad - len(rows), -1, np.int64)])
+                m_t = self.deps.max_intervals
+                part: Dict[str, object] = {"kind": kname, "gmap": gmap,
+                                           "nq": b_pad, "q_m": q_m,
+                                           "mq": m_t * q_m, "d_ent": 1,
+                                           "immediate": immediate}
+                if rankb_np is None:
+                    rankb_np = aidx.rank_bounds(qnp)
+                rankb = jnp.asarray(rankb_np[rows_p])
+                pz = prune if prune is not None else _prune_zeros()
+                if kind == "sharded":
+                    table = self.deps.device_table_sharded(self.mesh)
+                    d = int(np.prod(list(self.mesh.shape.values())))
+                    n = table.capacity
+                    s = min(self._batch_flat, b_pad * (n // d) * m_t * q_m)
+                    k = min(self._batch_k, (n // d) * m_t * q_m)
+                    qmat = jnp.asarray(qnp[rows_p])
+                    mesh = self.mesh
+                    # merged replicated block with GLOBAL slot codes: the
+                    # cross-shard Deps.merge happens on device
+                    wide = dk.wide_codes(n, m_t, q_m)
+                    from ..parallel.sharded import sharded_flat_attr
+                    acols = self.deps.device_attr_cols_sharded(mesh)
+                    ai = aidx.device_replicated(mesh)
+
+                    def relaunch(s2, k2, _m=mesh, _t=table, _q=qmat,
+                                 _a=acols, _i=ai, _r=rankb, _p=pz):
+                        return sharded_flat_attr(
+                            _m, q_m, s2, k2, wide, k_floors,
+                            k_elide)(_t, _a, _i, _q, _r, *_p)
+
+                    part.update(d_ent=d, s=s, k=k, wide=wide,
+                                s_cap=b_pad * (n // d) * m_t * q_m,
+                                k_cap=(n // d) * m_t * q_m)
+                    self.n_mesh_queries += len(rows)
+                elif kind == "sharded_bucketed":
+                    btable = self.deps.bucket_device_sharded(self.mesh)
+                    d = int(np.prod(list(self.mesh.shape.values())))
+                    span = self.deps.SPAN
+                    keff = self.deps.bucket_keff()
+                    wide = dk.wide_codes(self.deps.capacity, m_t, q_m)
+                    # per-shard candidate ceiling: every touched bucket's live
+                    # entry slice plus this shard's slice of the wide list
+                    # crossed with the query intervals (exact triples)
+                    c = (q_m * span * keff
+                         + q_m * (btable.wlo.shape[0] // d))
+                    s = min(self._batch_flat, b_pad * c)
+                    k = min(self._batch_k, c)
+                    qb = qcols[rows_p].reshape(b_pad, q_m * span)
+                    qmat = jnp.asarray(np.concatenate(
+                        [qnp[rows_p], qb], axis=1))
+                    mesh = self.mesh
+                    from ..parallel.sharded import sharded_bucketed_attr
+                    acols = self.deps.device_attr_cols_replicated(mesh)
+                    ai = aidx.device_replicated(mesh)
+                    tsh = self.deps.device_table_sharded(mesh)
+
+                    def relaunch(s2, k2, _m=mesh, _b=btable, _t=tsh,
+                                 _q=qmat, _a=acols, _i=ai, _r=rankb,
+                                 _p=pz):
+                        return sharded_bucketed_attr(
+                            _m, q_m, span, s2, k2, m_t, keff, wide,
+                            k_floors, k_elide)(_b, _t, _a, _i, _q, _r,
+                                               *_p)
+
+                    part.update(d_ent=d, s=s, k=k, wide=wide,
+                                s_cap=b_pad * c, k_cap=c)
+                    self.n_mesh_queries += len(rows)
+                    self.n_mesh_bucketed_queries += len(rows)
+                elif kind == "dense":
+                    table = self.deps.device_table()
+                    n = table.capacity
+                    wide = dk.wide_codes(n, m_t, q_m)
+                    s = min(self._batch_flat, b_pad * n * m_t * q_m)
+                    k = min(self._batch_k, n * m_t * q_m)
+                    qmat = jnp.asarray(qnp[rows_p])
+                    acols = self.deps.device_attr_cols()
+                    ai = aidx.device()
+
+                    def relaunch(s2, k2, _t=table, _q=qmat, _a=acols,
+                                 _i=ai, _r=rankb, _p=pz):
+                        return dk.calculate_deps_flat_attr(
+                            _t, _a, _i, _q, _r, *_p, q_m, s2, k2, wide,
+                            k_floors, k_elide)
+
+                    self.n_dense_queries += len(rows)
+                    part.update(s=s, k=k, wide=wide,
+                                s_cap=b_pad * n * m_t * q_m,
+                                k_cap=n * m_t * q_m)
+                else:   # bucketed
+                    table = self.deps.device_table()
+                    btable = self.deps.bucket_device()
+                    span = self.deps.SPAN
+                    keff = self.deps.bucket_keff()
+                    wide = dk.wide_codes(table.capacity, m_t, q_m)
+                    c = (q_m * span * keff + q_m * btable.wlo.shape[0])
+                    s = min(self._batch_flat, b_pad * c)
+                    k = min(self._batch_k, c)
+                    qb = qcols[rows_p].reshape(b_pad, q_m * span)
+                    qmat = jnp.asarray(np.concatenate(
+                        [qnp[rows_p], qb], axis=1))
+                    acols = self.deps.device_attr_cols()
+                    ai = aidx.device()
+
+                    def relaunch(s2, k2, _t=table, _b=btable, _q=qmat,
+                                 _a=acols, _i=ai, _r=rankb, _p=pz):
+                        return dk.bucketed_attr_jit(
+                            _t, _a, _i, _b, _q, _r, q_m, span, s2, k2,
+                            _p, keff=keff, wide=wide, floors=k_floors,
+                            elide=k_elide)
+
+                    self.n_bucketed_queries += len(rows)
+                    part.update(s=s, k=k, wide=wide, s_cap=b_pad * c,
+                                k_cap=c)
+                hdr_dev, ent_dev = relaunch(s, k)
+                part["relaunch"] = relaunch
                 self.n_dispatches += 1
-                self._ktime("dispatch_host", _t0)
-                return
-            if kind == "host_slice":
-                # r21 hybrid twin part: while slices are quarantined the
-                # assembled sharded table masks their slots to SLOT_FREE,
-                # and this part answers for EXACTLY those slots from the
-                # host mirror — disjoint from the device part's slot set
-                # by construction, so the concatenated entries finalize
-                # byte-identically to an all-device answer
-                cb, cj, cm, cq = self.deps.host_pairs(qnp, q_m, floor_id)
-                keep = self.store_shards.quarantined_slot_mask(cj)
-                parts.append({"kind": "host_slice",
-                              "ent": (cb[keep], cj[keep], cm[keep],
-                                      cq[keep])})
-                self.n_dispatches += 1
-                self._ktime("dispatch_host_slice", _t0)
-                return
-            dk.launch_check(kind)
-            kname = "attr_" + kind
-            b_pad = _pow2_at_least(len(rows), 1)
-            rows_p = np.concatenate(
-                [rows, np.full(b_pad - len(rows), rows[-1], np.int64)])
-            gmap = np.concatenate(
-                [rows, np.full(b_pad - len(rows), -1, np.int64)])
-            m_t = self.deps.max_intervals
-            part: Dict[str, object] = {"kind": kname, "gmap": gmap,
-                                       "nq": b_pad, "q_m": q_m,
-                                       "mq": m_t * q_m, "d_ent": 1,
-                                       "immediate": immediate}
-            if rankb_np is None:
-                rankb_np = aidx.rank_bounds(qnp)
-            rankb = jnp.asarray(rankb_np[rows_p])
-            pz = prune if prune is not None else _prune_zeros()
-            if kind == "sharded":
-                table = self.deps.device_table_sharded(self.mesh)
-                d = int(np.prod(list(self.mesh.shape.values())))
-                n = table.capacity
-                s = min(self._batch_flat, b_pad * (n // d) * m_t * q_m)
-                k = min(self._batch_k, (n // d) * m_t * q_m)
-                qmat = jnp.asarray(qnp[rows_p])
-                mesh = self.mesh
-                # merged replicated block with GLOBAL slot codes: the
-                # cross-shard Deps.merge happens on device
-                wide = dk.wide_codes(n, m_t, q_m)
-                from ..parallel.sharded import sharded_flat_attr
-                acols = self.deps.device_attr_cols_sharded(mesh)
-                ai = aidx.device_replicated(mesh)
-
-                def relaunch(s2, k2, _m=mesh, _t=table, _q=qmat,
-                             _a=acols, _i=ai, _r=rankb, _p=pz):
-                    return sharded_flat_attr(
-                        _m, q_m, s2, k2, wide, k_floors,
-                        k_elide)(_t, _a, _i, _q, _r, *_p)
-
-                part.update(d_ent=d, s=s, k=k, wide=wide,
-                            s_cap=b_pad * (n // d) * m_t * q_m,
-                            k_cap=(n // d) * m_t * q_m)
-                self.n_mesh_queries += len(rows)
-            elif kind == "sharded_bucketed":
-                btable = self.deps.bucket_device_sharded(self.mesh)
-                d = int(np.prod(list(self.mesh.shape.values())))
-                span = self.deps.SPAN
-                keff = self.deps.bucket_keff()
-                wide = dk.wide_codes(self.deps.capacity, m_t, q_m)
-                # per-shard candidate ceiling: every touched bucket's live
-                # entry slice plus this shard's slice of the wide list
-                # crossed with the query intervals (exact triples)
-                c = (q_m * span * keff
-                     + q_m * (btable.wlo.shape[0] // d))
-                s = min(self._batch_flat, b_pad * c)
-                k = min(self._batch_k, c)
-                qb = qcols[rows_p].reshape(b_pad, q_m * span)
-                qmat = jnp.asarray(np.concatenate(
-                    [qnp[rows_p], qb], axis=1))
-                mesh = self.mesh
-                from ..parallel.sharded import sharded_bucketed_attr
-                acols = self.deps.device_attr_cols_replicated(mesh)
-                ai = aidx.device_replicated(mesh)
-                tsh = self.deps.device_table_sharded(mesh)
-
-                def relaunch(s2, k2, _m=mesh, _b=btable, _t=tsh,
-                             _q=qmat, _a=acols, _i=ai, _r=rankb,
-                             _p=pz):
-                    return sharded_bucketed_attr(
-                        _m, q_m, span, s2, k2, m_t, keff, wide,
-                        k_floors, k_elide)(_b, _t, _a, _i, _q, _r,
-                                           *_p)
-
-                part.update(d_ent=d, s=s, k=k, wide=wide,
-                            s_cap=b_pad * c, k_cap=c)
-                self.n_mesh_queries += len(rows)
-                self.n_mesh_bucketed_queries += len(rows)
-            elif kind == "dense":
-                table = self.deps.device_table()
-                n = table.capacity
-                wide = dk.wide_codes(n, m_t, q_m)
-                s = min(self._batch_flat, b_pad * n * m_t * q_m)
-                k = min(self._batch_k, n * m_t * q_m)
-                qmat = jnp.asarray(qnp[rows_p])
-                acols = self.deps.device_attr_cols()
-                ai = aidx.device()
-
-                def relaunch(s2, k2, _t=table, _q=qmat, _a=acols,
-                             _i=ai, _r=rankb, _p=pz):
-                    return dk.calculate_deps_flat_attr(
-                        _t, _a, _i, _q, _r, *_p, q_m, s2, k2, wide,
-                        k_floors, k_elide)
-
-                self.n_dense_queries += len(rows)
-                part.update(s=s, k=k, wide=wide,
-                            s_cap=b_pad * n * m_t * q_m,
-                            k_cap=n * m_t * q_m)
-            else:   # bucketed
-                table = self.deps.device_table()
-                btable = self.deps.bucket_device()
-                span = self.deps.SPAN
-                keff = self.deps.bucket_keff()
-                wide = dk.wide_codes(table.capacity, m_t, q_m)
-                c = (q_m * span * keff + q_m * btable.wlo.shape[0])
-                s = min(self._batch_flat, b_pad * c)
-                k = min(self._batch_k, c)
-                qb = qcols[rows_p].reshape(b_pad, q_m * span)
-                qmat = jnp.asarray(np.concatenate(
-                    [qnp[rows_p], qb], axis=1))
-                acols = self.deps.device_attr_cols()
-                ai = aidx.device()
-
-                def relaunch(s2, k2, _t=table, _b=btable, _q=qmat,
-                             _a=acols, _i=ai, _r=rankb, _p=pz):
-                    return dk.bucketed_attr_jit(
-                        _t, _a, _i, _b, _q, _r, q_m, span, s2, k2,
-                        _p, keff=keff, wide=wide, floors=k_floors,
-                        elide=k_elide)
-
-                self.n_bucketed_queries += len(rows)
-                part.update(s=s, k=k, wide=wide, s_cap=b_pad * c,
-                            k_cap=c)
-            hdr_dev, ent_dev = relaunch(s, k)
-            part["relaunch"] = relaunch
-            self.n_dispatches += 1
-            self._ktime("dispatch_" + kname, _t0)
             box: Dict[str, object] = {"hdr": hdr_dev, "ent": ent_dev}
             part["box"] = box
             if not immediate:
@@ -3151,20 +3159,24 @@ class DeviceState:
                 # on the deterministic store-task thread (_collect_part
                 # re-checks before consuming each stage)
                 nq_, s_, k_, de_ = b_pad, s, k, part["d_ent"]
+                # the worker's spans: a table of its own, folded into
+                # kernel_times by the collector once it has joined
+                times = box["times"] = {}
+                ids = self._span_ids
 
                 def _fetch():
-                    import time as _time
                     try:
-                        t0 = _time.perf_counter()
-                        hdr = np.asarray(hdr_dev).reshape(1, _HOFF + nq_)
+                        with devprof.span("wait_header_" + kname, times,
+                                          *ids):
+                            hdr = np.asarray(hdr_dev).reshape(
+                                1, _HOFF + nq_)
                         box["hdr_np"] = hdr
-                        box["t_hdr"] = (t0, _time.perf_counter())
                         if int(hdr[0, 1]) > s_ or int(hdr[0, 2]) > k_:
                             return    # overflowed: collector re-runs
-                        t1 = _time.perf_counter()
-                        box["ent_np"] = _fetch_entry_prefix(
-                            ent_dev, de_ * s_, int(hdr[0, 0]))
-                        box["t_ent"] = (t1, _time.perf_counter())
+                        with devprof.span("wait_entries_" + kname, times,
+                                          *ids):
+                            box["ent_np"] = _fetch_entry_prefix(
+                                ent_dev, de_ * s_, int(hdr[0, 0]))
                     except BaseException as e:     # surfaced after join
                         box["err"] = e
 
@@ -3185,7 +3197,8 @@ class DeviceState:
         else:
             route = self.route_override
             if route is None:
-                route = self._choose_route(qnp, q_m, floor_id)
+                with self._span("choose_route"):
+                    route = self._choose_route(qnp, q_m, floor_id)
             if route != "host" and may_probe:
                 probing = True
                 self.n_reprobes += 1
@@ -3295,56 +3308,56 @@ class DeviceState:
                  "floor_skip": floor_skip}
         return (parts, ids, ivs, qnp, q_m, list(queries), fmeta)
 
+    def _pack_queries(self, queries):
+        """(q_m, qnp): a flush's queries as one int64 matrix, each row
+        padded to ``q_m`` intervals (dk.pack_query_matrix)."""
+        with self._span("pack_queries"):
+            q_m = _pow2_at_least(
+                max(len(t[3]) + len(t[4]) for t in queries))
+            packed = [(sb, wit, toks, rngs, tid)
+                      for (tid, sb, wit, toks, rngs) in queries]
+            return q_m, dk.pack_query_matrix(packed, q_m)
+
     def _bucket_query_cols(self, qnp: np.ndarray, q_m: int):
         """Vectorized query->bucket-row mapping: int64[NQ, q_m, SPAN] dense
         rows (-1 = no/empty bucket) and the wide-query mask (any interval
         spanning more than SPAN buckets — those take the dense kernel)."""
-        shift = self.deps.BSHIFT
-        span = self.deps.SPAN
-        lo = qnp[:, 7:7 + q_m]
-        hi = qnp[:, 7 + q_m:7 + 2 * q_m]
-        used = lo <= hi
-        blo = lo >> shift
-        bhi = hi >> shift
-        wide_q = np.any(used & (bhi - blo + 1 > span), axis=1)
-        sorted_bids, row_of = self.deps.bid_rows()
-        cols = np.full((qnp.shape[0], q_m, span), -1, np.int64)
-        if len(sorted_bids):
-            for off in range(span):
-                bid = blo + off
-                ok = used & (bid <= bhi)
-                idx = np.searchsorted(sorted_bids, bid)
-                idxc = np.minimum(idx, len(sorted_bids) - 1)
-                found = ok & (sorted_bids[idxc] == bid)
-                cols[:, :, off] = np.where(found, row_of[idxc], -1)
-        return cols, wide_q
+        with self._span("pack_queries"):
+            shift = self.deps.BSHIFT
+            span = self.deps.SPAN
+            lo = qnp[:, 7:7 + q_m]
+            hi = qnp[:, 7 + q_m:7 + 2 * q_m]
+            used = lo <= hi
+            blo = lo >> shift
+            bhi = hi >> shift
+            wide_q = np.any(used & (bhi - blo + 1 > span), axis=1)
+            sorted_bids, row_of = self.deps.bid_rows()
+            cols = np.full((qnp.shape[0], q_m, span), -1, np.int64)
+            if len(sorted_bids):
+                for off in range(span):
+                    bid = blo + off
+                    ok = used & (bid <= bhi)
+                    idx = np.searchsorted(sorted_bids, bid)
+                    idxc = np.minimum(idx, len(sorted_bids) - 1)
+                    found = ok & (sorted_bids[idxc] == bid)
+                    cols[:, :, off] = np.where(found, row_of[idxc], -1)
+            return cols, wide_q
 
-    def _ktime(self, kind: str, t0: float) -> None:
-        import time as _time
-        self._ktime_span(kind, t0, _time.perf_counter())
+    def _flush_span(self):
+        """One deps flush of a serving node, in its loop's table (none in a
+        sim): the kernel_times kinds are its children."""
+        return devprof.span(
+            "srv.deps_flush",
+            getattr(getattr(self.store, "node", None), "loop_times", None))
 
-    def _ktime_span(self, kind: str, t0: float, t1: float) -> None:
-        """One finished launch-boundary slice with explicit endpoints —
-        the two-stage downloads measure their header/entry fetches where
-        they actually happened (possibly on the prefetch thread) and
-        report them here (dispatch_* = host pack + upload + enqueue,
-        wait_header_* = header join, wait_entries_* = entry-prefix
-        transfer, host_* = host passes)."""
-        cell = self.kernel_times.get(kind)
-        if cell is None:
-            cell = self.kernel_times[kind] = [0, 0.0]
-        cell[0] += 1
-        cell[1] += t1 - t0
-        prof = devprof.PROFILER
-        if prof is not None:
-            # every launch boundary timed here becomes a Chrome-trace
-            # slice: pid = node, tid = store — the launch timeline, not
-            # just a counter
-            prof.complete(
-                kind, t0, t1,
-                pid=getattr(getattr(self.store, "node", None),
-                            "node_id", 0) or 0,
-                tid=getattr(self.store, "store_id", 0) or 0)
+    def _span(self, kind: str):
+        """One host span into ``kernel_times`` (obs.devprof.span): every
+        launch boundary and host pass of the store is one — dispatch_* =
+        host pack + upload + enqueue, wait_header_* = header join,
+        wait_entries_* = entry-prefix transfer, host_* = host passes —
+        and with a profiler armed a Chrome-trace slice too: pid = node,
+        tid = store, the launch timeline and not just a counter."""
+        return devprof.span(kind, self.kernel_times, *self._span_ids)
 
     def _overflow_resize(self, total: int, maxc: int, s: int, k: int,
                          s_cap: int, k_cap: int, runs: int):
@@ -3391,26 +3404,24 @@ class DeviceState:
         same compacted transfer — the full pow2-padded buffer is never
         materialized on the host.  Returns per-triple (b, j, m, q) global
         arrays (codes decoded, pad rows dropped)."""
-        import time as _time
         box = part["box"]
         th = part.get("th")
         nq, d_ent = part["nq"], part["d_ent"]
         s, k = part["s"], part["k"]
         itemsize = 8 if part["wide"] else 4
         faults.check("transfer", "header download")
-        _t0 = _time.perf_counter()
         if th is not None:
+            # the worker's wait_header_* / wait_entries_* spans are its
+            # own clocks on its own thread; they count here, at the join
             th.result()
+            devprof.merge(self.kernel_times, box["times"])
             err = box.get("err")
             if err is not None:
                 raise err           # the real device/transfer failure
             hdr = box["hdr_np"]
-            t_h = box.get("t_hdr")
         else:
-            hdr = np.asarray(box["hdr"]).reshape(1, _HOFF + nq)
-            t_h = None
-        self._ktime_span("wait_header_" + part["kind"],
-                         *(t_h or (_t0, _time.perf_counter())))
+            with self._span("wait_header_" + part["kind"]):
+                hdr = np.asarray(box["hdr"]).reshape(1, _HOFF + nq)
         self.download_bytes += hdr.nbytes
         self.download_bytes_padded += hdr.nbytes + d_ent * s * itemsize
         runs = 0
@@ -3428,32 +3439,28 @@ class DeviceState:
             box = {"hdr": hdr_dev, "ent": ent_dev}
             th = None
             faults.check("transfer", "header download")
-            _t0 = _time.perf_counter()
-            hdr = np.asarray(hdr_dev).reshape(1, _HOFF + nq)
-            self._ktime("wait_header_" + part["kind"], _t0)
+            with self._span("wait_header_" + part["kind"]):
+                hdr = np.asarray(hdr_dev).reshape(1, _HOFF + nq)
             self.download_bytes += hdr.nbytes
             self.download_bytes_padded += hdr.nbytes \
                 + d_ent * s * itemsize
             runs += 1
         faults.check("transfer", "entry download")
-        _t1 = _time.perf_counter()
         if th is not None and "ent_np" in box:
             ent = box["ent_np"]
-            t_e = box.get("t_ent")
         else:
             # synchronous fetch (immediate flush or post-overflow): slice
             # the live prefix only when the modeled byte saving beats the
             # extra slice dispatch — on the pipelined path the prefix
             # fetch rides the prefetch thread and overlaps compute, so it
             # never asks
-            maxtot = int(hdr[0, 0])
-            if self._prefix_pays(d_ent * s, maxtot, itemsize):
-                ent = _fetch_entry_prefix(box["ent"], d_ent * s, maxtot)
-            else:
-                ent = np.asarray(box["ent"]).reshape(1, d_ent * s)
-            t_e = None
-        self._ktime_span("wait_entries_" + part["kind"],
-                         *(t_e or (_t1, _time.perf_counter())))
+            with self._span("wait_entries_" + part["kind"]):
+                maxtot = int(hdr[0, 0])
+                if self._prefix_pays(d_ent * s, maxtot, itemsize):
+                    ent = _fetch_entry_prefix(box["ent"], d_ent * s,
+                                              maxtot)
+                else:
+                    ent = np.asarray(box["ent"]).reshape(1, d_ent * s)
         self.download_bytes += ent.nbytes
         if self.store_shards is not None and self.store_shards.active \
                 and "sharded" in part["kind"]:
@@ -3509,15 +3516,13 @@ class DeviceState:
         the shared finalize the same entries.  Returns (tb, tj, tm, tq,
         ids, ivs, qnp, q_m, queries)."""
         (parts, ids, ivs, qnp, q_m, queries, fmeta) = handle
-        import time as _time
         nq = len(queries)
         if len(parts) == 1 and parts[0]["kind"] == "host":
-            _th = _time.perf_counter()
-            tb, tj, tm, tq = self._host_attr_triples(handle,
-                                                     part=parts[0])
-            self.n_queries += nq
-            self.n_kernel_deps += len(tj)
-            self._ktime("host_attr_filter", _th)
+            with self._span("host_attr_filter"):
+                tb, tj, tm, tq = self._host_attr_triples(handle,
+                                                         part=parts[0])
+                self.n_queries += nq
+                self.n_kernel_deps += len(tj)
             return tb, tj, tm, tq, ids, ivs, qnp, q_m, queries
         try:
             # host_slice twin parts (the r21 hybrid) answer from the host
@@ -3535,7 +3540,6 @@ class DeviceState:
             tb, tj, tm, tq = self._host_attr_triples(handle)
             self.n_kernel_deps += len(tj)
             return tb, tj, tm, tq, ids, ivs, qnp, q_m, queries
-        _tg = _time.perf_counter()
         if len(outs) == 1:
             tb, tj, tm, tq = outs[0]
         else:
@@ -3566,7 +3570,6 @@ class DeviceState:
             self._restore_device()   # the probe flush succeeded end-to-end
         self.n_queries += nq
         self.n_kernel_deps += len(tj)
-        self._ktime("host_decode", _tg)
         return tb, tj, tm, tq, ids, ivs, qnp, q_m, queries
 
     def _finalize_attr_entries(self, tb, tj, tm, tq, ids, ivs, qnp, q_m,
@@ -3641,13 +3644,11 @@ class DeviceState:
         pre-floored/pre-elided (in-kernel on the device routes,
         _attr_filter_entries on the host route) and take the thin shared
         finalize."""
-        import time as _time
         tb, tj, tm, tq, ids, ivs, qnp, q_m, _queries = \
             self._batch_collect_attr(handle)
-        _ta = _time.perf_counter()
-        self._finalize_attr_entries(tb, tj, tm, tq, ids, ivs, qnp,
-                                    q_m, builders)
-        self._ktime("host_attr_finalize", _ta)
+        with self._span("host_attr_finalize"):
+            self._finalize_attr_entries(tb, tj, tm, tq, ids, ivs, qnp,
+                                        q_m, builders)
 
     # ------------------------------------------------------------------
     # fused cross-store dispatch (r08; driven by local.dispatch's
@@ -3670,14 +3671,12 @@ class DeviceState:
             # hybrid (device + host-twin) flushes run solo: a fused
             # member's block is all-device, with no twin part to graft
             return None
-        q_m = _pow2_at_least(max(len(t[3]) + len(t[4]) for t in queries))
-        packed = [(sb, wit, toks, rngs, tid)
-                  for (tid, sb, wit, toks, rngs) in queries]
-        qnp = dk.pack_query_matrix(packed, q_m)
+        q_m, qnp = self._pack_queries(queries)
         floor_id, prune_np = self._batch_floor(qnp, q_m)
         route = self.route_override
         if route is None:
-            route = self._choose_route(qnp, q_m, floor_id)
+            with self._span("choose_route"):
+                route = self._choose_route(qnp, q_m, floor_id)
         if route == "host":
             return None
         nq = qnp.shape[0]
@@ -3778,136 +3777,134 @@ class DeviceState:
         scan, probe restore, and whole-batch host failover on any
         device-boundary failure.  Returns attributed ENTRY arrays
         (tb, tj, tm, tq)."""
-        import time as _time
-        _t0 = _time.perf_counter()
-        nq = hint["nq"]
-        n_range = self._count_range_queries(hint["queries"])
-        if "host" in hint:           # launch already failed over to host
-            self.n_host_queries += nq
-            self.n_dispatches += 1
-            return self._hint_attr_entries(hint, hint["host"])
-        qnp, q_m = hint["qnp"], hint["q_m"]
-        shard_n = hint["shard_n"]
-        b_pad = hint["b_pad_c"]
-        mq, qmc = hint["mq"], hint["q_m_c"]
-        pad_stride = hint.get("pad_shard_n")   # mesh: padded shard stride
-        try:
-            hdr_all, ent_all = launch.materialize()
-            hdr = hdr_all[hint["row"]].reshape(1, 5 + b_pad)
-            ent = ent_all[hint["row"]]
-            s_, k_ = launch.s, launch.k
-            runs = 0
-            while int(hdr[:, 1].max()) > s_ or int(hdr[:, 2].max()) > k_:
-                # overflow: escalate EXACTLY like the solo path — re-run
-                # this store alone against the same cached table + attr
-                # inputs, sized from the exact header
-                cap_k = shard_n * hint["m_iv"] * qmc
-                s_, k_ = self._overflow_resize(
-                    int(hdr[:, 1].max()), int(hdr[:, 2].max()), s_, k_,
-                    b_pad * cap_k, cap_k, runs)
-                qmat = jnp.asarray(hint["qmat_np"])
-                rankb = jnp.asarray(hint["rankb_pad"])
-                pnp = hint["prune"]
-                pz = _prune_zeros() if pnp is None else \
-                    (jnp.asarray(pnp[0]), jnp.asarray(pnp[1]),
-                     jnp.asarray(pnp[2]))
-                wide = hint["wide"]
-                fl_, el_ = (not hint.get("floor_skip", False),
-                            hint["aidx"].n_execs > 0)
-                if self.mesh is not None:
-                    from ..parallel.sharded import sharded_flat_attr
-                    hdr_dev, ent_dev = sharded_flat_attr(
-                        self.mesh, qmc, s_, k_, wide, fl_, el_)(
-                        hint["table"],
-                        self.deps.device_attr_cols_sharded(self.mesh),
-                        hint["aidx"].device_replicated(self.mesh),
-                        qmat, rankb, *pz)
-                    d_ent = len(self.mesh.devices.flat)
-                else:
-                    hdr_dev, ent_dev = dk.calculate_deps_flat_attr(
-                        hint["table"], self.deps.device_attr_cols(),
-                        hint["aidx"].device(), qmat, rankb, *pz,
-                        qmc, s_, k_, wide, fl_, el_)
-                    d_ent = 1
-                faults.check("transfer", "header download")
-                hdr = np.asarray(hdr_dev).reshape(1, 5 + b_pad)
-                itemsize = 8 if wide else 4
-                self.download_bytes += hdr.nbytes
-                self.download_bytes_padded += hdr.nbytes \
-                    + d_ent * s_ * itemsize
-                if int(hdr[:, 1].max()) <= s_ \
-                        and int(hdr[:, 2].max()) <= k_:
-                    faults.check("transfer", "entry download")
-                    ent = _fetch_entry_prefix(ent_dev, d_ent * s_,
-                                              int(hdr[:, 0].max()))
-                    self.download_bytes += ent.nbytes
-                runs += 1
-            if runs:
-                # the re-run scanned the store's OWN table solo, so its
-                # codes scale on the store's interval width and its slot
-                # ids are contiguous-global (no fused pad stride)
-                mq = hint["m_iv"] * qmc
-                pad_stride = None
-            if ent.ndim == 1:
-                ent = ent.reshape(1, -1)
-        except faults.DEVICE_EXCEPTIONS as e:
-            # whole-batch failover: quarantine every member, serve this
-            # flush from the SNAPSHOT host scan (begin-time bytes)
-            launch.poison(e)
-            self.n_fallback_queries += nq
-            self.n_host_queries += nq
-            self.n_dispatches += 1
-            return self._hint_attr_entries(
-                hint, self.deps.host_pairs(
-                    qnp, q_m, hint["floor_id"],
-                    snapshot=self._fused_snapshot(hint)))
-        self.n_elided_transitive += int(hdr[:, 3].sum())
-        self.n_elided_decided += int(hdr[:, 4].sum())
-        self.attr_download_bytes += hdr.nbytes + ent.nbytes
-        tb, tj, tm, tq = _decode_triples(hdr, ent, b_pad, mq, qmc)
-        if pad_stride is not None:
-            # mesh fused codes number slots on the PADDED per-shard
-            # stride (every member padded to the group's largest slice):
-            # fold back onto this store's contiguous slot ids
-            tj = (tj // pad_stride) * np.int64(hint["cap"]
-                                               // hint["d_mesh"]) \
-                + tj % pad_stride
-        if self._paranoid() and len(tj) \
-                and faults.should_fire("stale_result"):
-            tj = (tj + np.int64(1)) % np.int64(len(hint["ids"][0]))
-        gmap = hint["gmap"]
-        b_global = gmap[tb]
-        keep = b_global >= 0
-        tb, tj, tm, tq = b_global[keep], tj[keep], tm[keep], tq[keep]
-        if self._paranoid():
-            self.n_shadow_checks += 1
-            hb, hj, hm, hq = self._hint_attr_entries(
-                hint, self.deps.host_pairs(
-                    qnp, q_m, hint["floor_id"],
-                    snapshot=self._fused_snapshot(hint)))
-            cap = np.int64(len(hint["ids"][0]))
-            if not np.array_equal(np.unique(tb * cap + tj),
-                                  np.unique(hb * cap + hj)):
-                self.n_shadow_mismatches += 1
-                self._device_fault("stale_result", "fused shadow mismatch")
-                self.n_fallback_queries += nq
+        with self._span("wait_attr_fused"):
+            nq = hint["nq"]
+            n_range = self._count_range_queries(hint["queries"])
+            if "host" in hint:           # launch already failed over to host
+                self.n_host_queries += nq
                 self.n_dispatches += 1
-                return hb, hj, hm, hq
-        sh = self.store_shards
-        if sh is not None and sh.active:
-            sh.note_success()
-        if hint.get("probing"):
-            self._restore_device()
-        self.n_dispatches += 1
-        self.n_fused_flushes += 1
-        self.n_fused_queries += nq
-        self.n_range_device_queries += n_range
-        if self.mesh is not None:
-            self.n_mesh_queries += nq
-        else:
-            self.n_dense_queries += nq
-        self._ktime("wait_attr_fused", _t0)
-        return tb, tj, tm, tq
+                return self._hint_attr_entries(hint, hint["host"])
+            qnp, q_m = hint["qnp"], hint["q_m"]
+            shard_n = hint["shard_n"]
+            b_pad = hint["b_pad_c"]
+            mq, qmc = hint["mq"], hint["q_m_c"]
+            pad_stride = hint.get("pad_shard_n")   # mesh: padded shard stride
+            try:
+                hdr_all, ent_all = launch.materialize()
+                hdr = hdr_all[hint["row"]].reshape(1, 5 + b_pad)
+                ent = ent_all[hint["row"]]
+                s_, k_ = launch.s, launch.k
+                runs = 0
+                while int(hdr[:, 1].max()) > s_ or int(hdr[:, 2].max()) > k_:
+                    # overflow: escalate EXACTLY like the solo path — re-run
+                    # this store alone against the same cached table + attr
+                    # inputs, sized from the exact header
+                    cap_k = shard_n * hint["m_iv"] * qmc
+                    s_, k_ = self._overflow_resize(
+                        int(hdr[:, 1].max()), int(hdr[:, 2].max()), s_, k_,
+                        b_pad * cap_k, cap_k, runs)
+                    qmat = jnp.asarray(hint["qmat_np"])
+                    rankb = jnp.asarray(hint["rankb_pad"])
+                    pnp = hint["prune"]
+                    pz = _prune_zeros() if pnp is None else \
+                        (jnp.asarray(pnp[0]), jnp.asarray(pnp[1]),
+                         jnp.asarray(pnp[2]))
+                    wide = hint["wide"]
+                    fl_, el_ = (not hint.get("floor_skip", False),
+                                hint["aidx"].n_execs > 0)
+                    if self.mesh is not None:
+                        from ..parallel.sharded import sharded_flat_attr
+                        hdr_dev, ent_dev = sharded_flat_attr(
+                            self.mesh, qmc, s_, k_, wide, fl_, el_)(
+                            hint["table"],
+                            self.deps.device_attr_cols_sharded(self.mesh),
+                            hint["aidx"].device_replicated(self.mesh),
+                            qmat, rankb, *pz)
+                        d_ent = len(self.mesh.devices.flat)
+                    else:
+                        hdr_dev, ent_dev = dk.calculate_deps_flat_attr(
+                            hint["table"], self.deps.device_attr_cols(),
+                            hint["aidx"].device(), qmat, rankb, *pz,
+                            qmc, s_, k_, wide, fl_, el_)
+                        d_ent = 1
+                    faults.check("transfer", "header download")
+                    hdr = np.asarray(hdr_dev).reshape(1, 5 + b_pad)
+                    itemsize = 8 if wide else 4
+                    self.download_bytes += hdr.nbytes
+                    self.download_bytes_padded += hdr.nbytes \
+                        + d_ent * s_ * itemsize
+                    if int(hdr[:, 1].max()) <= s_ \
+                            and int(hdr[:, 2].max()) <= k_:
+                        faults.check("transfer", "entry download")
+                        ent = _fetch_entry_prefix(ent_dev, d_ent * s_,
+                                                  int(hdr[:, 0].max()))
+                        self.download_bytes += ent.nbytes
+                    runs += 1
+                if runs:
+                    # the re-run scanned the store's OWN table solo, so its
+                    # codes scale on the store's interval width and its slot
+                    # ids are contiguous-global (no fused pad stride)
+                    mq = hint["m_iv"] * qmc
+                    pad_stride = None
+                if ent.ndim == 1:
+                    ent = ent.reshape(1, -1)
+            except faults.DEVICE_EXCEPTIONS as e:
+                # whole-batch failover: quarantine every member, serve this
+                # flush from the SNAPSHOT host scan (begin-time bytes)
+                launch.poison(e)
+                self.n_fallback_queries += nq
+                self.n_host_queries += nq
+                self.n_dispatches += 1
+                return self._hint_attr_entries(
+                    hint, self.deps.host_pairs(
+                        qnp, q_m, hint["floor_id"],
+                        snapshot=self._fused_snapshot(hint)))
+            self.n_elided_transitive += int(hdr[:, 3].sum())
+            self.n_elided_decided += int(hdr[:, 4].sum())
+            self.attr_download_bytes += hdr.nbytes + ent.nbytes
+            tb, tj, tm, tq = _decode_triples(hdr, ent, b_pad, mq, qmc)
+            if pad_stride is not None:
+                # mesh fused codes number slots on the PADDED per-shard
+                # stride (every member padded to the group's largest slice):
+                # fold back onto this store's contiguous slot ids
+                tj = (tj // pad_stride) * np.int64(hint["cap"]
+                                                   // hint["d_mesh"]) \
+                    + tj % pad_stride
+            if self._paranoid() and len(tj) \
+                    and faults.should_fire("stale_result"):
+                tj = (tj + np.int64(1)) % np.int64(len(hint["ids"][0]))
+            gmap = hint["gmap"]
+            b_global = gmap[tb]
+            keep = b_global >= 0
+            tb, tj, tm, tq = b_global[keep], tj[keep], tm[keep], tq[keep]
+            if self._paranoid():
+                self.n_shadow_checks += 1
+                hb, hj, hm, hq = self._hint_attr_entries(
+                    hint, self.deps.host_pairs(
+                        qnp, q_m, hint["floor_id"],
+                        snapshot=self._fused_snapshot(hint)))
+                cap = np.int64(len(hint["ids"][0]))
+                if not np.array_equal(np.unique(tb * cap + tj),
+                                      np.unique(hb * cap + hj)):
+                    self.n_shadow_mismatches += 1
+                    self._device_fault("stale_result", "fused shadow mismatch")
+                    self.n_fallback_queries += nq
+                    self.n_dispatches += 1
+                    return hb, hj, hm, hq
+            sh = self.store_shards
+            if sh is not None and sh.active:
+                sh.note_success()
+            if hint.get("probing"):
+                self._restore_device()
+            self.n_dispatches += 1
+            self.n_fused_flushes += 1
+            self.n_fused_queries += nq
+            self.n_range_device_queries += n_range
+            if self.mesh is not None:
+                self.n_mesh_queries += nq
+            else:
+                self.n_dense_queries += nq
+            return tb, tj, tm, tq
 
     def fused_harvest(self, safe, hint, launch) -> None:
         """Store-task leg of a fused flush: parse this store's block of
@@ -3920,13 +3917,14 @@ class DeviceState:
         boundary in deterministic store order."""
         batch = hint["batch"]
         try:
-            tb, tj, tm, tq = self._fused_collect(hint, launch)
-            self.n_queries += hint["nq"]
-            self.n_kernel_deps += len(tj)
-            self._finalize_attr_entries(tb, tj, tm, tq, hint["ids"],
-                                        hint["ivs"], hint["qnp"],
-                                        hint["q_m"],
-                                        [b for _q, b, _d in batch])
+            with self._flush_span():
+                tb, tj, tm, tq = self._fused_collect(hint, launch)
+                self.n_queries += hint["nq"]
+                self.n_kernel_deps += len(tj)
+                self._finalize_attr_entries(tb, tj, tm, tq, hint["ids"],
+                                            hint["ivs"], hint["qnp"],
+                                            hint["q_m"],
+                                            [b for _q, b, _d in batch])
         except BaseException as e:  # noqa: BLE001
             for _q, _b, done in batch:
                 done(e, None)
@@ -4128,8 +4126,6 @@ class DeviceState:
         used_fused = False
         mode = None
         if not (self.host_pinned or self._dev_quar_flushes > 0):
-            import time as _time
-            _t0 = _time.perf_counter()
             if fused is not None and fused.serves(self):
                 try:
                     cand_slots = fused.result_for(self)
@@ -4146,70 +4142,72 @@ class DeviceState:
                 # ladder counter and not in n_host_ticks.  A widened
                 # wavefront (mid-cascade) is not re-priced: one antichain is
                 # what the host sweep finds, the level kernel finds W
-                cand_slots = self.drain.host_ready_slots()
-                self._ktime("drain_tick_host", _t0)
+                with self._span("drain_tick_host"):
+                    cand_slots = self.drain.host_ready_slots()
                 self.n_priced_host_ticks += 1
                 mode = "host-priced"
             else:
                 self._tick_dev_micros = self._node_micros()
                 try:
-                    _t0 = _time.perf_counter()
-                    dk.launch_check("drain")
-                    state, live = self.drain.state()
-                    faults.check("transfer", "drain download")
-                    wave = self._drain_wavefront
-                    fut = None
-                    if wave > 1 and drk.drain_logdepth_enabled():
-                        # widened sweep: the log-depth level pass prices one
-                        # launch for the next `wave` executeAt antichains.
-                        # Candidates beyond the true frontier are safe — the
-                        # per-candidate host re-validation below makes a
-                        # not-actually-ready candidate a no-op — and any
-                        # that fail to execute reset the wavefront
-                        try:
-                            if isinstance(state, drk.EllDrainState):
-                                mode = "ell-wave"
-                                lv, _r = drk.level_assign_ell(state)
-                            else:
-                                mode = "wave"
-                                lv, _r = drk.level_assign_dense(state)
-                            fut = (lv >= 1) & (lv <= wave)
-                            self.n_wavefront_ticks += 1
-                        except faults.DEVICE_EXCEPTIONS:
-                            # fail the widened launch over to the plain
-                            # frontier route, byte-identically (the W=1
-                            # candidate set); leave the outer handler to
-                            # the frontier's own faults
-                            self._drain_wavefront = wave = 1
-                            mode = None
-                            fut = None
-                    if wave > 1 and fut is not None:
-                        pass
-                    elif isinstance(state, drk.EllDrainState):
-                        # large in-flight set: sparse gather sweep (no [N, N])
-                        mode = "ell"
-                        fut = drk.ready_frontier_ell(state)
-                    elif self.mesh is not None and \
-                            state.status.shape[0] % \
-                            len(self.mesh.devices.flat) == 0 \
-                            and self._mesh_tick_pays(state.status.shape[0]):
-                        # live mesh path: the frontier sweep row-shards across
-                        # devices (fixpoint analogue: parallel.sharded.
-                        # sharded_drain)
-                        from ..parallel.sharded import sharded_ready_frontier
-                        mode = "mesh"
-                        fut = sharded_ready_frontier(self.mesh)(state)
-                    else:
-                        mode = "device"
-                        fut = drk.ready_frontier(state)
-                    # drain forensics: split the sweep at the async-dispatch
-                    # boundary — upload+enqueue vs the result join — so a
-                    # drain-bound regime shows WHERE the tick pays
-                    # (kernel_times rows + devprof drain_tick_* slices)
-                    _t1 = _time.perf_counter()
-                    ready = np.asarray(fut)[: len(live)]
-                    self._ktime_span("drain_tick_dispatch", _t0, _t1)
-                    self._ktime("drain_tick_wait", _t1)
+                    # drain forensics: the sweep is split at the async-
+                    # dispatch boundary, upload + enqueue against the
+                    # result join, so a drain-bound regime shows WHERE
+                    # the tick pays
+                    with self._span("drain_tick_dispatch"):
+                        dk.launch_check("drain")
+                        state, live = self.drain.state()
+                        faults.check("transfer", "drain download")
+                        wave = self._drain_wavefront
+                        fut = None
+                        if wave > 1 and drk.drain_logdepth_enabled():
+                            # widened sweep: the log-depth level pass
+                            # prices one launch for the next `wave`
+                            # executeAt antichains.  Candidates beyond the
+                            # true frontier are safe — the per-candidate
+                            # host re-validation below makes a not-
+                            # actually-ready candidate a no-op — and any
+                            # that fail to execute reset the wavefront
+                            try:
+                                if isinstance(state, drk.EllDrainState):
+                                    mode = "ell-wave"
+                                    lv, _r = drk.level_assign_ell(state)
+                                else:
+                                    mode = "wave"
+                                    lv, _r = drk.level_assign_dense(state)
+                                fut = (lv >= 1) & (lv <= wave)
+                                self.n_wavefront_ticks += 1
+                            except faults.DEVICE_EXCEPTIONS:
+                                # fail the widened launch over to the plain
+                                # frontier route, byte-identically (the W=1
+                                # candidate set); leave the outer handler to
+                                # the frontier's own faults
+                                self._drain_wavefront = wave = 1
+                                mode = None
+                                fut = None
+                        if wave > 1 and fut is not None:
+                            pass
+                        elif isinstance(state, drk.EllDrainState):
+                            # large in-flight set: sparse gather sweep
+                            # (no [N, N])
+                            mode = "ell"
+                            fut = drk.ready_frontier_ell(state)
+                        elif self.mesh is not None and \
+                                state.status.shape[0] % \
+                                len(self.mesh.devices.flat) == 0 \
+                                and self._mesh_tick_pays(
+                                    state.status.shape[0]):
+                            # live mesh path: the frontier sweep row-shards
+                            # across devices (fixpoint analogue:
+                            # parallel.sharded.sharded_drain)
+                            from ..parallel.sharded import \
+                                sharded_ready_frontier
+                            mode = "mesh"
+                            fut = sharded_ready_frontier(self.mesh)(state)
+                        else:
+                            mode = "device"
+                            fut = drk.ready_frontier(state)
+                    with self._span("drain_tick_wait"):
+                        ready = np.asarray(fut)[: len(live)]
                     cand_slots = live[ready & self.drain.active[live]]
                 except faults.DEVICE_EXCEPTIONS as e:
                     self._device_fault(e, f"drain tick: {e}")

@@ -47,7 +47,6 @@ for correctness); everything else is priced, not thresholded.
 from __future__ import annotations
 
 import os
-import time
 from functools import partial
 from typing import Dict, List, Optional
 
@@ -67,19 +66,14 @@ def fusion_enabled() -> bool:
         "off", "0", "false", "no")
 
 
-def _profiled_harvest(name, dev0, members, download):
-    """Run one fused-result ``download()`` under the device profiler (when
-    armed): the harvest-barrier slice, pid-matched to the dispatch slice's
-    node row.  Shared by the flush and tick harvest paths."""
-    prof = devprof.PROFILER
-    _t0 = time.perf_counter() if prof is not None else 0.0
-    out = download()
-    if prof is not None:
-        prof.complete(name, _t0, time.perf_counter(), cat="fused",
-                      pid=getattr(getattr(dev0.store, "node", None),
-                                  "node_id", 0) or 0,
-                      args={"members": members})
-    return out
+def _harvest_span(name, dev0, members):
+    """The span of one fused-result download: the harvest-barrier slice,
+    pid-matched to the dispatch slice's node row.  Shared by the flush and
+    tick harvest paths."""
+    return devprof.span(
+        name, pid=getattr(getattr(dev0.store, "node", None),
+                          "node_id", 0) or 0,
+        args={"members": members})
 
 
 class FusedFlushLaunch:
@@ -113,18 +107,16 @@ class FusedFlushLaunch:
             itemsize = 8 if self.wide else 4
             dev0 = self.hints[0]["dev"]
             faults.check("transfer", "fused header download")
-            hdr = _profiled_harvest(
-                "fused_flush_harvest_header", dev0,
-                n_s, lambda: np.asarray(self.hdr_dev))
+            with _harvest_span("fused_flush_harvest_header", dev0, n_s):
+                hdr = np.asarray(self.hdr_dev)
             hdr = hdr.reshape(n_s, 5 + self.b_pad)
             s_eff = self.d_ent * self.s
             maxtot = min(int(hdr[:, 0].max()), s_eff)
             length = _prefix_len(maxtot, s_eff)
             faults.check("transfer", "fused entry download")
             ent3 = self.ent_dev.reshape(n_s, s_eff)[:, :length]
-            ent = _profiled_harvest(
-                "fused_flush_harvest_entries", dev0,
-                n_s, lambda: np.asarray(ent3))
+            with _harvest_span("fused_flush_harvest_entries", dev0, n_s):
+                ent = np.asarray(ent3)
             # byte accounting lands on the first harvester (deterministic:
             # harvest order is store-id order)
             dev0.download_bytes += hdr.nbytes + ent.nbytes
@@ -166,9 +158,9 @@ class FusedTick:
             raise self.failed
         if self._out is None:
             faults.check("transfer", "fused drain download")
-            self._out = _profiled_harvest(
-                "fused_tick_harvest", self.members[0],
-                len(self.members), lambda: np.asarray(self.dev))
+            with _harvest_span("fused_tick_harvest", self.members[0],
+                               len(self.members)):
+                self._out = np.asarray(self.dev)
         i, live, _v = self.rows[id(dev)]
         ready = self._out[i][: len(live)]
         return live[ready & dev.drain.active[live]]
@@ -227,12 +219,19 @@ class DeviceDispatcher:
             self.node.scheduler.now(self._run_flushes)
 
     def _run_flushes(self) -> None:
-        from .command_store import PreLoadContext
         self._flush_scheduled = False
         devs = self._flush_pending
         self._flush_pending = []
         if not getattr(self.node, "alive", True):
             return    # dead incarnation (restart): ghost work must not run
+        # the planning of a tick's flushes (a fused launch with it); the
+        # flushes themselves are store tasks, each a ``srv.deps_flush``
+        with devprof.span("srv.deps_plan",
+                          getattr(self.node, "loop_times", None)):
+            self._plan_flushes(devs)
+
+    def _plan_flushes(self, devs) -> None:
+        from .command_store import PreLoadContext
         devs.sort(key=lambda d: d.store.store_id)
         plans = []
         for dev in devs:
@@ -255,7 +254,17 @@ class DeviceDispatcher:
                         hints[id(dev)] = h
                 if len(hints) >= 2 and \
                         self._fused_flush_pays(list(hints.values())):
-                    launch = self._launch_fused_flush(list(hints.values()))
+                    # pack + stack + async enqueue of ONE store-tagged
+                    # launch in place of len(hints) solo launches: the
+                    # coalescing win as a timeline slice (the harvest
+                    # lands in fused_flush_harvest_*)
+                    members = list(hints.values())
+                    with devprof.span(
+                            "fused_flush_dispatch",
+                            pid=getattr(self.node, "node_id", 0),
+                            args={"members": len(members),
+                                  "nq": sum(h["nq"] for h in members)}):
+                        launch = self._launch_fused_flush(members)
                 else:
                     hints = {}
             except BaseException as e:  # noqa: BLE001
@@ -360,8 +369,6 @@ class DeviceDispatcher:
         return 2.0 * rtt + c_dev * fused_elems + snap_cost < solo
 
     def _launch_fused_flush(self, hints) -> Optional[FusedFlushLaunch]:
-        prof = devprof.PROFILER
-        _t0 = time.perf_counter() if prof is not None else 0.0
         devs = [h["dev"] for h in hints]
         mesh = devs[0].mesh            # one node -> one mesh for all stores
         d = 1 if mesh is None else max(len(mesh.devices.flat), 1)
@@ -453,14 +460,6 @@ class DeviceDispatcher:
             return None
         self.n_fused_launches += 1
         self.n_fused_members += len(hints)
-        if prof is not None:
-            # pack + stack + async enqueue of ONE store-tagged launch in
-            # place of len(hints) solo launches — the coalescing win as a
-            # timeline slice (harvest lands in fused_flush_harvest)
-            prof.complete("fused_flush_dispatch", _t0, time.perf_counter(),
-                          cat="fused", pid=getattr(self.node, "node_id", 0),
-                          args={"members": len(hints),
-                                "nq": sum(h["nq"] for h in hints)})
         if self.on_fused is not None:
             self.on_fused("flush", len(hints),
                           sum(h["nq"] for h in hints))
@@ -543,10 +542,12 @@ class DeviceDispatcher:
             if len(group) < 2 or not self._fused_tick_pays(group, calib,
                                                            kind):
                 continue
-            prof = devprof.PROFILER
-            _t0 = time.perf_counter() if prof is not None else 0.0
             try:
-                out_dev = kernel([st for _d, st, _lv in group])
+                with devprof.span(
+                        "fused_tick_dispatch",
+                        pid=getattr(self.node, "node_id", 0),
+                        args={"members": len(group), "kind": kind}):
+                    out_dev = kernel([st for _d, st, _lv in group])
             except faults.DEVICE_EXCEPTIONS as e:
                 for dev, _st, _lv in group:
                     dev._device_fault(e, f"fused drain launch: {e}")
@@ -554,11 +555,6 @@ class DeviceDispatcher:
             ft = FusedTick(out_dev, group)
             self.n_fused_tick_launches += 1
             self.n_fused_tick_members += len(group)
-            if prof is not None:
-                prof.complete("fused_tick_dispatch", _t0,
-                              time.perf_counter(), cat="fused",
-                              pid=getattr(self.node, "node_id", 0),
-                              args={"members": len(group), "kind": kind})
             if self.on_fused is not None:
                 self.on_fused("tick", len(group), 0)
             for dev, _st, _lv in group:

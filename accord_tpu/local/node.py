@@ -17,6 +17,7 @@ from ..primitives.timestamp import Domain, Timestamp, TxnId, TxnKind
 from ..primitives.txn import Txn
 from ..topology.manager import TopologyManager
 from ..topology.topology import Topologies, Topology
+from ..obs import devprof
 from ..utils import async_chain, invariants
 from .command_store import CommandStores, PreLoadContext
 from .fastpath import proto_fastpath_enabled
@@ -114,6 +115,11 @@ class Node:
         # control verbs / reconfig gossip at the envelope unbatcher)
         self.n_grouped_ops = 0
         self.n_group_fallbacks = 0
+        # a serving node's span table and the requests delivered under its
+        # ``srv.req.<T>`` spans (NodeServer.loop_times / loop_members);
+        # None in a sim, whose spans then feed an armed profiler alone
+        self.loop_times: Optional[dict] = None
+        self.loop_members: Optional[dict] = None
 
     # -- time (ref: Node.java:341-366) --------------------------------------
     HLC_RESERVE_BATCH = 1 << 20   # ids per journal reservation write
@@ -323,7 +329,8 @@ class Node:
                 lambda _t, fail: self.receive(request, from_id, reply_context)
                 if fail is None else None)
             return
-        self.scheduler.now(lambda: self._process(request, from_id, reply_context))
+        self.scheduler.now(lambda: self._process_run(
+            ((request, reply_context),), from_id))
 
     def receive_group(self, items, from_id: int) -> None:
         """r20 store-grouped delivery: a run of protocol requests from one
@@ -346,12 +353,26 @@ class Node:
         if not ready:
             return
         self.n_grouped_ops += len(ready)
+        self.scheduler.now(lambda: self._process_run(ready, from_id))
 
-        def run():
-            for request, reply_context in ready:
-                self._process(request, from_id, reply_context)
-
-        self.scheduler.now(run)
+    def _process_run(self, items, from_id: int) -> None:
+        """Requests delivered together, processed back to back in this one
+        callback: each run of one verb under one ``srv.req.<T>`` span, its
+        members counted beside it where a serving node asks."""
+        members = self.loop_members
+        i, n = 0, len(items)
+        while i < n:
+            verb = type(items[i][0])
+            j = i + 1
+            while j < n and type(items[j][0]) is verb:
+                j += 1
+            name = "srv.req." + verb.__name__
+            with devprof.span(name, self.loop_times):
+                for request, reply_context in items[i:j]:
+                    self._process(request, from_id, reply_context)
+            if members is not None:
+                members[name] = members.get(name, 0) + (j - i)
+            i = j
 
     def witness_timestamp(self, ts) -> None:
         """HLC receive rule: merge a remotely-witnessed timestamp into the
@@ -483,7 +504,8 @@ class Node:
             if sp is not None:
                 sp.event(str(txn_id), "watchdog_recover")
             route = self.compute_route(txn_id, txn.keys)
-            Recover.recover(self, txn_id, route, txn).begin(on_recovered)
+            Recover.recover(self, txn_id, route, txn,
+                            cause="watchdog").begin(on_recovered)
 
         def on_recovered(value, failure):
             if result.is_done() or superseded["flag"]:
@@ -561,7 +583,8 @@ class Node:
         def adopt():
             # the old id reached a decision after all: finish it and hand
             # its outcome to the client rather than re-running the payload
-            Recover.recover(self, old_id, route, txn).begin(adopted)
+            Recover.recover(self, old_id, route, txn,
+                            cause="adopt").begin(adopted)
 
         def adopted(value, failure):
             if failure is not None:

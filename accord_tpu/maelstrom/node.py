@@ -37,6 +37,7 @@ from ..coordinate.errors import Timeout
 from ..local.fastpath import proto_fastpath_enabled, store_group_enabled
 from ..impl.config_service import AbstractConfigurationService
 from ..local.node import Node
+from ..obs import devprof
 from ..primitives.datum import datum_from_json, datum_to_json
 from ..primitives.keys import IntKey, Keys, Range, Ranges
 from ..primitives.txn import Txn
@@ -139,6 +140,9 @@ class MaelstromSink(api.MessageSink):
         # does the transport know a peer is down (a process without links
         # knows no such thing)
         self._peer_down = getattr(process, "peer_known_down", None)
+        # the serving loop's span table (a reply delivered is one
+        # ``srv.rsp.<T>`` span); None = nobody's
+        self._times = getattr(process, "loop_times", None)
         # how callbacks failed (obs.metrics.PEER_COUNTERS names them)
         self.n_failed_at_once = 0         # peer known down at the send
         self.n_failed_by_drop = 0         # pending when its link dropped
@@ -343,7 +347,8 @@ class MaelstromSink(api.MessageSink):
         final = reply.is_final() if hasattr(reply, "is_final") else True
         if final:
             self._resolve(in_reply_to)
-        p.callback.on_success(from_id, reply)
+        with devprof.span("srv.rsp." + type(reply).__name__, self._times):
+            p.callback.on_success(from_id, reply)
 
     def on_failure_response(self, from_id: int, in_reply_to: int,
                             error: str) -> None:
@@ -424,6 +429,12 @@ class MaelstromProcess:
         # name -> bool: does the transport know this peer is down (the TCP
         # server answers from its links; None = nobody knows such a thing)
         self.link_down: Optional[Callable[[str], bool]] = None
+        # the serving loop's span table and the requests delivered under
+        # its ``srv.req.<T>`` spans (NodeServer.loop_times / loop_members,
+        # handed on to the Node; None = nobody's: the sim runner, the
+        # Maelstrom harness)
+        self.loop_times: Optional[dict] = None
+        self.loop_members: Optional[dict] = None
         self.name: Optional[str] = None
         self.node: Optional[Node] = None
         self.sink: Optional[MaelstromSink] = None
@@ -538,7 +549,8 @@ class MaelstromProcess:
                           f"{(sub or {}).get('type')}: {exc!r}",
                           file=sys.stderr)
         elif typ == "accord_req":
-            request = wire.decode(body["payload"])
+            with devprof.span("srv.decode", self.loop_times):
+                request = wire.decode(body["payload"])
             try:
                 # r16: the inbound doc IS wire.encode(request) (the
                 # golden-frame gate pins decode∘encode as the identity) —
@@ -560,14 +572,18 @@ class MaelstromProcess:
                 # free at the server), so the re-encode here is cheap
                 # and rare.
                 self.reconfig.note_snapshot_reply(body)
-            reply = wire.decode(payload)
+            with devprof.span("srv.decode", self.loop_times):
+                reply = wire.decode(payload)
             self.sink.on_response(node_name_to_id(src), body["in_reply_to"],
                                   reply)
         elif typ == "accord_fail":
             self.sink.on_failure_response(node_name_to_id(src),
                                           body["in_reply_to"], body["error"])
         elif typ == "txn":
-            self._handle_txn(src, body)
+            # admission to hand-off: the coordination it starts goes on
+            # under the spans of the replies that drive it
+            with devprof.span("srv.txn", self.loop_times):
+                self._handle_txn(src, body)
         elif self.control_fallback is not None:
             # serving-surface control bodies (topo_new / epoch_sync /
             # topo_fetch / codec_hello / accord_chunk) that rode a peer
@@ -601,7 +617,8 @@ class MaelstromProcess:
             styp = (sub or {}).get("type")
             if styp == "accord_req":
                 try:
-                    request = wire.decode(sub["payload"])
+                    with devprof.span("srv.decode", self.loop_times):
+                        request = wire.decode(sub["payload"])
                     try:
                         request._wire_doc = sub["payload"]
                     except AttributeError:
@@ -672,6 +689,8 @@ class MaelstromProcess:
             device_mode=self.device_mode,
             journal=self.journal)
         self.node.obs = self.obs
+        self.node.loop_times = self.loop_times
+        self.node.loop_members = self.loop_members
         if self.journal is not None and self.journal.has_restored_state():
             # kill -9 recovery: re-ingest the epoch history WITHOUT
             # re-bootstrapping, seed the fresh data store with the

@@ -39,6 +39,7 @@ from typing import List
 
 from .. import api
 from ..local.fastpath import proto_fastpath_enabled
+from ..obs import devprof
 from ..utils import faults, invariants
 from ..utils.random_source import RandomSource
 from . import bootstrap as net_bootstrap
@@ -68,9 +69,18 @@ class _Scheduled(api.Scheduled):
 class AsyncioScheduler(api.Scheduler):
     """api.Scheduler over the asyncio event loop (micros in, seconds out)."""
 
-    def __init__(self, loop: asyncio.AbstractEventLoop):
+    def __init__(self, loop: asyncio.AbstractEventLoop,
+                 times: Optional[dict] = None):
         self.loop = loop
         self.stopped = False
+        # the serving loop's span table (NodeServer.loop_times): a fired
+        # callback is one ``srv.timer`` span; beside it how many fired and
+        # how late (fired - due, the loop's clock: a slow loop shows here
+        # before it shows as a timeout or a recovery)
+        self.times = times
+        self.n_fires = 0
+        self.lag_s = 0.0
+        self.lag_max_s = 0.0
 
     def stop(self) -> None:
         """Nothing scheduled through here runs from now on, and nothing
@@ -81,32 +91,44 @@ class AsyncioScheduler(api.Scheduler):
         if not self.stopped:
             self.loop.call_soon(run)
 
+    def _fire(self, due: float, run: Callable[[], None]) -> None:
+        lag = max(self.loop.time() - due, 0.0)
+        self.n_fires += 1
+        self.lag_s += lag
+        if lag > self.lag_max_s:
+            self.lag_max_s = lag
+        with devprof.span("srv.timer", self.times):
+            run()
+
     def once(self, delay_micros: int, run: Callable[[], None]) -> api.Scheduled:
         sched = _Scheduled()
+        due = self.loop.time() + delay_micros / 1e6
 
         def fire():
             if not sched.cancelled and not self.stopped:
-                run()
-        sched.handle = self.loop.call_later(delay_micros / 1e6, fire)
+                self._fire(due, run)
+        sched.handle = self.loop.call_at(due, fire)
         return sched
 
     def recurring(self, interval_micros: int,
                   run: Callable[[], None]) -> api.Scheduled:
         sched = _Scheduled()
+        due = self.loop.time() + interval_micros / 1e6
 
         def fire():
+            nonlocal due
             if sched.cancelled or self.stopped:
                 return
             try:
-                run()
+                self._fire(due, run)
             finally:
                 # reschedule even if run() raised: the timeout sweeper
                 # rides this — if one sweep's failure callback blows up,
                 # the node must keep detecting timeouts, not wedge with
                 # every future dead-peer request pending forever
-                sched.handle = self.loop.call_later(
-                    interval_micros / 1e6, fire)
-        sched.handle = self.loop.call_later(interval_micros / 1e6, fire)
+                due = self.loop.time() + interval_micros / 1e6
+                sched.handle = self.loop.call_at(due, fire)
+        sched.handle = self.loop.call_at(due, fire)
         return sched
 
 
@@ -199,6 +221,16 @@ class NodeServer:
         self.n_fast_sheds = 0          # sheds decided pre-body-decode
         self.scheduler: Optional[AsyncioScheduler] = None
         self.crashed = False           # crash_stop() ran: nothing in or out
+        # what the serving loop spends its time on: span name -> [calls,
+        # seconds] (obs.devprof.span; the node, its sink, stores,
+        # dispatcher, scheduler and journal write the ``srv.*`` names
+        # into this one table, on the loop's thread), and beside a
+        # ``srv.req.<T>`` span the requests delivered under it.  Surfaced
+        # as stats()["loop"]; no table, and so no clock, under
+        # ACCORD_TPU_OBS=off
+        on = devprof.enabled()
+        self.loop_times: Optional[Dict[str, list]] = {} if on else None
+        self.loop_members: Optional[Dict[str, int]] = {} if on else None
 
     def now_micros(self) -> int:
         return (time.monotonic_ns() - self._start_ns) // 1_000
@@ -208,6 +240,8 @@ class NodeServer:
     # (at-most-once delivery allows it; the client's timeout owns
     # recovery) — the admission contract is bounded resources everywhere
     CLIENT_WRITE_BUFFER_CAP = 4 * 1024 * 1024
+    # finished txn span trees a node keeps (obs.spans.SpanRecorder's ring)
+    SPAN_ROOTS_KEPT = 4096
     # most bodies one accord_batch envelope carries (a pathological tick
     # chunks instead of building a frame that courts MAX_FRAME)
     MAX_BATCH_OPS = 512
@@ -242,39 +276,40 @@ class NodeServer:
         and every client connection's pending reply frames leave as one
         joined write.  Batching here is pure transport amortization: the
         receiver unbatches into the unchanged per-op protocol path."""
-        self._flush_scheduled = False
-        if self._peer_pend:
-            pend, self._peer_pend = self._peer_pend, {}
-            for dest, bodies in pend.items():
-                # chunk a pathological tick: one envelope must never
-                # approach MAX_FRAME (a lost giant frame would take every
-                # rider with it; the queue bound already caps frames)
-                for at in range(0, len(bodies), self.MAX_BATCH_OPS):
-                    chunk = bodies[at:at + self.MAX_BATCH_OPS]
-                    if len(chunk) == 1:
-                        body = chunk[0]
-                    else:
-                        body = {"type": "accord_batch", "msgs": chunk}
-                        self.n_batched_fanouts += 1
-                        self.n_batched_ops += len(chunk)
-                    n = len(chunk)
-                    self.batch_sizes[n] = self.batch_sizes.get(n, 0) + 1
-                    try:
-                        # no byte-overflow fallback needed here anymore:
-                        # _send_peer_body chunk-streams ANY payload over
-                        # CHUNK_THRESHOLD (1 MiB), so an envelope can
-                        # never approach MAX_FRAME whole
-                        self._send_peer_body(dest, body)
-                    except Exception as exc:   # one peer's bad frame must
-                        # not drop every OTHER peer's batch this tick
-                        print(f"[{self.name}] batch encode to {dest} "
-                              f"failed: {exc!r}", file=sys.stderr)
-        if self._client_pend:
-            pend, self._client_pend = self._client_pend, {}
-            for writer, (dest, frames) in pend.items():
-                self._write_bounded(
-                    dest, writer,
-                    frames[0] if len(frames) == 1 else b"".join(frames))
+        with devprof.span("srv.flush_tick", self.loop_times):
+            self._flush_scheduled = False
+            if self._peer_pend:
+                pend, self._peer_pend = self._peer_pend, {}
+                for dest, bodies in pend.items():
+                    # chunk a pathological tick: one envelope must never
+                    # approach MAX_FRAME (a lost giant frame would take every
+                    # rider with it; the queue bound already caps frames)
+                    for at in range(0, len(bodies), self.MAX_BATCH_OPS):
+                        chunk = bodies[at:at + self.MAX_BATCH_OPS]
+                        if len(chunk) == 1:
+                            body = chunk[0]
+                        else:
+                            body = {"type": "accord_batch", "msgs": chunk}
+                            self.n_batched_fanouts += 1
+                            self.n_batched_ops += len(chunk)
+                        n = len(chunk)
+                        self.batch_sizes[n] = self.batch_sizes.get(n, 0) + 1
+                        try:
+                            # no byte-overflow fallback needed here anymore:
+                            # _send_peer_body chunk-streams ANY payload over
+                            # CHUNK_THRESHOLD (1 MiB), so an envelope can
+                            # never approach MAX_FRAME whole
+                            self._send_peer_body(dest, body)
+                        except Exception as exc:   # one peer's bad frame must
+                            # not drop every OTHER peer's batch this tick
+                            print(f"[{self.name}] batch encode to {dest} "
+                                  f"failed: {exc!r}", file=sys.stderr)
+            if self._client_pend:
+                pend, self._client_pend = self._client_pend, {}
+                for writer, (dest, frames) in pend.items():
+                    self._write_bounded(
+                        dest, writer,
+                        frames[0] if len(frames) == 1 else b"".join(frames))
 
     def _send_peer_body(self, dest: str, body: dict) -> None:
         """Encode-once peer send: a body whose payload outgrows
@@ -323,10 +358,13 @@ class NodeServer:
             return
         writer = self._clients.get(dest)
         if writer is not None:
-            self.n_client_replies += 1
-            self._send_client(dest, writer, encode_frame(
-                {"src": self.name, "dest": dest, "body": body},
-                self._client_codec.get(dest, "json")))
+            # one reply frame to a client: the span's count is the
+            # replies (txn_ok, errors, control answers) sent
+            with devprof.span("srv.client_reply", self.loop_times):
+                self.n_client_replies += 1
+                self._send_client(dest, writer, encode_frame(
+                    {"src": self.name, "dest": dest, "body": body},
+                    self._client_codec.get(dest, "json")))
             return
         # init_ok to the synthetic "boot" client, or a reply to a client
         # whose connection is gone: at-most-once delivery — drop
@@ -353,38 +391,41 @@ class NodeServer:
         the cheapest outcome the admission contract promises even now
         that decode is the next-biggest per-request cost.  JSON (debug
         codec) frames take the full-decode path below."""
-        hdr = wire_codec.peek_header(payload)
-        if hdr is not None and hdr[0] == wire_codec.KIND_TXN \
-                and self.gate is not None and self.proc is not None:
-            _kind, src, msg_id = hdr
-            self._clients[src] = writer
-            self._client_codec[src] = "binary"
-            if msg_id is not None \
-                    and self.gate.inflight >= self.gate.effective_budget():
-                # duplicate of an already-answered request? the journaled
-                # at-most-once table replays it even under overload —
-                # dedupe outranks shedding (it costs one dict lookup)
-                j = self.proc.journal
-                stored = (j.replied_body(src, msg_id)
-                          if j is not None and hasattr(j, "replied_body")
-                          else None)
-                if stored is None:
-                    admitted, reason, retry_ms = self.gate.try_admit()
-                    if admitted:
-                        # a release raced the peek: keep the slow path's
-                        # single admission point authoritative
-                        self.gate.unadmit()
-                    else:
-                        self.n_fast_sheds += 1
-                        self.proc._reply_client(src, msg_id, {
-                            "type": "error", "code": 11,
-                            "text": "overloaded", "overloaded": True,
-                            "reason": reason, "retry_after_ms": retry_ms})
-                        return
-        try:
-            packet = decode_payload(payload)
-        except ValueError:
-            raise   # FrameServer counts + drops this connection
+        with devprof.span("srv.decode", self.loop_times):
+            hdr = wire_codec.peek_header(payload)
+            if hdr is not None and hdr[0] == wire_codec.KIND_TXN \
+                    and self.gate is not None and self.proc is not None:
+                _kind, src, msg_id = hdr
+                self._clients[src] = writer
+                self._client_codec[src] = "binary"
+                if msg_id is not None and \
+                        self.gate.inflight >= self.gate.effective_budget():
+                    # duplicate of an already-answered request? the
+                    # journaled at-most-once table replays it even under
+                    # overload — dedupe outranks shedding (it costs one
+                    # dict lookup)
+                    j = self.proc.journal
+                    stored = (j.replied_body(src, msg_id)
+                              if j is not None
+                              and hasattr(j, "replied_body") else None)
+                    if stored is None:
+                        admitted, reason, retry_ms = self.gate.try_admit()
+                        if admitted:
+                            # a release raced the peek: keep the slow
+                            # path's single admission point authoritative
+                            self.gate.unadmit()
+                        else:
+                            self.n_fast_sheds += 1
+                            self.proc._reply_client(src, msg_id, {
+                                "type": "error", "code": 11,
+                                "text": "overloaded", "overloaded": True,
+                                "reason": reason,
+                                "retry_after_ms": retry_ms})
+                            return
+            try:
+                packet = decode_payload(payload)
+            except ValueError:
+                raise   # FrameServer counts + drops this connection
         self._on_packet(packet, writer,
                         binary=payload[0] == wire_codec.MAGIC,
                         nbytes=len(payload))
@@ -674,6 +715,7 @@ class NodeServer:
             "coordination": self._coordination_stats(),
             "data": self._data_stats(),
             "peer_failures": self._peer_failure_stats(),
+            "loop": self._loop_stats(),
             "unroutable": self.n_unroutable,
             "reply_drops": self.n_reply_drops,
             "frame_errors": (self.frame_server.n_frame_errors
@@ -758,6 +800,21 @@ class NodeServer:
         out["peer_up_events"] = sum(l.n_ups for l in self.links.values())
         return out
 
+    def _loop_stats(self) -> dict:
+        """What the serving loop spent its time on so far: every ``srv.*``
+        span as ``name: [calls, seconds]`` (inclusive of the spans nested
+        under it; PERF.md §3 lists the sites), ``members`` (requests
+        delivered under each ``srv.req.<T>`` span), and the timers: how
+        many fired, the sum and the maximum of fired - due in seconds."""
+        out = {name: list(cell)
+               for name, cell in sorted((self.loop_times or {}).items())}
+        sched = self.scheduler
+        out["members"] = dict(sorted((self.loop_members or {}).items()))
+        out["timer_fires"] = sched.n_fires if sched else 0
+        out["timer_lag_s"] = sched.lag_s if sched else 0.0
+        out["timer_lag_max_s"] = sched.lag_max_s if sched else 0.0
+        return out
+
     def _data_stats(self) -> Optional[dict]:
         """Calls of, and host clock inside, this replica's data store's
         range read (KVDataStore.read_range); None before start()."""
@@ -785,8 +842,11 @@ class NodeServer:
         self.loop = asyncio.get_event_loop()
         faults.arm_socket_faults_from_env()
         faults.arm_disk_faults_from_env()
-        scheduler = self.scheduler = AsyncioScheduler(self.loop)
-        obs = Observability(now=self.now_micros)
+        scheduler = self.scheduler = AsyncioScheduler(self.loop,
+                                                      self.loop_times)
+        # a node serves for days: finished span trees leave through a ring
+        obs = Observability(now=self.now_micros,
+                            retire_roots=self.SPAN_ROOTS_KEPT)
         if self.journal_dir:
             # durable journal (r13): recover-or-create BEFORE the node
             # exists — the restored state rides into MaelstromProcess's
@@ -809,6 +869,8 @@ class NodeServer:
                 metrics=obs.metrics,
                 async_exec=_async_exec,
                 sync_policy=self.journal_sync)
+            self.journal.wal.times = self.journal.commit.times = \
+                self.loop_times
         # elastic serving (r17): the reconfiguration manager owns the
         # epoch ledger, the topology gossip and the dynamic link
         # lifecycle; it recovers any journaled epoch history FIRST so a
@@ -827,6 +889,8 @@ class NodeServer:
             durability=self.durability, obs=obs,
             journal=self.journal)
         self.proc.reconfig = self.reconfig
+        self.proc.loop_times = self.loop_times
+        self.proc.loop_members = self.loop_members
         self.proc.control_fallback = self._control_fallback
         self.proc.link_down = self._link_down
         if self.request_timeout_ms is not None:

@@ -33,19 +33,12 @@ protocol stats, which the verification gates read.
 
 from __future__ import annotations
 
-import os
 from typing import Callable, Optional
 
+from .devprof import enabled
 from .flight import FlightRecorder
 from .metrics import MetricsRegistry
 from .spans import SpanRecorder
-
-
-def enabled() -> bool:
-    """The ACCORD_TPU_OBS escape hatch: default ON; "off"/"0"/"false"/"no"
-    disables spans, histograms and the device profiler."""
-    return os.environ.get("ACCORD_TPU_OBS", "").lower() not in (
-        "off", "0", "false", "no")
 
 
 class Observability:
@@ -55,11 +48,15 @@ class Observability:
     pure function of the seed."""
 
     def __init__(self, now: Optional[Callable[[], int]] = None,
-                 spans_on: Optional[bool] = None):
+                 spans_on: Optional[bool] = None,
+                 retire_roots: Optional[int] = None):
         self.metrics = MetricsRegistry()
         on = enabled() if spans_on is None else spans_on
+        # ``retire_roots``: a serving node's ring of finished span trees
+        # (SpanRecorder); a run keeps every tree for its export
         self.spans: Optional[SpanRecorder] = (
-            SpanRecorder(now or (lambda: 0), self.metrics) if on else None)
+            SpanRecorder(now or (lambda: 0), self.metrics,
+                         retire_roots=retire_roots) if on else None)
         # the black-box flight recorder stands down with the spans (the
         # ACCORD_TPU_OBS=off escape hatch is total); when live it taps the
         # span recorder so phase completions and txn events need no second

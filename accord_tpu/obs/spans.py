@@ -24,20 +24,28 @@ deterministic scheduler order).  Span durations feed the registry's
 
 Bounded like utils.trace.Trace: past ``capacity`` spans new work is
 dropped (counted), never an error — a handle may be None and every
-operation accepts that."""
+operation accepts that.  A run (sim, burn, maelstrom) keeps every tree it
+records, for the export.  A SERVING node's recorder (``retire_roots=N``)
+keeps the open trees and a ring of the last N finished ones: a tree whose
+spans have all ended (its root too, unless the root is the synthetic one
+of a txn coordinated elsewhere) leaves ``roots`` for the ring, and the
+ring's oldest leaves the recorder (``retired`` counts them), so
+``phase_micros`` keeps feeding the admission gate and memory stops growing
+with uptime."""
 
 from __future__ import annotations
 
+import collections
 import itertools
 import json
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Deque, Dict, List, Optional
 
 from .metrics import MetricsRegistry
 
 
 class Span:
     __slots__ = ("seq", "key", "name", "node", "start", "end", "attrs",
-                 "events", "children")
+                 "events", "children", "open")
 
     def __init__(self, seq: int, key: str, name: str, node, start: int):
         self.seq = seq
@@ -49,6 +57,12 @@ class Span:
         self.attrs: Dict[str, object] = {}
         self.events: List[dict] = []
         self.children: List["Span"] = []
+        # on a root, the spans of its tree still open (the root itself
+        # while it waits for end_txn; a synthetic root, which nobody will
+        # end, does not count itself).  A phase finds its root by its key:
+        # a pointer back would make every finished tree a cycle, which
+        # leaves a serving node's ring only at a full collection
+        self.open = 0
 
     def render(self) -> dict:
         out = {"seq": self.seq, "txn": self.key, "name": self.name,
@@ -69,7 +83,8 @@ class SpanRecorder:
 
     def __init__(self, clock: Callable[[], int],
                  metrics: Optional[MetricsRegistry] = None,
-                 capacity: int = 200_000):
+                 capacity: int = 200_000,
+                 retire_roots: Optional[int] = None):
         self.clock = clock
         self.metrics = metrics
         # flight-recorder tap (obs.flight): completions and txn events
@@ -77,27 +92,48 @@ class SpanRecorder:
         self.flight = None
         self.capacity = capacity
         self._seq = itertools.count()
+        # key -> root, in creation order (which the export keeps); with
+        # ``retire_roots`` the trees still open alone
         self.roots: Dict[str, Span] = {}
-        self._order: List[Span] = []     # roots in creation order
-        self.n_spans = 0
+        self.n_spans = 0                 # resident: roots + finished ring
         self.n_events = 0                # point events share the same cap
-        self.dropped = 0
+        self.dropped = 0                 # refused at capacity
+        # a serving node's ring of finished trees (None: keep everything)
+        self.retire_roots = retire_roots
+        self.finished: Deque[Span] = collections.deque()
+        self.retired = 0                 # trees that left the ring
 
     # -- recording -----------------------------------------------------------
-    def _root(self, key: str, node=None) -> Optional[Span]:
+    def _root(self, key: str, node=None,
+              synthetic: bool = True) -> Optional[Span]:
         root = self.roots.get(key)
         if root is None:
             if self.n_spans >= self.capacity:
                 self.dropped += 1
                 return None
             root = Span(next(self._seq), key, "txn", node, self.clock())
+            root.open = 0 if synthetic else 1
             self.roots[key] = root
-            self._order.append(root)
             self.n_spans += 1
         return root
 
+    def _closed(self, root: Span) -> None:
+        """One span of ``root``'s tree ended: with a ring, a tree with
+        none left open moves there and the ring's oldest leaves."""
+        root.open -= 1
+        if self.retire_roots is None or root.open > 0 \
+                or self.roots.get(root.key) is not root:
+            return
+        del self.roots[root.key]
+        self.finished.append(root)
+        while len(self.finished) > self.retire_roots:
+            old = self.finished.popleft()
+            self.n_spans -= 1 + len(old.children)
+            self.n_events -= len(old.events)
+            self.retired += 1
+
     def begin_txn(self, key: str, node=None, **attrs) -> Optional[Span]:
-        root = self._root(key, node)
+        root = self._root(key, node, synthetic=False)
         if root is not None and attrs:
             root.attrs.update(attrs)
         return root
@@ -115,6 +151,7 @@ class SpanRecorder:
             if self.metrics is not None:
                 self.metrics.histogram("phase_micros", phase="txn").observe(
                     root.end - root.start)
+            self._closed(root)
 
     def begin(self, key: str, phase: str, node=None,
               **attrs) -> Optional[Span]:
@@ -131,6 +168,7 @@ class SpanRecorder:
         if attrs:
             sp.attrs.update(attrs)
         root.children.append(sp)
+        root.open += 1
         self.n_spans += 1
         return sp
 
@@ -146,6 +184,7 @@ class SpanRecorder:
         if self.metrics is not None:
             self.metrics.histogram("phase_micros", phase=span.name).observe(
                 span.end - span.start)
+        self._closed(self.roots[span.key])   # open, so still there
 
     def event(self, key: str, name: str, **attrs) -> None:
         """Point event on a txn's root — dropped (not created) for txn
@@ -179,10 +218,11 @@ class SpanRecorder:
 
     # -- export --------------------------------------------------------------
     def export(self) -> List[dict]:
-        """Root span trees in creation (= deterministic scheduler) order;
+        """Root span trees in creation (= deterministic scheduler) order
+        (a serving node's: the ring of finished trees, then the open ones);
         open spans export with ``end: null`` — a crashed coordinator's
         trace is part of the record, not an error."""
-        return [r.render() for r in self._order]
+        return [r.render() for r in (*self.finished, *self.roots.values())]
 
     def export_json(self) -> str:
         """Canonical bytes: sorted keys, no whitespace variance — the

@@ -257,7 +257,7 @@ def test_bucket_device_uploads_cells_or_the_whole_table(path):
     table_bytes = deps._brec.nbytes
     assert table_bytes == 64 * deps.BUCKET_K * 48
     assert {k: c for k, (c, _s) in dev.kernel_times.items()} == \
-        {"sync_bucket_full": 1, "sync_tables": 1}
+        {"sync_bucket_full": 1, "sync_tables": 1, "register": 20}
     assert (dev.n_bucket_cells_uploaded, dev.bucket_upload_bytes) == \
         (0, table_bytes)
     # the first sync sent every column of the three tables, by no program

@@ -578,7 +578,12 @@ async def _orphan_outside_the_home_shard(journal_root):
             None if dest in n5.links and dest != "n4" else emit(dest, body))
         attempt = asyncio.ensure_future(c.client.submit(
             [["append", key, 7] for key in keys], node=CRASH))
-        await asyncio.sleep(0.1)
+        # crash once n4 has witnessed it (a fixed 0.1 s was too short for a
+        # loaded machine: n4 had nothing yet and no orphan existed)
+        t0 = time.monotonic()
+        while not any(o[0] == "n4" for o in c.orphans()) \
+                and time.monotonic() - t0 < 10.0:
+            await asyncio.sleep(0.02)
         n5.crash_stop()
         await c.client.remove_node(CRASH)
         with pytest.raises(ConnectionError):

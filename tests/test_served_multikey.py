@@ -110,7 +110,15 @@ def served_run(tmp_path_factory):
     from accord_tpu.local.device_index import DeviceState
     threshold = gc.get_threshold()
     calib = DeviceState._CALIB
-    DeviceState._CALIB = None    # the run prices with what it measures
+    # The run prices with what this host measures, but for the link and
+    # the sweep: a round trip is the chip's millisecond (a CPU "device"
+    # answers in 10 us) and a swept row an idle core's microsecond.  Under
+    # tier-1's six workers the probe read the sweep 5-10 x slower, and with
+    # a 10 us round trip beside it a live set of ten rows priced to the
+    # device: ticks that were no audits, some of them fused, which
+    # test_served_drain_ticks_are_swept_on_the_host_by_price then counted.
+    DeviceState._CALIB = {**DeviceState._measure_route_calibration(),
+                          "rtt": 1e-3, "rtt_mesh": 1e-3, "c_sweep": 1e-6}
     try:
         return asyncio.run(_serve_and_drive(tmp_path_factory.mktemp("wal")))
     finally:
