@@ -9,6 +9,7 @@ the read once its drain releases the txn.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, Optional, Set
 
 from .. import api
@@ -17,12 +18,14 @@ from ..messages.read_data import ReadNack, ReadOk, ReadTxnData
 from ..primitives.deps import Deps
 from ..primitives.keys import Route
 from ..primitives.timestamp import Timestamp, TxnId
-from ..obs import spans_of
+from ..obs import devprof, spans_of
 from ..primitives.txn import Txn
 from ..utils import async_chain
 from .errors import Exhausted, Timeout
 from .persist import persist
 from .tracking import QuorumTracker, ReadTracker, RequestStatus
+
+_NO_SPAN = contextlib.nullcontext()
 
 
 def execute(node, txn_id: TxnId, txn: Txn, route: Route,
@@ -98,6 +101,8 @@ class _ExecuteTxn(api.Callback):
                     read_nodes=sorted(self.read_nodes))
         for n in self.read_nodes:
             self.read_tracker.record_in_flight(n)
+        self.node.n_deps_txns += 1
+        self.node.n_deps_entries += self.deps.txn_id_count()
         for to in sorted(self.stable_tracker.nodes()):
             request = Commit(CommitKind.Stable, self.txn_id, self.txn,
                              self.route, self.execute_at, self.deps,
@@ -171,9 +176,17 @@ class _ExecuteTxn(api.Callback):
         if self.done or not (self.stable_done and self.read_done):
             return
         self.done = True
-        writes = self.txn.execute(self.txn_id, self.execute_at, self.data)
-        result = (self.txn.result(self.txn_id, self.execute_at, self.data)
-                  if self.txn.query is not None else None)
+        # a stored procedure (sim/tpcc.py) is evaluated here, once, for
+        # its writes and its result alike; recovery re-executes through
+        # this same leg
+        with (devprof.span("srv.proc", getattr(self.node, "loop_times", None))
+              if getattr(self.txn.update, "is_procedure", False)
+              else _NO_SPAN):
+            writes = self.txn.execute(self.txn_id, self.execute_at,
+                                      self.data)
+            result = (self.txn.result(self.txn_id, self.execute_at,
+                                      self.data)
+                      if self.txn.query is not None else None)
         persist(self.node, self.txn_id, self.txn, self.route, self.execute_at,
                 self.deps, writes, result)
         # client is answered at persist-start (ref: CoordinationAdapter:189-194).

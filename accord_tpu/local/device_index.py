@@ -456,6 +456,11 @@ class _DepsMirror:
         self._attr_repl_key = None
         self._attr_sh = None                      # slot-sharded under a mesh
         self._attr_sh_key = None
+        # footprint width doublings (_grow_intervals: each a whole-table
+        # re-upload and a new program shape) and entries a full bucket
+        # spilled into ``wide_entries``
+        self.n_interval_grows = 0
+        self.n_wide_spills = 0
 
     # -- bucket index maintenance -------------------------------------------
     def bucket_keff(self) -> int:
@@ -503,6 +508,7 @@ class _DepsMirror:
                 # overflow spill: the straggler list absorbs hot buckets
                 self.wide_entries.add((lo, hi, slot, col))
                 self.wide_version += 1
+                self.n_wide_spills += 1
             else:
                 cell = row * k + n
                 self._bflat[cell] = ent
@@ -709,6 +715,7 @@ class _DepsMirror:
         self.attr_version += 1
 
     def _grow_intervals(self) -> None:
+        self.n_interval_grows += 1
         new_m = self.max_intervals * 2
         lo = np.full((self.capacity, new_m), dk.PAD_LO, np.int64)
         hi = np.full((self.capacity, new_m), dk.PAD_HI, np.int64)
@@ -2832,6 +2839,38 @@ class DeviceState:
             if self._attr_dirty:
                 cur = self._aidx = self._attr_refresh(cur)
         return cur
+
+    def warm_drain(self) -> None:
+        """Load the device drain tick's programs at the drain mirror's
+        state size (an empty mirror's: the floor) before traffic, each of
+        which would otherwise meet its first launch on the serving loop
+        whenever a tick first needs it: the dirty-row scatter (a tick
+        whose live set and edges held since the last device tick), and
+        over a built and a scattered state alike the frontier sweep and
+        the level pass a widened tick runs (a chain applied in one
+        cascade, as behind a hot row)."""
+        state, _live = self.drain.state()
+        n = len(state.status)
+        i32, i64 = np.zeros(n, np.int32), np.zeros(n, np.int64)
+        s, em, el, en = _scatter_drain_scalars(
+            state.status, state.exec_msb, state.exec_lsb, state.exec_node,
+            jnp.asarray(i32), i32, i64, i64, i32)
+        scattered = state._replace(status=s, exec_msb=em, exec_lsb=el,
+                                   exec_node=en)
+        for st in (state, scattered):
+            np.asarray(drk.ready_frontier(st))
+            lv, _r = drk.level_assign_dense(st)
+            np.asarray((lv >= 1) & (lv <= 2))
+
+    @property
+    def n_interval_grows(self) -> int:
+        """Times the deps mirror doubled its footprint width."""
+        return self.deps.n_interval_grows
+
+    @property
+    def n_wide_spills(self) -> int:
+        """Entries a full bucket of the deps mirror spilled wide."""
+        return self.deps.n_wide_spills
 
     @property
     def n_attr_tokens(self) -> int:

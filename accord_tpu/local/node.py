@@ -95,6 +95,11 @@ class Node:
         # reads sent to a replica the sink knew to be down, for want of a
         # live one in its shard (impl/sorter.pick_read_nodes)
         self.n_reads_to_down_replica = 0
+        # the deps this node made stable as a coordinator (the Stable
+        # round of coordinate/execute.py, recovery's included): txns, and
+        # the dependencies their deps named
+        self.n_deps_txns = 0
+        self.n_deps_entries = 0
         self._hlc = 0
         self._hlc_reserved = 0
         if journal is not None:

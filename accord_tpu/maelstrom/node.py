@@ -23,6 +23,14 @@ its token, so it is ordered against every insert into the range (no
 phantoms).  A txn that mixes a scan with an ``append`` is refused with error
 code 10: a range-domain txn with a key-domain write has no footprint the
 protocol knows.  An insert is an ``append`` to a key that holds nothing.
+
+And a stored procedure: ``["call", <name>, args]``, a txn alone, one of
+``sim/tpcc.py``'s ``PROCEDURES`` (TPC-C ``new_order`` and ``payment``).  Its
+footprint is the rows the procedure declares for ``args``; the coordinator
+computes its writes from what it read, and it is answered
+``["call", <name>, {"out": <outputs>, "seen": {<row>: <tag>}}]``: the
+procedure's outputs and, for each row it read, the tag of the version it
+saw.  An unknown procedure or malformed ``args`` is refused with code 10.
 """
 
 from __future__ import annotations
@@ -732,6 +740,9 @@ class MaelstromProcess:
                 dev.deps_query_batch_attributed(
                     None, [(tid, tid, tid.kind().witnesses(), [0], [])],
                     [DepsBuilder()])
+                # and the drain tick's programs, whose first launch would
+                # otherwise fall wherever a tick first needs each one
+                dev.warm_drain()
             except Exception:
                 pass   # warmup must never block startup
         self._reply_client(src, body["msg_id"], {"type": "init_ok"})
@@ -797,6 +808,11 @@ class MaelstromProcess:
             self._reply_client(src, msg_id, {
                 "type": "error", "code": 10, "text": text})
 
+        if any(op[0] == "call" for op in ops):
+            if len(ops) != 1:
+                return refuse("unsupported op mix: a call is a txn alone")
+            return self._coordinate_call(src, msg_id, ops[0], refuse,
+                                         release_once)
         read_tokens: List[int] = []
         appends: Dict[int, tuple] = {}
         scans: List[Range] = []
@@ -868,5 +884,32 @@ class MaelstromProcess:
                     out_ops.append(op)
             self._reply_client(src, msg_id, {"type": "txn_ok",
                                              "txn": out_ops})
+
+        self.node.coordinate(txn).begin(on_done)
+
+    def _coordinate_call(self, src: str, msg_id: int, op, refuse,
+                         release_once) -> None:
+        """``["call", <procedure>, args]``: one stored procedure
+        (sim/tpcc.py) over the rows it declares, answered
+        ``["call", <procedure>, {"out": ..., "seen": {row: tag}}]``."""
+        from ..sim.tpcc import procedure_txn
+        if len(op) != 3:
+            return refuse(f"a call is [\"call\", <procedure>, args], "
+                          f"not {op!r}")
+        try:
+            txn = procedure_txn(op[1], op[2])
+        except ValueError as e:
+            return refuse(f"unsupported call {op[1]!r}: {e}")
+
+        def on_done(result, failure):
+            release_once(failure is None)
+            if failure is not None:
+                self._reply_client(src, msg_id, {
+                    "type": "error", "code": 11, "text": repr(failure)})
+                return
+            self._reply_client(src, msg_id, {
+                "type": "txn_ok",
+                "txn": [["call", op[1], {"out": result.out,
+                                         "seen": result.seen}]]})
 
         self.node.coordinate(txn).begin(on_done)

@@ -781,6 +781,9 @@ class NodeServer:
             "key_txns": obs.metrics.peek_counter("txn_domain",
                                                  domain="key"),
             "scan_rows": self.proc.n_scan_rows,
+            # the deps this node made stable as a coordinator
+            "deps_txns": self.proc.node.n_deps_txns,
+            "deps_entries": self.proc.node.n_deps_entries,
         }
 
     def _peer_failure_stats(self) -> Optional[dict]:

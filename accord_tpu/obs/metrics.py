@@ -310,6 +310,10 @@ INDEX_COUNTERS: List[Tuple[str, str]] = [
     # 1 and 1 where the dirty rows and cells cross as one staging buffer
     ("sync_launches", "n_sync_launches"),
     ("sync_uploads", "n_sync_uploads"),
+    # the deps mirror's shape under wide and hot footprints: doublings of
+    # the footprint width, entries a full bucket spilled wide
+    ("interval_grows", "n_interval_grows"),
+    ("wide_spills", "n_wide_spills"),
 ]
 
 

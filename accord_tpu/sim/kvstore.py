@@ -68,6 +68,20 @@ class KVDataStore(api.DataStore):
         return tuple(v for vals, at, _tid in self.log.get(token, ())
                      if at < execute_at for v in vals)
 
+    def read_row(self, token: int, row_id: str,
+                 execute_at: Timestamp) -> Optional[tuple]:
+        """The newest version of the register ``row_id`` held on ``token``
+        below ``execute_at``, or None: a version is a value whose first
+        element names its row (sim/tpcc.py), so rows that share a token
+        are told apart."""
+        entries = self.log.get(token, ())
+        at = bisect.bisect_left(entries, execute_at, key=lambda e: e[1])
+        for i in range(at - 1, -1, -1):
+            for v in reversed(entries[i][0]):
+                if v[0] == row_id:
+                    return v
+        return None
+
     def snapshot(self, ranges: Ranges) -> Dict[int, list]:
         return {t: list(self.log[t]) for r in ranges
                 for t in self.tokens_in(r.start, r.end)}

@@ -373,6 +373,14 @@ def _register_kv_workload() -> None:
     register_fields(kvstore.KVResult, ["txn_id", "reads", "appends"])
     register(kvstore.KVQuery, "KVQuery",
              lambda q: {}, lambda d: kvstore.KVQuery())
+    # stored procedures (TPC-C New-Order / Payment): their writes ride the
+    # KVWrite above
+    from .sim import tpcc
+    register_fields(tpcc.RowRead, ["rows"])
+    register_fields(tpcc.ProcUpdate, ["name", "args", "rows"])
+    register_fields(tpcc.ProcResult, ["out", "seen"])
+    register(tpcc.ProcQuery, "ProcQuery",
+             lambda q: {}, lambda d: tpcc.ProcQuery())
 
 
 _register_status_types()

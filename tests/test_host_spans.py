@@ -499,3 +499,98 @@ def test_a_served_clusters_loop_stats_name_what_the_loop_does(tmp_path):
     for rec in recorders:
         assert rec.retire_roots and rec.dropped == 0
         assert len(rec.finished) <= rec.retire_roots
+
+
+# -- stored procedures: the srv.proc span and the deps / mirror counters ----
+
+def _tpcc_cluster():
+    from accord_tpu.ops.packing import enable_x64
+    from accord_tpu.primitives.timestamp import Domain, TxnId, TxnKind
+    from accord_tpu.sim import tpcc
+    from accord_tpu.sim.cluster import Cluster
+    from accord_tpu.sim.kvstore import KVDataStore
+    from accord_tpu.sim.topology_factory import build_topology
+    from benchmarks.lib import serial_tpcc
+    enable_x64()
+    population = serial_tpcc.populate(2, items=50, districts=2,
+                                      customers=20, orders=20, new_orders=6)
+    cluster = Cluster(topology=build_topology(1, (1, 2, 3), 3, 4,
+                                              max_token=1 << 32),
+                      seed=4, data_store_factory=KVDataStore)
+    load = TxnId.create(1, 1, TxnKind.Write, Domain.Key, 1)
+    snapshot = {}
+    for rid, values in population.items():
+        if values:
+            snapshot.setdefault(tpcc.row_token(rid), []).append(
+                (tuple(values), load, load))
+    for node in cluster.nodes.values():
+        node.data_store.install_snapshot(
+            {t: list(e) for t, e in snapshot.items()})
+    return cluster
+
+
+_CALLS = [("new_order", {"w": 1, "d": 1, "c": 3, "date": 1, "tag": 1,
+                         "lines": [[4, 1, 2], [9, 1, 1]]}),
+          ("payment", {"w": 1, "d": 2, "c_w": 1, "c_d": 2, "c": 5,
+                       "amount": 700, "date": 2, "tag": 2}),
+          ("new_order", {"w": 1, "d": 2, "c": 7, "date": 3, "tag": 3,
+                         "lines": [[4, 1, 1]]})]
+
+
+def test_a_procedure_is_one_srv_proc_span_and_its_deps_are_counted():
+    """Three procedure txns and one list-append txn coordinated by node 1,
+    one at a time: ``srv.proc`` counts the three evaluations (writes and
+    result from one evaluation each; a plain txn has none), and the node
+    counts four Stable rounds whose deps name the earlier txns that share
+    the warehouse row (transitively elided ones left out)."""
+    from accord_tpu.sim import tpcc
+    from accord_tpu.sim.kvstore import kv_txn
+    cluster = _tpcc_cluster()
+    node = cluster.nodes[1]
+    node.loop_times = {}
+    answered = []
+    for name, args in _CALLS:
+        node.coordinate(tpcc.procedure_txn(name, args)).begin(
+            lambda r, f: answered.append(f))
+        cluster.run_until_quiescent()
+    node.coordinate(kv_txn([10], {10: ("x",)})).begin(
+        lambda r, f: answered.append(f))
+    cluster.run_until_quiescent()
+    assert answered == [None] * 4 and cluster.failures == []
+    calls, secs = node.loop_times["srv.proc"]
+    assert calls == 3 and secs > 0.0
+    assert all("srv.proc" not in (n.loop_times or {})
+               for n in cluster.nodes.values() if n is not node)
+    assert node.n_deps_txns == 4
+    assert 2 <= node.n_deps_entries <= 3
+    assert sum(n.n_deps_txns for n in cluster.nodes.values()) == 4
+
+
+def test_the_mirror_counts_its_width_doublings_and_hot_bucket_spills():
+    """``n_interval_grows``: 4 -> 8 -> 16 for a 9-key txn (two), none for
+    one that fits; ``n_wide_spills``: the entries past BUCKET_K of one
+    bucket, one a txn, and not an interval too wide for the buckets."""
+    from accord_tpu.local.commands_for_key import InternalStatus
+    from accord_tpu.obs.metrics import INDEX_COUNTERS
+    from accord_tpu.primitives.keys import IntKey, Keys, Range, Ranges
+    from accord_tpu.primitives.timestamp import Domain, TxnId, TxnKind
+    from tests.conftest import make_device_state
+    _store, dev, _safe = make_device_state()
+    assert dev.n_interval_grows == 0 and dev.n_wide_spills == 0
+
+    def reg(hlc, keys, dom=Domain.Key):
+        dev.register(TxnId.create(1, hlc, TxnKind.Write, dom, 1),
+                     int(InternalStatus.PREACCEPTED), keys)
+
+    reg(1, Keys([IntKey(t) for t in range(1000, 10_000, 1000)]))
+    assert dev.deps.max_intervals == 16 and dev.n_interval_grows == 2
+    reg(2, Keys([IntKey(5)]))
+    reg(3, Ranges.of(Range(0, 1 << 20)), Domain.Range)     # wide, no spill
+    assert dev.n_interval_grows == 2 and dev.n_wide_spills == 0
+    k = dev.deps.BUCKET_K
+    for hlc in range(10, 10 + k + 5):
+        reg(hlc, Keys([IntKey(7)]))
+    # the hot bucket already held txn 2's key 5 (same bucket as 7)
+    assert dev.n_wide_spills == 6
+    assert ("interval_grows", "n_interval_grows") in INDEX_COUNTERS
+    assert ("wide_spills", "n_wide_spills") in INDEX_COUNTERS

@@ -140,7 +140,12 @@ def test_five_served_nodes_multikey_zipf_replay_and_coordination(served_run):
     if after[0] is None:         # ACCORD_TPU_OBS=off: nothing counts paths
         return
     assert all(set(c) == {"fast", "slow", "recoveries", "range_txns",
-                          "key_txns", "scan_rows"} for c in after)
+                          "key_txns", "scan_rows", "deps_txns",
+                          "deps_entries"} for c in after)
+    # every coordinator made the deps of the txns it decided stable, and
+    # on hot Zipf keys some of them named a dependency
+    assert all(c["deps_txns"] > 0 for c in after)
+    assert sum(c["deps_entries"] for c in after) > 0
     # every decision was a key-domain txn's: nothing here scans
     assert all(c["key_txns"] == c["fast"] + c["slow"]
                and c["range_txns"] == c["scan_rows"] == 0 for c in after)
