@@ -17,7 +17,7 @@ from ..messages.commit import Commit, CommitKind, CommitNack, CommitOk
 from ..messages.read_data import ReadNack, ReadOk, ReadTxnData
 from ..primitives.deps import Deps
 from ..primitives.keys import Route
-from ..primitives.timestamp import Timestamp, TxnId
+from ..primitives.timestamp import Ballot, Timestamp, TxnId
 from ..obs import devprof, spans_of
 from ..primitives.txn import Txn
 from ..utils import async_chain
@@ -40,7 +40,6 @@ def execute(node, txn_id: TxnId, txn: Txn, route: Route,
 
 class _ExecuteTxn(api.Callback):
     def __init__(self, node, txn_id, txn, route, execute_at, deps, ballot=None):
-        from ..primitives.timestamp import Ballot
         self.node = node
         self.txn_id = txn_id
         self.txn = txn
@@ -187,8 +186,12 @@ class _ExecuteTxn(api.Callback):
             result = (self.txn.result(self.txn_id, self.execute_at,
                                       self.data)
                       if self.txn.query is not None else None)
+        # the node's own coordination (a recovery runs under its ballot)
+        # hands Persist its start, for the start-to-durable interval
+        started = (self.node.coordination_started(self.txn_id)
+                   if self.ballot == Ballot.ZERO else None)
         persist(self.node, self.txn_id, self.txn, self.route, self.execute_at,
-                self.deps, writes, result)
+                self.deps, writes, result, started)
         # client is answered at persist-start (ref: CoordinationAdapter:189-194).
         # Sync points settle with their coordination handle so callers (the
         # durability rounds, bootstrap) can hand the decided executeAt+deps

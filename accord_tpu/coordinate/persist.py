@@ -20,12 +20,17 @@ from .tracking import AppliedTracker, RequestStatus
 
 def persist(node, txn_id: TxnId, txn: Txn, route: Route,
             execute_at: Timestamp, deps: Deps, writes: Optional[Writes],
-            result) -> None:
-    _Persist(node, txn_id, txn, route, execute_at, deps, writes, result)._start()
+            result, started_micros: Optional[int] = None) -> None:
+    """``started_micros``: where the node's own coordination of the txn
+    persists it, that coordination's start (``Node.coordination_started``):
+    the start-to-durable interval is then kept when a quorum has applied."""
+    _Persist(node, txn_id, txn, route, execute_at, deps, writes, result,
+             started_micros)._start()
 
 
 class _Persist(api.Callback):
-    def __init__(self, node, txn_id, txn, route, execute_at, deps, writes, result):
+    def __init__(self, node, txn_id, txn, route, execute_at, deps, writes,
+                 result, started_micros=None):
         self.node = node
         self.txn_id = txn_id
         self.txn = txn
@@ -38,6 +43,7 @@ class _Persist(api.Callback):
             route.participants, txn_id.epoch(), execute_at.epoch())
         self.tracker = AppliedTracker(self.topologies)
         self.durable_recorded = False
+        self.started_micros = started_micros
         self._spans = spans_of(node)
         self._sp = None
 
@@ -63,6 +69,8 @@ class _Persist(api.Callback):
             self.durable_recorded = True
             if self._spans is not None:    # duration = time to majority-durable
                 self._spans.end(self._sp)
+            if self.started_micros is not None:
+                self.node.note_coordinated_durable(self.started_micros)
             # a quorum of every shard has applied: the txn is majority-durable.
             # Tell every replica so progress logs stand down and truncation
             # watermarks can advance (ref: Persist.java InformDurable leg).

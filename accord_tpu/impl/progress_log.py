@@ -56,27 +56,34 @@ class _Progress(enum.IntEnum):
 # unresolved-op flake.
 _HOME_BACKOFF_CAP = 32
 _BLOCKED_BACKOFF_CAP = 128
+# Ceiling of a home entry's learned patience (SimpleProgressLog
+# ._home_countdown): however slow the node's own txns, a coordinator that
+# went quiet is investigated within this plus one scan.  8 s sits well
+# inside the burn's 120 s post-heal drain and the 20 s a served cluster is
+# given to settle a crashed node's orphans, and above twice the slowest
+# healthy p99 served so far (TPC-C's hot rows: about 1.5 s).
+_HOME_PATIENCE_CAP_MICROS = 8_000_000
 
 
 class _HomeEntry:
     __slots__ = ("txn_id", "route", "progress", "token", "countdown",
-                 "backoff", "since")
+                 "patience", "backoff", "since")
 
-    def __init__(self, txn_id: TxnId, route, now: int):
+    def __init__(self, txn_id: TxnId, route, now: int, patience: int):
         self.txn_id = txn_id
         self.route = route
-        self.progress = _Progress.Expected
         self.token = ProgressToken.none()
-        self.countdown = 2   # scans before investigating
+        self.observed_progress(now, patience)
+
+    def observed_progress(self, now: int, patience: int) -> None:
+        """``patience``: scans to wait for organic progress before the
+        first investigation (2, or more where the node's own txns are
+        slow)."""
+        self.progress = _Progress.Expected
+        self.countdown = self.patience = patience
         self.backoff = 2     # doubled on each fruitless investigation
         # the node's clock when the txn last progressed (or was first
         # tracked): a recovery's ``idle_micros`` counts from here
-        self.since = now
-
-    def observed_progress(self, now: int) -> None:
-        self.progress = _Progress.Expected
-        self.countdown = 2
-        self.backoff = 2
         self.since = now
 
     def no_progress(self) -> None:
@@ -123,7 +130,15 @@ class SimpleProgressLog(api.ProgressLog):
 
     def __init__(self, store, scan_delay_micros: int = 500_000):
         self.store = store
-        self.scan_delay_micros = scan_delay_micros
+        # stagger scans per node/store so home replicas of the same txn
+        # do not investigate (and mutually preempt) in lock-step
+        # (ref: SimpleProgressLog randomized scheduling jitter).  The
+        # offset mixes the FULL node/store ids so any pair of nodes gets
+        # distinct offsets (small moduli left ids congruent mod 8 in
+        # lock-step for clusters larger than 8 nodes).
+        mix = (store.node.node_id * 0x9E3779B1
+               ^ store.store_id * 0x85EBCA77)
+        self.scan_period_micros = scan_delay_micros + (mix % 399_989)
         self.home: Dict[TxnId, _HomeEntry] = {}
         self.non_home: Dict[TxnId, _NonHomeEntry] = {}
         self.blocked: Dict[TxnId, _BlockedEntry] = {}
@@ -136,16 +151,27 @@ class SimpleProgressLog(api.ProgressLog):
     def _arm(self) -> None:
         if self._scheduled is None and (self.home or self.blocked
                                         or self.non_home):
-            node = self.store.node
-            # stagger scans per node/store so home replicas of the same txn
-            # do not investigate (and mutually preempt) in lock-step
-            # (ref: SimpleProgressLog randomized scheduling jitter).  The
-            # offset mixes the FULL node/store ids so any pair of nodes gets
-            # distinct offsets (small moduli left ids congruent mod 8 in
-            # lock-step for clusters larger than 8 nodes).
-            mix = (node.node_id * 0x9E3779B1 ^ self.store.store_id * 0x85EBCA77)
-            delay = self.scan_delay_micros + (mix % 399_989)
-            self._scheduled = node.scheduler.once(delay, self._scan)
+            self._scheduled = self.store.node.scheduler.once(
+                self.scan_period_micros, self._scan)
+
+    def _home_countdown(self) -> int:
+        """Scans a home entry waits for organic progress before its first
+        investigation: twice the p99 of this node's own start-to-durable
+        latency (``Node.durable_p99_micros``; 0 until it has its samples),
+        at most ``_HOME_PATIENCE_CAP_MICROS``, in scans of this log's
+        period, and never fewer than 2: a coordinator merely queued behind
+        slow txns is not recovered, one that went quiet still is within the
+        cap plus a scan."""
+        patience = min(2 * self.store.node.durable_p99_micros,
+                       _HOME_PATIENCE_CAP_MICROS)
+        period = self.scan_period_micros
+        if patience <= 2 * period:
+            return 2
+        return -(-patience // period)
+
+    def patience_micros(self) -> int:
+        """The wait ``_home_countdown`` gives a home entry now, in micros."""
+        return self._home_countdown() * self.scan_period_micros
 
     def _scan(self) -> None:
         self._scheduled = None
@@ -158,13 +184,23 @@ class SimpleProgressLog(api.ProgressLog):
             if entry.txn_id in node._coordinating:
                 # a live local coordinator is driving this txn — don't
                 # preempt ourselves (ref: progress log skips local owner)
-                entry.observed_progress(node.now_micros())
+                entry.observed_progress(node.now_micros(),
+                                        self._home_countdown())
                 continue
             entry.countdown -= 1
             if entry.countdown <= 0:
                 asked = entry.progress
                 entry.progress = _Progress.Investigating
                 self._investigate(entry, asked)
+            elif entry.progress is _Progress.Expected and \
+                    entry.patience - entry.countdown >= 2:
+                # two scans without progress: past the two-scan floor,
+                # the learned patience defers the investigation
+                node.n_patience_deferrals += 1
+                o = node.obs
+                if o is not None:
+                    o.metrics.counter("patience_deferrals",
+                                      node=node.node_id).inc()
         for nh in list(self.non_home.values()):
             nh.countdown -= 1
             if nh.countdown <= 0:
@@ -219,7 +255,8 @@ class SimpleProgressLog(api.ProgressLog):
                              entry.token.status_phase)
                         entry.token = entry.token.merge(info)
                         if organic:
-                            entry.observed_progress(node.now_micros())
+                            entry.observed_progress(node.now_micros(),
+                                                    self._home_countdown())
                         else:
                             entry.no_progress()
                     else:
@@ -397,7 +434,8 @@ class SimpleProgressLog(api.ProgressLog):
         if node.is_home_shard_replica(txn_id, cmd.route):
             if txn_id not in self.home:
                 self.home[txn_id] = _HomeEntry(txn_id, cmd.route,
-                                               node.now_micros())
+                                               node.now_micros(),
+                                               self._home_countdown())
         elif undecided and cmd.route.home_key is not None:
             if txn_id not in self.non_home:
                 self.non_home[txn_id] = _NonHomeEntry(txn_id, cmd.route)
@@ -425,7 +463,8 @@ class SimpleProgressLog(api.ProgressLog):
             entry.token = entry.token.merge(ProgressToken(
                 int(cmd.durability), int(cmd.save_status.status.phase),
                 cmd.promised, entry.token.accepted))
-            entry.observed_progress(self.store.node.now_micros())
+            entry.observed_progress(self.store.node.now_micros(),
+                                    self._home_countdown())
 
     # -- ProgressLog hooks ---------------------------------------------------
     def unwitnessed(self, safe, txn_id: TxnId) -> None:

@@ -9,7 +9,7 @@ selection (:598-673).
 
 from __future__ import annotations
 
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, List, Optional
 
 from .. import api
 from ..primitives.keys import Ranges, Route, RoutingKeys, Seekables
@@ -111,6 +111,18 @@ class Node:
             self._hlc = max(journal.max_hlc + 1, journal.hlc_reserved)
             self._hlc_reserved = journal.hlc_reserved
         self._coordinating: Dict[TxnId, object] = {}  # active coordinations
+        # how long this node's own txns take, start to majority-durable:
+        # the node's clock at Node.coordinate until that coordination's
+        # Persist claims it (coordination_started), and a ring of the last
+        # DURABLE_RING intervals whose p99 the home progress log's patience
+        # scales with (impl/progress_log.py)
+        self._coordinate_started: Dict[TxnId, int] = {}
+        self._durable_ring: List[int] = []
+        self.n_durable_samples = 0
+        self.durable_p99_micros = 0       # 0 until DURABLE_EVERY samples
+        # scans at which a home entry's learned patience held back the
+        # investigation the old two-scan rule would have started
+        self.n_patience_deferrals = 0
         self._pending_topologies: Dict[int, Topology] = {}  # out-of-order epochs
         # PROTO_FASTPATH: (topology, owned Ranges) pair for _owned_ranges
         self._owned_memo = None
@@ -436,7 +448,8 @@ class Node:
             txn_id = self.next_txn_id(txn.kind, txn.domain())
         result = async_chain.AsyncResult()
         self._coordinating[txn_id] = result
-        result.begin(lambda _r, _f: self._coordinating.pop(txn_id, None))
+        self._coordinate_started[txn_id] = self.now_micros()
+        result.begin(lambda _r, _f: self._end_coordination(txn_id))
 
         from ..obs import spans_of
         sp = spans_of(self)
@@ -488,7 +501,7 @@ class Node:
                              reason="Rejected", attempt=_retries + 1)
                     sp.end_txn(str(txn_id), "Rejected-retried")
                 superseded["flag"] = True
-                self._coordinating.pop(txn_id, None)
+                self._end_coordination(txn_id)
                 self._invalidate_then_retry(txn, txn_id, _retries, result,
                                             retry_at_epoch=retry_epoch)
                 return
@@ -542,6 +555,36 @@ class Node:
 
         self.with_epoch(txn_id.epoch(), start)
         return result
+
+    def _end_coordination(self, txn_id: TxnId) -> None:
+        self._coordinating.pop(txn_id, None)
+        self._coordinate_started.pop(txn_id, None)
+
+    # -- start-to-durable latency (the home progress log's patience) --------
+    DURABLE_RING = 256    # intervals kept
+    DURABLE_EVERY = 32    # intervals between two computations of the p99
+
+    def coordination_started(self, txn_id: TxnId) -> Optional[int]:
+        """The node's clock when this node began coordinating ``txn_id``,
+        handed once, to that coordination's own Persist; None for a txn
+        this node did not coordinate from its start or whose coordination
+        already settled or was abandoned."""
+        return self._coordinate_started.pop(txn_id, None)
+
+    def note_coordinated_durable(self, started_micros: int) -> None:
+        """A txn this node coordinated from ``started_micros`` reached
+        majority durability: keep the interval, and every DURABLE_EVERY
+        intervals recompute the p99 over the ring (nearest rank)."""
+        ring = self._durable_ring
+        sample = self.now_micros() - started_micros
+        if len(ring) < self.DURABLE_RING:
+            ring.append(sample)
+        else:
+            ring[self.n_durable_samples % self.DURABLE_RING] = sample
+        self.n_durable_samples += 1
+        if self.n_durable_samples % self.DURABLE_EVERY == 0:
+            ordered = sorted(ring)
+            self.durable_p99_micros = ordered[(99 * len(ordered) - 1) // 100]
 
     def _invalidate_then_retry(self, txn: Txn, old_id: TxnId, retries: int,
                                result: async_chain.AsyncResult,

@@ -769,11 +769,19 @@ class NodeServer:
         obs = self.proc.obs if self.proc is not None else None
         if obs is None or obs.spans is None:
             return None
+        node = self.proc.node
         return {
             "fast": obs.metrics.peek_counter("txn_path", path="fast"),
             "slow": obs.metrics.peek_counter("txn_path", path="slow"),
             "recoveries": obs.metrics.counter_totals(
                 "recoveries", by="event").get("attempt", 0),
+            # the home progress log's learned patience: scans it held back
+            # an investigation the two-scan rule would have started, and
+            # the wait it gives a home entry now (the stores' largest)
+            "patience_deferrals": node.n_patience_deferrals,
+            "patience_micros": max(
+                (s.progress_log.patience_micros()
+                 for s in node.command_stores.stores), default=0),
             # the decided txns by TxnId.domain(), and the records this
             # node's scan replies carried to clients
             "range_txns": obs.metrics.peek_counter("txn_domain",

@@ -141,7 +141,11 @@ def test_five_served_nodes_multikey_zipf_replay_and_coordination(served_run):
         return
     assert all(set(c) == {"fast", "slow", "recoveries", "range_txns",
                           "key_txns", "scan_rows", "deps_txns",
-                          "deps_entries"} for c in after)
+                          "deps_entries", "patience_deferrals",
+                          "patience_micros"} for c in after)
+    # the home patience is never shorter than two scans of 0.5 s
+    assert all(c["patience_micros"] >= 1_000_000
+               and c["patience_deferrals"] >= 0 for c in after)
     # every coordinator made the deps of the txns it decided stable, and
     # on hot Zipf keys some of them named a dependency
     assert all(c["deps_txns"] > 0 for c in after)
