@@ -491,6 +491,58 @@ class CommandStore:
     def unsafe_set_command(self, command: Command) -> None:
         self.commands[command.txn_id] = command
 
+    def install_history(self, rows) -> int:
+        """Hold ``rows`` — (txn_id, execute_at, keys), applied txns whose
+        writes are in the data store already and which no floor truncates
+        yet — as a replica that witnessed them holds them: each sliced to
+        this store's ranges as a PreAccept would be, an Applied command
+        record, its CommandsForKey entries or its range-index entry, the
+        per-key executed timestamps and the conflict watermark, then the
+        device mirror in one pass (DeviceState.register_history).  Nothing
+        is coordinated, journaled or fetched: a later txn that depends on
+        one finds it applied.  For a store that serves no txn yet, as a
+        bootstrapping replica's.  Returns the txns this store holds."""
+        owned = self.ranges_for_epoch.all()
+        starts = [r.start for r in owned]
+        ends = [r.end for r in owned]
+        safe = SafeCommandStore(self, PreLoadContext.empty())
+        mine, latest = [], None
+        for txn_id, execute_at, keys in rows:
+            # most footprints lie inside one owned range or outside them
+            # all: bisect those, slice the rest
+            lo, hi = ((keys[0].start, keys[-1].end)
+                      if isinstance(keys, Ranges)
+                      else (keys[0].token(), keys[-1].token() + 1))
+            at = bisect.bisect_right(starts, lo) - 1
+            inside = at >= 0 and hi <= ends[at]
+            outside = (at < 0 or ends[at] <= lo) \
+                and (at + 1 == len(starts) or starts[at + 1] >= hi)
+            if outside:
+                continue
+            if not inside:
+                keys = keys.slice(owned)
+                if keys.is_empty():
+                    continue
+            mine.append((txn_id, execute_at, keys))
+            self.commands[txn_id] = Command(
+                txn_id, SaveStatus.Applied, execute_at=execute_at)
+            if isinstance(keys, Ranges):
+                self.put_range_command(txn_id, keys)
+            else:
+                for key in keys:
+                    token = key.token()
+                    self.cfk(token).update(txn_id, InternalStatus.APPLIED,
+                                           execute_at)
+                    self.timestamps_for_key.get(token).on_executed(
+                        safe, txn_id, execute_at)
+            if latest is None or execute_at > latest:
+                latest = execute_at
+        if latest is not None:
+            self.max_conflicts.update(owned, latest)
+        if self.device is not None:
+            self.device.register_history(mine, int(InternalStatus.APPLIED))
+        return len(mine)
+
     def __repr__(self):
         return f"CommandStore#{self.store_id}@{self.node.node_id}"
 

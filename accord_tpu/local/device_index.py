@@ -142,6 +142,13 @@ def _pow2_at_least(n: int, floor: int = _MIN_INTERVALS) -> int:
     return out
 
 
+def flush_batches(max_flush: int) -> Tuple[int, ...]:
+    """The padded batch sizes (powers of two) a flush of up to
+    ``max_flush`` queries launches at: the sizes warm_flush loads."""
+    return tuple(1 << i
+                 for i in range(_pow2_at_least(max_flush, 1).bit_length()))
+
+
 _PZ = None
 
 
@@ -198,6 +205,11 @@ def _fetch_entry_prefix(ent_dev, s: int, maxtot: int) -> np.ndarray:
 # scalar prefix of the attributed header (ops.deps_kernel: total, the two
 # overflow watermarks, the two elision tallies), before row_end[B]
 _HOFF = 5
+
+
+class _OverBudget(Exception):
+    """A flush's answer outgrew the entry budgets its store loaded the
+    programs of (DeviceState.loaded_budgets): the host route answers it."""
 
 
 def _decode_triples(hdr: np.ndarray, ent: np.ndarray, nq: int,
@@ -688,8 +700,12 @@ class _DepsMirror:
             # HBM backpressure: compaction made room under the budget —
             # the caller's free_slots.pop() proceeds without doubling
             return
+        self._resize(self.capacity * 2)
+
+    def _resize(self, new: int) -> None:
+        """Every column to ``new`` slots, the new tail free (popped lowest
+        first, as after a chain of doublings to the same size)."""
         old = self.capacity
-        new = old * 2
         self.msb = _grow(self.msb, new, 0)
         self.lsb = _grow(self.lsb, new, 0)
         self.node = _grow(self.node, new, 0)
@@ -714,9 +730,9 @@ class _DepsMirror:
         self._attr_sh = None
         self.attr_version += 1
 
-    def _grow_intervals(self) -> None:
+    def _grow_intervals(self, new_m: Optional[int] = None) -> None:
         self.n_interval_grows += 1
-        new_m = self.max_intervals * 2
+        new_m = new_m or self.max_intervals * 2
         lo = np.full((self.capacity, new_m), dk.PAD_LO, np.int64)
         hi = np.full((self.capacity, new_m), dk.PAD_HI, np.int64)
         lo[:, : self.max_intervals] = self.lo
@@ -758,6 +774,89 @@ class _DepsMirror:
             self.mut_version += 1
             self._bucket_add(slot, lo_v, hi_v, used)
             used += 1
+
+    def alloc_bulk(self, txn_ids: Sequence[TxnId],
+                   footprints: Sequence[Sequence[Tuple[int, int]]],
+                   status: int,
+                   execs: Sequence[Optional[Timestamp]]) -> None:
+        """New slots for ``txn_ids`` (none of them held yet), each left as
+        alloc() + add_intervals() of its ``footprints`` row of (lo, hi)
+        bounds (inclusive, none inside another) + a ``status`` and
+        executeAt write would leave it, and in the same slots; but the
+        capacity and the footprint width are sized once and the columns
+        written as arrays."""
+        n = len(txn_ids)
+        if not n:
+            return
+        # the free slots first, then the grown tail's, as alloc() pops them
+        taken = self.free_slots[::-1][:n]
+        del self.free_slots[len(self.free_slots) - len(taken):]
+        if len(taken) < n:
+            self._resize(_pow2_at_least(self.capacity + n - len(taken),
+                                        self.capacity))
+            at = len(self.free_slots) - (n - len(taken))
+            taken += self.free_slots[at:][::-1]
+            del self.free_slots[at:]
+        width = max(map(len, footprints), default=0)
+        if width > self.max_intervals:
+            self._grow_intervals(_pow2_at_least(width, self.max_intervals))
+        slots = np.asarray(taken, np.int64)
+        self.slot_of.update(zip(txn_ids, taken))
+        self.id_of.update(zip(taken, txn_ids))
+        objs = np.empty(n, object)
+        objs[:] = txn_ids
+        self.obj[slots] = objs
+        self.msb[slots] = np.fromiter((t.msb for t in txn_ids), np.uint64,
+                                      n).view(np.int64)
+        self.lsb[slots] = np.fromiter((t.lsb for t in txn_ids), np.uint64,
+                                      n).view(np.int64)
+        self.node[slots] = np.fromiter((t.node for t in txn_ids), np.int32, n)
+        self.kind[slots] = np.fromiter((int(t.kind()) for t in txn_ids),
+                                       np.int32, n)
+        self.domain[slots] = np.fromiter((int(t.domain()) for t in txn_ids),
+                                         np.int8, n)
+        self.status[slots] = status
+        known = np.fromiter((e is not None for e in execs), bool, n)
+        self.eknown[slots] = known
+        ks = slots[known]
+        ke = [e for e in execs if e is not None]
+        self.emsb[ks] = np.fromiter((e.msb for e in ke), np.uint64,
+                                    len(ke)).view(np.int64)
+        self.elsb[ks] = np.fromiter((e.lsb for e in ke), np.uint64,
+                                    len(ke)).view(np.int64)
+        self.enode[ks] = np.fromiter((e.node for e in ke), np.int32, len(ke))
+        self.lo[slots] = dk.PAD_LO
+        self.hi[slots] = dk.PAD_HI
+        widths = np.fromiter(map(len, footprints), np.int64, n)
+        row = np.repeat(slots, widths)
+        col = np.arange(len(row)) - np.repeat(np.cumsum(widths) - widths,
+                                              widths)
+        bounds = np.array([b for f in footprints for b in f],
+                          np.int64).reshape(-1, 2)
+        self.lo[row, col] = bounds[:, 0]
+        self.hi[row, col] = bounds[:, 1]
+        # the bucket index: an interval over more than SPAN buckets is a
+        # straggler whatever the rows hold, the rest go entry by entry
+        wide = (bounds[:, 1] >> self.BSHIFT) - (bounds[:, 0] >> self.BSHIFT) \
+            + 1 > self.SPAN
+        if wide.any():
+            self.wide_entries.update(zip(
+                bounds[wide, 0].tolist(), bounds[wide, 1].tolist(),
+                row[wide].tolist(), col[wide].tolist()))
+            self.wide_version += 1
+            self.bucket_version += 1
+        for j in np.nonzero(~wide)[0].tolist():
+            self._bucket_add(int(row[j]), int(bounds[j, 0]),
+                             int(bounds[j, 1]), int(col[j]))
+        self._dirty.update(taken)
+        self._attr_dirty.update(taken)
+        if self.shards is not None:
+            self._dirty_sh.update(taken)
+            self._attr_dirty_sh.update(taken)
+        self.n_live += n
+        self.version += 1
+        self.mut_version += 1
+        self.attr_version += 1
 
     def set_status(self, slot: int, status: int) -> None:
         cur = int(self.status[slot])
@@ -1190,6 +1289,19 @@ class _DepsMirror:
         if owner is not None:
             owner.n_sync_uploads += uploads
             owner.n_sync_launches += bool(pieces)
+
+    def warm_sync(self, max_rows: int) -> None:
+        """Load the table sync's program for each power of two of dirty
+        rows from 8 to ``max_rows``: rows 0.. are marked dirty and synced,
+        which rewrites on the device what they hold already."""
+        self.sync_device()
+        n = 8
+        while n <= max_rows:
+            rows = range(min(n, self.capacity))
+            self._dirty.update(rows)
+            self._attr_dirty.update(rows)
+            self.sync_device()
+            n *= 2
 
 
 @jax.jit
@@ -2020,6 +2132,9 @@ class DeviceState:
         # one per column
         self.n_sync_launches = 0
         self.n_sync_uploads = 0
+        # txns witnessed through register_history (a replica's untruncated
+        # history installed in one pass), not one register() each
+        self.n_history_registered = 0
         # -- device-fault tolerance (module docstring: degradation ladder) --
         # shadow-verify every device flush against the host route when True
         # (or when utils.faults.PARANOIA is set process-wide)
@@ -2049,6 +2164,17 @@ class DeviceState:
         self.n_device_faults = 0
         self.n_quarantines = 0
         self.n_fallback_queries = 0    # queries served by host fallback/pin
+        # (s, k): the entry budgets warm_flush loaded the serving flush
+        # programs at before traffic, for batches up to ``loaded_batch``
+        # queries; None until then.  Once set, a serving flush (immediate,
+        # or a fused member) launches at these budgets, and one that no
+        # loaded program can answer (a larger batch, or an answer past the
+        # budgets) is answered by the host route with the same bytes,
+        # never by compiling a program on the serving loop; its queries
+        # count here (and as host queries), apart from the faults
+        self.loaded_budgets: Optional[Tuple[int, int]] = None
+        self.loaded_batch = 0
+        self.n_budget_host_queries = 0
         self.n_reprobes = 0
         self.n_restores = 0
         self.n_shadow_checks = 0
@@ -2121,6 +2247,52 @@ class DeviceState:
                     self.deps.add_intervals(
                         slot, [k.token() for k in keys], ())
             self._advance_status(txn_id, slot, status, None)
+
+    def register_history(self, rows, status: int = dk.SLOT_APPLIED) -> int:
+        """Witness a replica's untruncated history in one pass: ``rows``
+        are (txn_id, execute_at, keys) of distinct txns, ``keys`` the
+        txn's sliced footprint (Keys or Ranges), all at the decided
+        ``status``.  Each txn is left as ``register`` and ``update_status``
+        would leave it, but the mirror's capacity and width are sized
+        once, its columns written as arrays and its device tables synced
+        once; the store's CommandsForKey must hold the rows already (the
+        attribution index reads their pivots here, not at the first
+        flush).  Returns the txns registered; one the mirror holds already
+        takes the per-txn path."""
+        with self._span("register_history"):
+            ids, footprints, execs = [], [], []
+            pivots = dk.SLOT_COMMITTED <= status <= dk.SLOT_APPLIED
+            held = self.deps.slot_of
+            for txn_id, execute_at, keys in rows:
+                if held and txn_id in held:
+                    self.register(txn_id, status, keys)
+                    self.update_status(txn_id, status, execute_at)
+                    continue
+                ids.append(txn_id)
+                execs.append(execute_at)
+                if isinstance(keys, Ranges):
+                    footprints.append([(r.start, r.end - 1) for r in keys])
+                    continue
+                toks = [k.token() for k in keys]
+                footprints.append([(t, t) for t in toks])
+                if execute_at is not None and pivots \
+                        and txn_id.kind().is_write() \
+                        and txn_id.domain() == Domain.Key:
+                    # a decided key-domain write: an elision pivot on each
+                    # of its points (_advance_status marks them one by one)
+                    self._attr_dirty.update(toks)
+            self.deps.alloc_bulk(ids, footprints, status, execs)
+            self.n_history_registered += len(ids)
+            self._attr_index()
+            if self.mesh is None and not self.host_pinned:
+                self.deps.sync_device()
+        return len(rows)
+
+    @property
+    def n_live_slots(self) -> int:
+        """Slots of the deps mirror that are neither free nor
+        invalidated (_DepsMirror.n_live)."""
+        return self.deps.n_live
 
     def update_status(self, txn_id: TxnId, status: int,
                       execute_at: Optional[Timestamp] = None) -> None:
@@ -2511,14 +2683,17 @@ class DeviceState:
         the round trip), (c) the host per-element cost of the vectorized
         numpy predicate the host route runs, and the coefficients of the
         later routes (copy, transfer, attribution, the host drain sweep).
-        No hard-coded thresholds: the crossovers ARE these numbers."""
+        No hard-coded thresholds: the crossovers ARE these numbers.  Each
+        is a median over repeated timings: a store whose live set sits
+        near a crossover routes by them, and a noisy probe would give the
+        same replica another route mix at every start."""
         import statistics as _st
         import time as _time
         x = jnp.arange(256, dtype=jnp.int64)
         tiny = jax.jit(lambda a: a + 1)
         np.asarray(tiny(x))                      # warm + compile
         rtts = []
-        for _ in range(5):
+        for _ in range(9):
             t0 = _time.perf_counter()
             np.asarray(tiny(x))
             rtts.append(_time.perf_counter() - t0)
@@ -2530,7 +2705,7 @@ class DeviceState:
         jax.block_until_ready(dk.calculate_deps_flat(table, qmat, m,
                                                      256, 64))
         runs = []
-        for _ in range(3):
+        for _ in range(7):
             t0 = _time.perf_counter()
             jax.block_until_ready(dk.calculate_deps_flat(table, qmat, m,
                                                          256, 64))
@@ -2542,19 +2717,23 @@ class DeviceState:
         a = np.arange(n, dtype=np.int64)
         c = a[::-1].copy()
         _ = ((a < c) | ((a == c) & (c < a))).sum()   # warm
-        t0 = _time.perf_counter()
-        reps = 4
-        for _ in range(reps):
-            _ = ((a < c) | ((a == c) & (c < a))).sum()
-        c_host = max((_time.perf_counter() - t0) / (reps * n), 1e-11)
+        reps, blocks = 4, []
+        for _ in range(5):
+            t0 = _time.perf_counter()
+            for _ in range(reps):
+                _ = ((a < c) | ((a == c) & (c < a))).sum()
+            blocks.append(_time.perf_counter() - t0)
+        c_host = max(_st.median(blocks) / (reps * n), 1e-11)
         # host per-element column-copy cost (the deferred-harvest mirror
         # snapshot the fused pricing charges) — memcpy, ~20x cheaper per
         # element than the compare chain
         _ = a.copy()
-        t0 = _time.perf_counter()
-        for _ in range(8):
+        copies = []
+        for _ in range(9):
+            t0 = _time.perf_counter()
             _ = a.copy()
-        c_copy = max((_time.perf_counter() - t0) / (8 * n), 1e-12)
+            copies.append(_time.perf_counter() - t0)
+        c_copy = max(_st.median(copies) / n, 1e-12)
         # device->host transfer cost per BYTE (the r10 prefix-fetch model:
         # an immediate flush slices the entry buffer only when the bytes
         # it saves cost more than the extra slice dispatch ~ one rtt; on
@@ -2593,17 +2772,17 @@ class DeviceState:
                                                      s_probe, 64))
         jax.block_until_ready(dk.calculate_deps_flat_attr(
             table, attr, aidx, qmat, rb0, *zeros3, m, s_probe, 64))
-        t0 = _time.perf_counter()
-        for _ in range(3):
+        raws, attrs = [], []
+        for _ in range(7):           # interleaved: drift hits both alike
+            t0 = _time.perf_counter()
             jax.block_until_ready(dk.calculate_deps_flat(table, qmat, m,
                                                          s_probe, 64))
-        t_raw = (_time.perf_counter() - t0) / 3
-        t0 = _time.perf_counter()
-        for _ in range(3):
+            raws.append(_time.perf_counter() - t0)
+            t0 = _time.perf_counter()
             jax.block_until_ready(dk.calculate_deps_flat_attr(
                 table, attr, aidx, qmat, rb0, *zeros3, m, s_probe, 64))
-        t_attr = (_time.perf_counter() - t0) / 3
-        c_attr = max(t_attr - t_raw, 0.0) / s_probe
+            attrs.append(_time.perf_counter() - t0)
+        c_attr = max(_st.median(attrs) - _st.median(raws), 0.0) / s_probe
         # the host drain sweep is a Python loop, not a numpy pass, so
         # c_host is the wrong price for it: time the sweep itself on a
         # mirror of Stable rows whose deps are all decided and execute
@@ -2862,6 +3041,91 @@ class DeviceState:
             lv, _r = drk.level_assign_dense(st)
             np.asarray((lv >= 1) & (lv <= 2))
 
+    # dirty rows a table sync between two device flushes may carry: its
+    # programs are loaded for each power of two up to this many
+    WARM_SYNC_ROWS = 4096
+
+    def _serving_budgets(self, immediate: bool) -> Tuple[int, int]:
+        """The entry budgets (s, k) a flush launches at: a serving flush
+        (immediate, or a fused member) on a store whose programs
+        warm_flush loaded runs at the loaded budgets, whatever a pipelined
+        flush has learned since (a larger budget is another program);
+        every other flush at the learned ones."""
+        if immediate and self.loaded_budgets is not None:
+            return self.loaded_budgets
+        return self._batch_flat, self._batch_k
+
+    def _unloaded_batch(self, nq: int) -> bool:
+        """A serving flush of ``nq`` queries that no program loaded by
+        warm_flush can run: its padded batch is larger than any loaded."""
+        return self.loaded_budgets is not None \
+            and _pow2_at_least(nq, 1) > self.loaded_batch
+
+    def warm_queries(self, n: int) -> list:
+        """``n`` deps queries replaying the footprints of the store's
+        newest txns, each at a bound above them all (what the next
+        clients' txns look like); [] for a store that holds none."""
+        d = self.deps
+        live = np.nonzero((d.status >= 0) & (d.status != dk.SLOT_INVALIDATED)
+                          & (d.lo[:, 0] <= d.hi[:, 0]))[0]
+        if not len(live):
+            return []
+        newest = sorted((d.obj[j] for j in live.tolist()), reverse=True)[:n]
+        top, out = newest[0], []
+        for i in range(n):
+            tid = newest[i % len(newest)]
+            row = d.slot_of[tid]
+            used = d.lo[row] <= d.hi[row]
+            points = tid.domain() == Domain.Key
+            bounds = zip(d.lo[row][used].tolist(), d.hi[row][used].tolist())
+            toks, rngs = [], []
+            for lo, hi in bounds:
+                if points and lo == hi:
+                    toks.append(lo)
+                else:
+                    rngs.append(Range(lo, hi + 1))
+            bound = TxnId.create(top.epoch(), top.hlc() + 1 + i, tid.kind(),
+                                 tid.domain(), tid.node)
+            out.append((bound, bound, tid.kind().witnesses(), toks, rngs))
+        return out
+
+    def warm_flush(self, batches) -> None:
+        """Load, before traffic, the programs a serving store's device
+        flush runs at the deps mirror's capacity and width: the attributed
+        kernel for each batch size in ``batches`` (one device-routed flush
+        each, of ``warm_queries``, the answers dropped), the live-prefix
+        slices of its download, and the table sync for each power of two
+        of dirty rows up to WARM_SYNC_ROWS.  The entry budgets they were
+        loaded at become ``loaded_budgets`` and the largest size
+        ``loaded_batch``: from here a serving flush launches at those
+        budgets, and one that no loaded program answers is answered by
+        the host route instead of compiling on the serving loop."""
+        from ..primitives.deps import DepsBuilder
+        queries = self.warm_queries(max(batches))
+        if not queries or self.host_pinned:
+            return
+        self.loaded_budgets = (self._batch_flat, self._batch_k)
+        self.loaded_batch = max(batches)
+        saved, self.route_override = self.route_override, "device"
+        try:
+            for b in batches:
+                handle = self.deps_query_batch_begin(queries[:b],
+                                                     immediate=True)
+                self.deps_query_batch_end_attributed(
+                    None, handle, [DepsBuilder() for _ in range(b)])
+        finally:
+            self.route_override = saved
+        if self.mesh is not None:
+            return
+        s, d = self.loaded_budgets[0], self.deps
+        wide = dk.wide_codes(d.capacity, d.max_intervals,
+                             _pow2_at_least(1))
+        ent = jnp.zeros(s, jnp.int64 if wide else jnp.int32)
+        gran = max(128, s >> 4)
+        for length in range(gran, s + 1, gran):
+            _fetch_entry_prefix(ent, s, length)
+        d.warm_sync(min(self.WARM_SYNC_ROWS, d.capacity // 4))
+
     @property
     def n_interval_grows(self) -> int:
         """Times the deps mirror doubled its footprint width."""
@@ -3043,6 +3307,7 @@ class DeviceState:
         floor_skip = aidx.floors_match(qnp, q_m, floor_id)
         k_floors = not floor_skip
         k_elide = aidx.n_execs > 0
+        b_flat, b_k = self._serving_budgets(immediate)
 
         def dispatch(kind, rows, qcols=None):
             """rows: np int64 array of query indices for this part, padded
@@ -3099,8 +3364,8 @@ class DeviceState:
                     table = self.deps.device_table_sharded(self.mesh)
                     d = int(np.prod(list(self.mesh.shape.values())))
                     n = table.capacity
-                    s = min(self._batch_flat, b_pad * (n // d) * m_t * q_m)
-                    k = min(self._batch_k, (n // d) * m_t * q_m)
+                    s = min(b_flat, b_pad * (n // d) * m_t * q_m)
+                    k = min(b_k, (n // d) * m_t * q_m)
                     qmat = jnp.asarray(dk.qmat_words(qnp[rows_p]))
                     mesh = self.mesh
                     # merged replicated block with GLOBAL slot codes: the
@@ -3131,8 +3396,8 @@ class DeviceState:
                     # crossed with the query intervals (exact triples)
                     c = (q_m * span * keff
                          + q_m * (btable.wlo.shape[0] // d))
-                    s = min(self._batch_flat, b_pad * c)
-                    k = min(self._batch_k, c)
+                    s = min(b_flat, b_pad * c)
+                    k = min(b_k, c)
                     qb = qcols[rows_p].reshape(b_pad, q_m * span)
                     qmat = jnp.asarray(dk.qmat_words(np.concatenate(
                         [qnp[rows_p], qb], axis=1)))
@@ -3158,8 +3423,8 @@ class DeviceState:
                     table = self.deps.device_table()
                     n = table.capacity
                     wide = dk.wide_codes(n, m_t, q_m)
-                    s = min(self._batch_flat, b_pad * n * m_t * q_m)
-                    k = min(self._batch_k, n * m_t * q_m)
+                    s = min(b_flat, b_pad * n * m_t * q_m)
+                    k = min(b_k, n * m_t * q_m)
                     qmat = jnp.asarray(dk.qmat_words(qnp[rows_p]))
                     acols = self.deps.device_attr_cols()
                     ai = aidx.device()
@@ -3181,8 +3446,8 @@ class DeviceState:
                     keff = self.deps.bucket_keff()
                     wide = dk.wide_codes(table.capacity, m_t, q_m)
                     c = (q_m * span * keff + q_m * btable.wlo.shape[0])
-                    s = min(self._batch_flat, b_pad * c)
-                    k = min(self._batch_k, c)
+                    s = min(b_flat, b_pad * c)
+                    k = min(b_k, c)
                     qb = qcols[rows_p].reshape(b_pad, q_m * span)
                     qmat = jnp.asarray(dk.qmat_words(np.concatenate(
                         [qnp[rows_p], qb], axis=1)))
@@ -3253,6 +3518,9 @@ class DeviceState:
             if route is None:
                 with self._span("choose_route"):
                     route = self._choose_route(qnp, q_m, floor_id)
+            if route != "host" and immediate and self._unloaded_batch(nq):
+                route = "host"
+                self.n_budget_host_queries += nq
             if route != "host" and may_probe:
                 probing = True
                 self.n_reprobes += 1
@@ -3479,6 +3747,9 @@ class DeviceState:
         self.download_bytes += hdr.nbytes
         self.download_bytes_padded += hdr.nbytes + d_ent * s * itemsize
         runs = 0
+        if part.get("immediate") and self.loaded_budgets is not None \
+                and (int(hdr[0, 1]) > s or int(hdr[0, 2]) > k):
+            raise _OverBudget()
         while int(hdr[0, 1]) > s or int(hdr[0, 2]) > k:
             # overflow: re-size from the exact header (shared policy,
             # _overflow_resize), then re-dispatch against the same
@@ -3561,6 +3832,22 @@ class DeviceState:
         self.n_elided_decided += n_d
         return tb, tj, tm, tq
 
+    # the route counters a device part's queries count to at dispatch
+    _PART_COUNTERS = {
+        "attr_dense": ("n_dense_queries",),
+        "attr_bucketed": ("n_bucketed_queries",),
+        "attr_sharded": ("n_mesh_queries",),
+        "attr_sharded_bucketed": ("n_mesh_queries",
+                                  "n_mesh_bucketed_queries"),
+    }
+
+    def _uncount_device_part(self, part) -> None:
+        """Take a device part's queries back out of the route counters
+        its dispatch added them to (the host route answered them)."""
+        n = int((part["gmap"] >= 0).sum())
+        for name in self._PART_COUNTERS.get(part["kind"], ()):
+            setattr(self, name, getattr(self, name) - n)
+
     def _batch_collect_attr(self, handle):
         """Collect a dispatched batch: the kernels already
         applied floors/elision/dedupe, so the download IS the final entry
@@ -3585,6 +3872,21 @@ class DeviceState:
             outs = [self._host_attr_triples(handle, part=p)
                     if p["kind"] == "host_slice" else self._collect_part(p)
                     for p in parts]
+        except _OverBudget:
+            # an immediate flush: the live mirror is its snapshot.  Its
+            # queries were counted to their device kinds at dispatch: the
+            # host answered them
+            for p in parts:
+                self._uncount_device_part(p)
+            self.n_range_device_queries -= sum(
+                1 for q in queries if q[0].domain() == Domain.Range)
+            self.n_budget_host_queries += nq
+            self.n_host_queries += nq
+            self.n_dispatches += 1
+            self.n_queries += nq
+            tb, tj, tm, tq = self._host_attr_triples(handle)
+            self.n_kernel_deps += len(tj)
+            return tb, tj, tm, tq, ids, ivs, qnp, q_m, queries
         except faults.DEVICE_EXCEPTIONS as e:
             self._device_fault(e, f"collect: {e}", sliced=True)
             self.n_host_queries += nq
@@ -3731,9 +4033,10 @@ class DeviceState:
         if route is None:
             with self._span("choose_route"):
                 route = self._choose_route(qnp, q_m, floor_id)
-        if route == "host":
-            return None
         nq = qnp.shape[0]
+        if route == "host" or self._unloaded_batch(nq):
+            # (a batch no loaded program runs goes solo, to the host)
+            return None
         b_pad = _pow2_at_least(nq, 1)
         cap = self.deps.capacity
         d = 1 if self.mesh is None else max(len(self.mesh.devices.flat), 1)
@@ -3849,6 +4152,18 @@ class DeviceState:
                 ent = ent_all[hint["row"]]
                 s_, k_ = launch.s, launch.k
                 runs = 0
+                if self.loaded_budgets is not None and (
+                        int(hdr[:, 1].max()) > s_
+                        or int(hdr[:, 2].max()) > k_):
+                    # past the loaded budgets: the host answers (no device
+                    # counter has counted this member yet)
+                    self.n_budget_host_queries += nq
+                    self.n_host_queries += nq
+                    self.n_dispatches += 1
+                    return self._hint_attr_entries(
+                        hint, self.deps.host_pairs(
+                            qnp, q_m, hint["floor_id"],
+                            snapshot=self._fused_snapshot(hint)))
                 while int(hdr[:, 1].max()) > s_ or int(hdr[:, 2].max()) > k_:
                     # overflow: escalate EXACTLY like the solo path — re-run
                     # this store alone against the same cached table + attr

@@ -374,11 +374,11 @@ class DeviceDispatcher:
         d = 1 if mesh is None else max(len(mesh.devices.flat), 1)
         q_m = max(h["q_m"] for h in hints)
         b_pad = _pow2_at_least(max(h["b_pad"] for h in hints), 1)
-        s = max(min(dev._batch_flat, b_pad * (h["cap"] // d)
-                    * h["m_iv"] * q_m)
-                for dev, h in zip(devs, hints))
-        k = max(min(dev._batch_k, (h["cap"] // d) * h["m_iv"] * q_m)
-                for dev, h in zip(devs, hints))
+        budgets = [dev._serving_budgets(True) for dev in devs]
+        s = max(min(bs, b_pad * (h["cap"] // d) * h["m_iv"] * q_m)
+                for (bs, _bk), h in zip(budgets, hints))
+        k = max(min(bk, (h["cap"] // d) * h["m_iv"] * q_m)
+                for (_bs, bk), h in zip(budgets, hints))
         qmats = np.empty((len(hints), b_pad, 7 + 2 * q_m), np.int64)
         pm = np.zeros(len(hints), np.int64)
         pl = np.zeros(len(hints), np.int64)
@@ -466,6 +466,49 @@ class DeviceDispatcher:
                           sum(h["nq"] for h in hints))
         return FusedFlushLaunch(out, hints, s, k,
                                 d if mesh is not None else 1, b_pad, wide)
+
+    def warm_fused(self, devs, batches) -> None:
+        """Load, before traffic, the fused launch's programs for this
+        node's device stores flushing together, one a batch size in
+        ``batches``: each store's ``warm_queries`` through the planning,
+        launch and harvest a serving flush takes, on the device route,
+        the answers dropped; and the live-prefix slices of the shared
+        download.  Run after the stores' own warm_flush (their budgets are
+        the launch's)."""
+        from ..primitives.deps import DepsBuilder
+        devs = [d for d in devs if d.mesh is None and not d.host_pinned]
+        if not self.fusion or len(devs) < 2:
+            return
+        saved = [d.route_override for d in devs]
+        for d in devs:
+            d.route_override = "device"
+        queries = [d.warm_queries(max(batches)) for d in devs]
+        try:
+            for b in batches:
+                hints = []
+                for d, qs in zip(devs, queries):
+                    qs = qs[:b]
+                    h = d.fused_eligible(qs) if qs else None
+                    if h is not None:
+                        h["batch"] = [(q, DepsBuilder(), lambda _f, _s: None)
+                                      for q in qs]
+                        hints.append(h)
+                if len(hints) < 2:
+                    return
+                launch = self._launch_fused_flush(hints)
+                for h in hints:
+                    h["dev"].fused_harvest(None, h, launch)
+        finally:
+            for d, route in zip(devs, saved):
+                d.route_override = route
+        import jax.numpy as jnp
+        s = max(d._serving_budgets(True)[0] for d in devs)
+        wide = any(dk.wide_codes(d.deps.capacity, d.deps.max_intervals,
+                                 _pow2_at_least(1)) for d in devs)
+        ent = jnp.zeros((len(devs), s), jnp.int64 if wide else jnp.int32)
+        gran = max(128, s >> 4)
+        for length in range(gran, s + 1, gran):
+            ent.reshape(len(devs), s)[:, :length].block_until_ready()
 
     # -- tick side ----------------------------------------------------------
     def register_tick(self, dev) -> None:

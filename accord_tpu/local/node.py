@@ -391,6 +391,34 @@ class Node:
                 members[name] = members.get(name, 0) + (j - i)
             i = j
 
+    def install_history(self, rows, max_flush: int) -> int:
+        """The node-level half of a bootstrapping replica's catch-up,
+        beside its data store's ``install_snapshot``: ``rows`` are
+        (txn_id, execute_at, keys), txns applied at their executeAt whose
+        writes the snapshot holds and which no floor truncates yet, so the
+        deps index must keep returning them.  Every store holds the rows'
+        slices of its own ranges (CommandStore.install_history), each
+        device store loads its flush programs, solo and fused, at the size
+        the history left and for every batch of up to ``max_flush``
+        queries (DeviceState.warm_flush), and this node's clock moves past
+        them.  Before the node serves; one ``srv.install_history`` span on
+        its loop table.  Returns the (store, txn) pairs held."""
+        from .device_index import flush_batches
+        rows = list(rows)
+        with devprof.span("srv.install_history", self.loop_times):
+            stores = self.command_stores.unsafe_all_stores()
+            held = sum(store.install_history(rows) for store in stores)
+            devs = [store.device for store in stores
+                    if store.device is not None]
+            batches = flush_batches(max_flush)
+            for dev in devs:
+                dev.warm_flush(batches)
+            if devs and self.dispatcher is not None:
+                self.dispatcher.warm_fused(devs, batches)
+            if rows:
+                self.witness_timestamp(max(e for _t, e, _k in rows))
+        return held
+
     def witness_timestamp(self, ts) -> None:
         """HLC receive rule: merge a remotely-witnessed timestamp into the
         local clock so later ids exceed it (ref: Node.java uniqueNow(atLeast)

@@ -759,6 +759,10 @@ class NodeServer:
             # programs launched, arrays handed over
             "sync_launches": sum(d.n_sync_launches for d in devs),
             "sync_uploads": sum(d.n_sync_uploads for d in devs),
+            # the deps index's live entries, and those a history install
+            # put there in one pass (Node.install_history)
+            "live_slots": sum(d.n_live_slots for d in devs),
+            "history_registered": sum(d.n_history_registered for d in devs),
         }
 
     def _coordination_stats(self) -> Optional[dict]:
@@ -989,6 +993,16 @@ class NodeServer:
               f"codec={self.wire_codec} "
               f"coalesce_us={coalesce_window_micros()}",
               file=sys.stderr, flush=True)
+
+    def install_history(self, rows) -> int:
+        """``Node.install_history`` on a started node, before it serves:
+        its stores' flush programs loaded for the largest deps flush the
+        admission gates let reach one replica store, every member's
+        ``admit_max`` coordinations in flight at once, each with one
+        query there.  Returns the (store, txn) pairs held."""
+        members = len(self.peers) + 1
+        return self.proc.node.install_history(
+            rows, max_flush=self.admit_max * members)
 
     def crash_stop(self) -> None:
         """Stop as a killed process stops, in a process that hosts other

@@ -314,6 +314,15 @@ INDEX_COUNTERS: List[Tuple[str, str]] = [
     # the footprint width, entries a full bucket spilled wide
     ("interval_grows", "n_interval_grows"),
     ("wide_spills", "n_wide_spills"),
+    # the deps index's live entries (non-free, non-invalidated slots of the
+    # mirror) and the txns a replica's untruncated history put there in
+    # one pass (DeviceState.register_history)
+    ("live_slots", "n_live_slots"),
+    ("history_registered", "n_history_registered"),
+    # queries of serving flushes whose answer outgrew the entry budgets
+    # the store loaded its programs at (DeviceState.loaded_budgets): the
+    # host route answered them, no larger program compiled
+    ("budget_host_queries", "n_budget_host_queries"),
 ]
 
 

@@ -211,4 +211,9 @@ def test_attribution_index_is_refreshed_by_token_and_timed(served_run):
             "attr_device_builds": 0, "attr_tokens": held,
             "range_queries": 0, "range_device_queries": 0,
             # no flush went to the device, so no table was ever synced
-            "sync_launches": 0, "sync_uploads": 0}
+            "sync_launches": 0, "sync_uploads": 0,
+            # every txn the run witnessed is live in the index (nothing
+            # truncates), none of it installed as a history
+            "live_slots": sum(d.n_live_slots for d in devs),
+            "history_registered": 0}
+    assert sum(d.n_live_slots for d in devs) > 0
