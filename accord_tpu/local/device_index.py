@@ -42,9 +42,11 @@ collect is a pure vectorized decode:
  - **host**: a vectorized numpy interval scan over only the LIVE TAIL
    (slots above the batch-global RedundantBefore floor): token-sorted point
    entries probed with searchsorted, flat range entries stabbed with one
-   broadcast.  Wins when the live working set is small relative to a device
-   round trip (the hot-key / durable-prefix-dominated regime, where 90%+ of
-   the table sits below the floor and RTTs dominate a ~10k-entry scan).
+   broadcast, kept by appends as slots are registered (dead and below-floor
+   entries dropped at the probe, a whole rebuild only when due).  Wins when
+   the live working set is small relative to a device round trip (the
+   hot-key / durable-prefix-dominated regime, where 90%+ of the table sits
+   below the floor and RTTs dominate a ~10k-entry scan).
  - **bucketed** (device): the CINTIA-analogue bucket index
    (ops.deps_kernel.bucketed_attr_jit) — O(candidates) per query.  Under
    a mesh the bucket rows are row-sharded (parallel.sharded.
@@ -125,7 +127,7 @@ import jax.numpy as jnp
 from ..obs import devprof
 from ..ops import deps_kernel as dk
 from ..ops import drain_kernel as drk
-from ..ops.packing import W64, bitcast_i32, to_i64
+from ..ops.packing import W64, bitcast_i32, to_i64, to_u64
 from ..primitives.keys import Range, Ranges
 from ..primitives.timestamp import Domain, Kinds, Timestamp, TxnId
 from ..utils import faults
@@ -308,21 +310,27 @@ def _sync_tables(table, own, bdev, stage, n_rows, n_cells):
     return table, own, bdev
 
 
+def _at_or_above(msb, lsb, node, floor_id) -> np.ndarray:
+    """bool per id: (msb, lsb, node) >= ``floor_id`` under EXACTLY the
+    kernel's ts_lt order (unsigned on the two int64 words, then signed
+    node)."""
+    fm = np.uint64(to_u64(to_i64(floor_id.msb)))
+    fl = np.uint64(to_u64(to_i64(floor_id.lsb)))
+    fn = np.int32(floor_id.node)
+    um = msb.astype(np.uint64)
+    ul = lsb.astype(np.uint64)
+    return (um > fm) | ((um == fm)
+                        & ((ul > fl) | ((ul == fl) & (node >= fn))))
+
+
 def _host_index_of(status, lo_a, hi_a, msb, lsb, node, fkey):
     """Build the host-route index (see _DepsMirror.host_index) from
-    explicit arrays — shared by the live cached path and the snapshot-based
-    fused fallback/shadow path.  ``fkey`` is the normalized floor (None =
-    no floor)."""
+    explicit arrays — the maintained index's full build (no floor) and the
+    snapshot-based fused fallback/shadow path.  ``fkey`` is the normalized
+    floor (None = no floor)."""
     live = (status >= 0) & (status != dk.SLOT_INVALIDATED)
     if fkey is not None:
-        from ..ops.packing import to_u64
-        fm = np.uint64(to_u64(to_i64(fkey.msb)))
-        fl = np.uint64(to_u64(to_i64(fkey.lsb)))
-        fn = np.int32(fkey.node)
-        um = msb.astype(np.uint64)
-        ul = lsb.astype(np.uint64)
-        live &= ((um > fm) | ((um == fm)
-                             & ((ul > fl) | ((ul == fl) & (node >= fn)))))
+        live &= _at_or_above(msb, lsb, node, fkey)
     j = np.nonzero(live)[0]
     lo, hi = lo_a[j], hi_a[j]
     used = lo <= hi
@@ -333,6 +341,84 @@ def _host_index_of(status, lo_a, hi_a, msb, lsb, node, fkey):
     rr2, cc2 = np.nonzero(used & ~pt)
     return (ptok[order], j[rr][order], cc[order],
             lo[rr2, cc2], hi[rr2, cc2], j[rr2], cc2)
+
+
+def _with_room(cols, n: int, need: int):
+    """Parallel columns whose first ``n`` rows are used, with room for
+    ``need`` rows: ``cols`` itself, or copies of at least twice the size
+    (amortised doubling)."""
+    if need <= len(cols[0]):
+        return cols
+    size = _pow2_at_least(need, max(2 * len(cols[0]), 64))
+    out = []
+    for a in cols:
+        b = np.empty(size, a.dtype)
+        b[:n] = a[:n]
+        out.append(b)
+    return tuple(out)
+
+
+class _HostIndex:
+    """The host route's index over a mirror's entries, kept by appends
+    (_DepsMirror.host_index): point entries sorted by token and a flat
+    range-entry table, each entry with its slot, its interval column and
+    the slot's generation when it was written.  An entry whose slot was
+    freed since (another generation) or invalidated stays until a rebuild
+    and is dropped at the probe, as is one below a batch's floor.
+    ``count[slot]`` is the entries a live slot holds here, ``dead`` those
+    of the slots freed or invalidated since the build."""
+
+    def __init__(self, capacity: int, gen: np.ndarray, built) -> None:
+        ptok, pslot, pcol, rlo, rhi, rslot, rcol = built
+        self.pts = (ptok, pslot, pcol, gen[pslot])
+        self.rngs = (rlo, rhi, rslot, rcol, gen[rslot])
+        self.n_pts, self.n_rngs = len(ptok), len(rlo)
+        self.count = np.bincount(np.concatenate([pslot, rslot]),
+                                 minlength=capacity)
+        self.dead = 0
+
+    def view(self):
+        """(ptok, pslot, pcol, pgen, rlo, rhi, rslot, rcol, rgen)."""
+        n, m = self.n_pts, self.n_rngs
+        return tuple(a[:n] for a in self.pts) \
+            + tuple(a[:m] for a in self.rngs)
+
+    def rebuild_due(self) -> bool:
+        return 2 * self.dead > self.n_pts + self.n_rngs
+
+    def kill(self, slot: int) -> None:
+        """``slot`` was freed or invalidated: its entries are dead."""
+        c = int(self.count[slot])
+        if c:
+            self.dead += c
+            self.count[slot] = 0
+
+    def fold(self, slot, col, gen, lo, hi) -> None:
+        """Take in live entries written since the last read: points by a
+        sorted merge, ranges by an append."""
+        np.add.at(self.count, slot, 1)
+        pt = lo == hi
+        if pt.any():
+            o = np.argsort(lo[pt], kind="stable")
+            new = tuple(v[pt][o] for v in (lo, slot, col, gen))
+            n, k = self.n_pts, len(o)
+            self.pts = _with_room(self.pts, n, n + k)
+            at = np.searchsorted(self.pts[0][:n], new[0], side="right")
+            dest = at + np.arange(k)
+            first = int(at[0])
+            old = np.arange(first, n)
+            moved = old + np.searchsorted(at, old, side="right")
+            for a, v in zip(self.pts, new):
+                a[moved] = a[first:n].copy()
+                a[dest] = v
+            self.n_pts = n + k
+        if not pt.all():
+            rg = ~pt
+            n, k = self.n_rngs, int(rg.sum())
+            self.rngs = _with_room(self.rngs, n, n + k)
+            for a, v in zip(self.rngs, (lo, hi, slot, col, gen)):
+                a[n:n + k] = v[rg]
+            self.n_rngs = n + k
 
 
 class _DepsMirror:
@@ -449,8 +535,17 @@ class _DepsMirror:
         self.mut_version = 0
         self._snap = None
         self._fstats = None                       # cached floor stats
-        self._hidx = None                         # cached host-route index
-        self._hidx_key = None
+        # the host-route index (_HostIndex; None until the host route first
+        # reads it, and again once a rebuild is due), the (slot, col, gen)
+        # entries written since its last read, and each slot's generation
+        # (bumped by free: an entry of an earlier one is dead)
+        self._hidx: Optional[_HostIndex] = None
+        self._hpend: List[Tuple[int, int, int]] = []
+        self.gen = np.zeros(capacity, np.int64)
+        # whole builds of the host-route index, and its reads that folded
+        # entries written since the last one in
+        self.n_host_index_builds = 0
+        self.n_host_index_folds = 0
         # -- device attribution columns (r15): domain / fresh status /
         # decided executeAt, scatter-updated alongside the slot table so
         # the ATTRIBUTED kernels can apply elision in-kernel.  They get
@@ -682,6 +777,9 @@ class _DepsMirror:
         self.obj[slot] = None
         self.eknown[slot] = False
         self._bucket_remove(slot)
+        self.gen[slot] += 1
+        if self._hidx is not None:
+            self._hidx.kill(slot)
         if self.status[slot] != dk.SLOT_INVALIDATED:
             self.n_live -= 1
         self.status[slot] = dk.SLOT_FREE
@@ -719,10 +817,12 @@ class _DepsMirror:
         self.elsb = _grow(self.elsb, new, 0)
         self.enode = _grow(self.enode, new, 0)
         self.eknown = _grow(self.eknown, new, False)
+        self.gen = _grow(self.gen, new, 0)
         self.free_slots.extend(range(new - 1, old - 1, -1))
         self.capacity = new
         self.mut_version += 1
         self._snap = None
+        self._hidx = None
         self._device = None  # shape changed: full re-upload
         self._device_sh = None
         self._attr_own = None
@@ -741,6 +841,7 @@ class _DepsMirror:
         self.max_intervals = new_m
         self.mut_version += 1
         self._snap = None
+        self._hidx = None
         self._device = None
         self._device_sh = None
 
@@ -773,6 +874,8 @@ class _DepsMirror:
             self.version += 1
             self.mut_version += 1
             self._bucket_add(slot, lo_v, hi_v, used)
+            if self._hidx is not None:
+                self._hpend.append((slot, used, int(self.gen[slot])))
             used += 1
 
     def alloc_bulk(self, txn_ids: Sequence[TxnId],
@@ -848,6 +951,9 @@ class _DepsMirror:
         for j in np.nonzero(~wide)[0].tolist():
             self._bucket_add(int(row[j]), int(bounds[j, 0]),
                              int(bounds[j, 1]), int(col[j]))
+        if self._hidx is not None:
+            self._hpend.extend(zip(row.tolist(), col.tolist(),
+                                   self.gen[row].tolist()))
         self._dirty.update(taken)
         self._attr_dirty.update(taken)
         if self.shards is not None:
@@ -863,13 +969,13 @@ class _DepsMirror:
         if cur != status:
             if status == dk.SLOT_INVALIDATED and cur != dk.SLOT_FREE:
                 self.n_live -= 1
-                # liveness changed: the host-route index (which excludes
-                # dead slots STRUCTURALLY) is stale.  Live->live status
-                # moves deliberately do NOT bump: the index carries only
-                # geometry + liveness, and commit/apply churn between
-                # flushes would otherwise rebuild it every flush in
-                # exactly the hot regime the host route serves
+                # liveness changed: the slot's host-index entries are dead
+                # (dropped at the probe, counted toward a rebuild).
+                # Live->live status moves deliberately do NOT bump: the
+                # kernels' dep mask reads liveness alone
                 self.version += 1
+                if self._hidx is not None:
+                    self._hidx.kill(slot)
             self.status[slot] = status
             self._dirty.add(slot)
             if self.shards is not None:
@@ -962,14 +1068,7 @@ class _DepsMirror:
     def _above_floor_mask(self, floor_id) -> np.ndarray:
         """bool[capacity]: packed id >= floor, under EXACTLY the kernel's
         ts_lt order (unsigned on the two int64 words, then signed node)."""
-        from ..ops.packing import to_u64
-        fm = np.uint64(to_u64(to_i64(floor_id.msb)))
-        fl = np.uint64(to_u64(to_i64(floor_id.lsb)))
-        fn = np.int32(floor_id.node)
-        um = self.msb.astype(np.uint64)
-        ul = self.lsb.astype(np.uint64)
-        return ((um > fm) | ((um == fm)
-                            & ((ul > fl) | ((ul == fl) & (self.node >= fn)))))
+        return _at_or_above(self.msb, self.lsb, self.node, floor_id)
 
     def floor_stats(self, floor_id) -> Dict[str, float]:
         """Estimated shape of the LIVE-above-floor working set: slot count,
@@ -1006,35 +1105,62 @@ class _DepsMirror:
                 "n_rng": st["n_rng"] + grown * per * (1.0 - frac_pt),
                 "tok_lo": st["tok_lo"], "tok_hi": st["tok_hi"]}
 
-    def host_index(self, floor_id):
-        """(ptok, pslot, pcol, rlo, rhi, rslot, rcol): the live-above-floor
-        tail as a token-SORTED point-entry array plus a flat range-entry
-        table — the reference's own scan shape (CommandsForKey sorted
-        arrays + rangeCommands, ref: local/CommandsForKey.java:614-650),
-        rebuilt from the mirror whenever a mutation lands and cached
-        between flushes.  ``pcol``/``rcol`` record each entry's interval
-        column in its slot row, so probes yield exact emit triples and the
-        collect pass never rebuilds the overlap geometry."""
-        fkey = floor_id if floor_id is not None and floor_id > TxnId.NONE \
-            else None
-        key = (fkey, self.version)
-        if self._hidx is not None and self._hidx_key == key:
-            return self._hidx
-        self._hidx = _host_index_of(self.status, self.lo, self.hi,
-                                    self.msb, self.lsb, self.node, fkey)
-        self._hidx_key = key
-        return self._hidx
+    def host_index_rebuild_due(self) -> bool:
+        """The next read of the host index builds it whole: its first
+        read, after a resize or a width doubling, or once the entries of
+        freed and invalidated slots outnumber the live ones."""
+        return self._hidx is None or self._hidx.rebuild_due()
+
+    @property
+    def n_host_index_pending(self) -> int:
+        """Entries written since the host index's last read."""
+        return len(self._hpend)
+
+    def host_index(self):
+        """(ptok, pslot, pcol, pgen, rlo, rhi, rslot, rcol, rgen): every
+        entry of the mirror as a token-SORTED point-entry array plus a flat
+        range-entry table — the reference's own scan shape (CommandsForKey
+        sorted arrays + rangeCommands, ref: local/CommandsForKey.java:
+        614-650).  Built whole once (host_index_rebuild_due), then kept by
+        folding in the entries add_intervals / alloc_bulk wrote since the
+        last read; ``pgen``/``rgen`` are the slot generations the entries
+        were written in, so host_pairs drops a dead one (and one below the
+        batch's floor) at the probe.  ``pcol``/``rcol`` record each entry's
+        interval column in its slot row, so probes yield exact emit triples
+        and the collect pass never rebuilds the overlap geometry."""
+        if self.host_index_rebuild_due():
+            with self._span("host_index_build"):
+                self._hpend = []
+                self._hidx = _HostIndex(
+                    self.capacity, self.gen,
+                    _host_index_of(self.status, self.lo, self.hi, self.msb,
+                                   self.lsb, self.node, None))
+            self.n_host_index_builds += 1
+        elif self._hpend:
+            with self._span("host_index_fold"):
+                pend = np.array(self._hpend, np.int64)
+                self._hpend = []
+                sl, co, ge = pend[:, 0], pend[:, 1], pend[:, 2]
+                # an entry of a slot freed or invalidated since it was
+                # written is dead already
+                ok = (self.gen[sl] == ge) \
+                    & (self.status[sl] != dk.SLOT_INVALIDATED)
+                sl, co, ge = sl[ok], co[ok], ge[ok]
+                self._hidx.fold(sl, co, ge, self.lo[sl, co], self.hi[sl, co])
+            self.n_host_index_folds += 1
+        return self._hidx.view()
 
     def host_pairs(self, qnp: np.ndarray, q_m: int, floor_id,
                    snapshot=None):
         """The host route's candidate generation: per-ENTRY arrays
         (query row, slot, entry interval column, query interval column)
-        satisfying the EXACT kernel predicate (liveness + floor structurally
-        via the index; witness / earlier / not-self as vectorized compares
-        identical to the device ts_lt) — the same overlap triples the
-        device routes' raw compaction yields, so the attribution filter
-        sees identical inputs and results are bit-identical by
-        construction.
+        satisfying the EXACT kernel predicate (liveness + floor, then
+        witness / earlier / not-self, as vectorized compares identical to
+        the device ts_lt, on the probed candidates; a snapshot's index
+        holds the live-above-floor entries alone) — the same overlap
+        triples the device routes' raw compaction yields, so the
+        attribution filter sees identical inputs and results are
+        bit-identical by construction.
 
         ``snapshot`` = (msb, lsb, node, kind, status, lo, hi) computes the
         scan against a begin-time copy of the mirror instead of the live
@@ -1042,17 +1168,18 @@ class _DepsMirror:
         AFTER dispatch, and its host fallback / shadow verify must answer
         for the snapshot the device kernel scanned, not for mutations that
         landed in between."""
+        fkey = floor_id if floor_id is not None and floor_id > TxnId.NONE \
+            else None
         if snapshot is not None:
             s_msb, s_lsb, s_node, s_kind, s_status, s_lo, s_hi = snapshot
-            fkey = floor_id if floor_id is not None \
-                and floor_id > TxnId.NONE else None
-            idx = _host_index_of(s_status, s_lo, s_hi, s_msb, s_lsb,
-                                 s_node, fkey)
+            ptok, pslot, pcol, rlo, rhi, rslot, rcol = _host_index_of(
+                s_status, s_lo, s_hi, s_msb, s_lsb, s_node, fkey)
+            pgen = rgen = None
         else:
             s_msb, s_lsb, s_node, s_kind = (self.msb, self.lsb, self.node,
                                             self.kind)
-            idx = self.host_index(floor_id)
-        ptok, pslot, pcol, rlo, rhi, rslot, rcol = idx
+            (ptok, pslot, pcol, pgen, rlo, rhi, rslot, rcol,
+             rgen) = self.host_index()
         lo = qnp[:, 7:7 + q_m]
         hi = qnp[:, 7 + q_m:7 + 2 * q_m]
         used = lo <= hi
@@ -1073,6 +1200,7 @@ class _DepsMirror:
         parts_j: List[np.ndarray] = []
         parts_m: List[np.ndarray] = []
         parts_q: List[np.ndarray] = []
+        parts_g: List[np.ndarray] = []
         if len(ptok):
             # token-sorted probe: every query interval (point OR range)
             # selects the contiguous token slice it covers
@@ -1091,6 +1219,8 @@ class _DepsMirror:
                 parts_j.append(pslot[pos])
                 parts_m.append(pcol[pos])
                 parts_q.append(mi[owner])
+                if pgen is not None:
+                    parts_g.append(pgen[pos])
         if len(rlo) and len(qi):
             ov = (rlo[None, :] <= fhi[:, None]) & (flo[:, None] <= rhi[None, :])
             ii, jj = np.nonzero(ov)
@@ -1098,6 +1228,8 @@ class _DepsMirror:
             parts_j.append(rslot[jj])
             parts_m.append(rcol[jj])
             parts_q.append(mi[ii])
+            if rgen is not None:
+                parts_g.append(rgen[jj])
         if not parts_b:
             return (np.zeros(0, np.int64),) * 4
         cb = np.concatenate(parts_b).astype(np.int64)
@@ -1113,6 +1245,13 @@ class _DepsMirror:
                                & ((uel < ubl) | ((uel == ubl) & (en < bn)))))
         keep &= ~((em == qnp[cb, 4]) & (el == qnp[cb, 5])
                   & (en == qnp[cb, 6]))
+        if parts_g:
+            # the maintained index: what the snapshot's build leaves out
+            # structurally, dead entries and those below the floor
+            keep &= (self.gen[cj] == np.concatenate(parts_g)) \
+                & (self.status[cj] != dk.SLOT_INVALIDATED)
+            if fkey is not None:
+                keep &= _at_or_above(em, el, en, fkey)
         if not keep.all():
             cb, cj, cm, cq = cb[keep], cj[keep], cm[keep], cq[keep]
         return cb, cj, cm, cq
@@ -2906,13 +3045,8 @@ class DeviceState:
         # ~5 vectorized passes per candidate (probe + attr filter + the
         # thin finalize — r15 replaced the attribute re-sort), plus a
         # fixed per-flush overhead (probe setup, index builds, snapshots)
-        host_cost = calib["c_host"] * (5.0 * est_host + 40_000.0)
-        if self.deps._hidx_key != ((floor_id if floor_id is not None
-                                    and floor_id > TxnId.NONE else None),
-                                   self.deps.version):
-            # index rebuild: one vectorized pass over the live tail
-            host_cost += calib["c_host"] * 4.0 * (st["n_above"]
-                                                  + st["n_pt"] + st["n_rng"])
+        host_cost = calib["c_host"] * (5.0 * est_host + 40_000.0) \
+            + self._host_index_price(st, calib["c_host"])
         # device model: the cheaper kernel's PER-SHARD element count (wall
         # clock of a parallel launch = the per-shard work).  The slot table
         # row-shards, so dense work divides by d; the bucket probe matrix
@@ -2949,6 +3083,16 @@ class DeviceState:
             # A/B micro-probe slope (r21), never a device-count threshold
             dev_cost += calib.get("c_shard", 0.0) * d * s_attr
         return "host" if host_cost < dev_cost else "device"
+
+    def _host_index_price(self, st, c_host: float) -> float:
+        """What reading the host index adds to the next host flush
+        (_DepsMirror.host_index): a whole build where one is due, one
+        vectorized pass over the live tail; else the fold of the entries
+        written since its last read."""
+        d = self.deps
+        if d.host_index_rebuild_due():
+            return c_host * 4.0 * (st["n_above"] + st["n_pt"] + st["n_rng"])
+        return c_host * 4.0 * d.n_host_index_pending
 
     def _count_range_queries(self, queries) -> int:
         """Count a flush's queries of range-domain txns into
@@ -3125,6 +3269,16 @@ class DeviceState:
         for length in range(gran, s + 1, gran):
             _fetch_entry_prefix(ent, s, length)
         d.warm_sync(min(self.WARM_SYNC_ROWS, d.capacity // 4))
+
+    @property
+    def n_host_index_builds(self) -> int:
+        """Whole builds of the host route's index."""
+        return self.deps.n_host_index_builds
+
+    @property
+    def n_host_index_folds(self) -> int:
+        """Reads of the host route's index that folded new entries in."""
+        return self.deps.n_host_index_folds
 
     @property
     def n_interval_grows(self) -> int:

@@ -763,6 +763,9 @@ class NodeServer:
             # put there in one pass (Node.install_history)
             "live_slots": sum(d.n_live_slots for d in devs),
             "history_registered": sum(d.n_history_registered for d in devs),
+            # the host route's index: whole builds, reads that folded
+            "host_index_builds": sum(d.n_host_index_builds for d in devs),
+            "host_index_folds": sum(d.n_host_index_folds for d in devs),
         }
 
     def _coordination_stats(self) -> Optional[dict]:

@@ -323,6 +323,10 @@ INDEX_COUNTERS: List[Tuple[str, str]] = [
     # the store loaded its programs at (DeviceState.loaded_budgets): the
     # host route answered them, no larger program compiled
     ("budget_host_queries", "n_budget_host_queries"),
+    # the host route's index (_DepsMirror.host_index): whole builds, and
+    # reads that folded the entries written since the last one in
+    ("host_index_builds", "n_host_index_builds"),
+    ("host_index_folds", "n_host_index_folds"),
 ]
 
 

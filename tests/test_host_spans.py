@@ -180,12 +180,14 @@ _BEFORE = {
 }
 _BEFORE["device", False] = _BEFORE["device", True]
 _BEFORE[None, True] = _BEFORE["host", True]
-# the four kinds new with the spans
+# the four kinds new with the spans, and the host index's first (whole)
+# build inside dispatch_host, a kind of its own since it is maintained
 _NEW = {
-    ("host", True): {"pack_queries": 1},
+    ("host", True): {"pack_queries": 1, "host_index_build": 1},
     ("device", True): {"pack_queries": 2},
     ("device", False): {"pack_queries": 2, "snapshot_cols": 1},
-    (None, True): {"pack_queries": 1, "choose_route": 1},
+    (None, True): {"pack_queries": 1, "choose_route": 1,
+                   "host_index_build": 1},
 }
 
 

@@ -215,5 +215,11 @@ def test_attribution_index_is_refreshed_by_token_and_timed(served_run):
             # every txn the run witnessed is live in the index (nothing
             # truncates), none of it installed as a history
             "live_slots": sum(d.n_live_slots for d in devs),
-            "history_registered": 0}
+            "history_registered": 0,
+            # the host route's index, built whole once a store and then
+            # kept by folding in the registrations between flushes
+            "host_index_builds": sum(d.n_host_index_builds for d in devs),
+            "host_index_folds": sum(d.n_host_index_folds for d in devs)}
     assert sum(d.n_live_slots for d in devs) > 0
+    assert 0 < sum(d.n_host_index_builds for d in devs) \
+        < sum(d.n_host_index_folds for d in devs)
