@@ -2,37 +2,100 @@
 
 Rebuild of ref: accord-core/src/main/java/accord/local/MaxConflicts.java:32,
 RedundantBefore.java:49, DurableBefore.java:39.  All three are range-keyed
-step functions (ReducingRangeMap) — sorted boundary arrays, which is also
-their device format for the deps floor in the PreAccept kernel.
+step functions — sorted boundary arrays, which is also their device format
+for the deps floor in the PreAccept kernel: the two watermarks are
+ReducingRangeMaps, MaxConflicts keeps the same canonical form in two
+mutable lists of its own.
 """
 
 from __future__ import annotations
 
 import enum
-from typing import Optional
+from bisect import bisect_left, bisect_right
+from typing import List, Optional
 
-from ..primitives.keys import Range, Ranges, RoutingKeys, Unseekables
+from ..primitives.keys import Keys, Range, Ranges, RoutingKeys, Unseekables
 from ..primitives.timestamp import Timestamp, TxnId, max_timestamp
 from ..utils.interval_map import ReducingRangeMap
 
 
 class MaxConflicts:
     """range -> max Timestamp witnessed; consulted to propose executeAt
-    (ref: local/MaxConflicts.java)."""
+    (ref: local/MaxConflicts.java).
 
-    __slots__ = ("_map",)
+    Store-private and read only on its store's thread, so unlike the
+    watermark maps below it is MUTABLE: the step function is two lists in
+    ``ReducingRangeMap``'s canonical compacted form (``_b`` the sorted
+    boundaries, ``_v`` one value a gap, None where nothing was witnessed),
+    and a witnessed range is spliced into them in place.  Every key a txn
+    touches adds up to two boundaries and nothing truncates them, so an
+    add must not copy the map: it costs a bisect, the covered gaps and one
+    memmove of the tail.  ``n_adds`` counts the ranges taken (a key is
+    one), ``n_boundaries()`` the boundaries held."""
+
+    __slots__ = ("_b", "_v", "n_adds")
 
     def __init__(self):
-        self._map: ReducingRangeMap = ReducingRangeMap.empty()
+        self._b: List[int] = []
+        self._v: List[Optional[Timestamp]] = [None]
+        self.n_adds = 0
+
+    def n_boundaries(self) -> int:
+        return len(self._b)
 
     def get_max(self, keys_or_ranges) -> Timestamp:
-        ranges = _as_ranges(keys_or_ranges)
-        out = self._map.fold_over_ranges(ranges, lambda v, acc: max_timestamp(acc, v), None)
+        b, v = self._b, self._v
+        if isinstance(keys_or_ranges, (Keys, RoutingKeys)):
+            gaps = [v[bisect_right(b, t)] for t in keys_or_ranges.tokens()]
+        else:
+            gaps = []
+            for r in _as_ranges(keys_or_ranges):
+                gaps += v[bisect_right(b, r.start):bisect_right(b, r.end - 1) + 1]
+        out = None
+        for x in gaps:
+            if x is not None and (out is None or x > out):
+                out = x
         return out if out is not None else Timestamp.NONE
 
     def update(self, keys_or_ranges, ts: Timestamp) -> None:
-        ranges = _as_ranges(keys_or_ranges)
-        self._map = self._map.add(ranges, ts, lambda a, b: a if a >= b else b)
+        if isinstance(keys_or_ranges, (Keys, RoutingKeys)):
+            for t in keys_or_ranges.tokens():
+                self._add(t, t + 1, ts)
+        else:
+            for r in _as_ranges(keys_or_ranges):
+                self._add(r.start, r.end, ts)
+
+    def _add(self, s: int, e: int, ts: Timestamp) -> None:
+        """Max ``ts`` over [s, e): ``ReducingRangeMap._add_one``'s splice
+        (bisect the gap, reduce only the covered gaps, re-compact only the
+        joints the splice can have equalised) as slice assignments."""
+        self.n_adds += 1
+        b, v = self._b, self._v
+        lo = bisect_right(b, s)    # the gap holding s
+        hi = bisect_left(b, e)     # the gap holding e - 1
+        covered = v[lo:hi + 1]
+        grew = False
+        for i, x in enumerate(covered):
+            if x is None or ts > x:
+                covered[i] = ts
+                grew = True
+        if not grew:               # the map already holds ts or more there
+            return
+        # replace b[bs:be] / v[bs:be + 1]: from s's boundary (new, or the one
+        # already at s) to e's, with the untouched values on either side
+        bs = lo - 1 if lo and b[lo - 1] == s else lo
+        be = hi + 1 if hi < len(b) and b[hi] == e else hi
+        nb = [s, *b[lo:hi], e]
+        nv = [v[bs], *covered, v[be]]
+        kb: List[int] = []
+        kv: List[Optional[Timestamp]] = [nv[0]]
+        for k, x in enumerate(nb):  # drop any joint the max made equal
+            if nv[k + 1] == kv[-1]:
+                continue
+            kb.append(x)
+            kv.append(nv[k + 1])
+        b[bs:be] = kb
+        v[bs:be + 1] = kv
 
 
 class RedundantStatus(enum.IntEnum):

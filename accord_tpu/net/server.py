@@ -799,6 +799,13 @@ class NodeServer:
             # the deps this node made stable as a coordinator
             "deps_txns": self.proc.node.n_deps_txns,
             "deps_entries": self.proc.node.n_deps_entries,
+            # the stores' MaxConflicts: ranges (a key is one) spliced in,
+            # and the boundaries they hold now
+            "n_max_conflicts_adds": sum(
+                s.max_conflicts.n_adds for s in node.command_stores.stores),
+            "max_conflicts_boundaries": sum(
+                s.max_conflicts.n_boundaries()
+                for s in node.command_stores.stores),
         }
 
     def _peer_failure_stats(self) -> Optional[dict]:

@@ -442,9 +442,11 @@ async def _serve_three(journal_root, txns=40):
         await asyncio.sleep(0.6)          # the sweeper and a scan fire
         stats = [s.stats() for s in servers]
         for st, s in zip(stats, servers):     # batches the stores drained
+            stores = s.proc.node.command_stores.stores
             st["_batches"] = sum(
-                n for store in s.proc.node.command_stores.stores
-                for n in store.group_sizes.values())
+                n for store in stores for n in store.group_sizes.values())
+            st["_mc"] = (sum(x.max_conflicts.n_adds for x in stores),
+                         sum(len(x.max_conflicts._b) for x in stores))
         answered = client.n_ok
         recorders = [s.proc.obs.spans for s in servers]
     finally:
@@ -501,6 +503,13 @@ def test_a_served_clusters_loop_stats_name_what_the_loop_does(tmp_path):
     for rec in recorders:
         assert rec.retire_roots and rec.dropped == 0
         assert len(rec.finished) <= rec.retire_roots
+    # the stores' MaxConflicts counters reach stats()["coordination"]
+    for st in stats:
+        coord = st["coordination"]
+        assert (coord["n_max_conflicts_adds"],
+                coord["max_conflicts_boundaries"]) == st["_mc"]
+        assert coord["n_max_conflicts_adds"] > 0
+        assert coord["max_conflicts_boundaries"] > 0
 
 
 # -- stored procedures: the srv.proc span and the deps / mirror counters ----
@@ -596,3 +605,32 @@ def test_the_mirror_counts_its_width_doublings_and_hot_bucket_spills():
     assert dev.n_wide_spills == 6
     assert ("interval_grows", "n_interval_grows") in INDEX_COUNTERS
     assert ("wide_spills", "n_wide_spills") in INDEX_COUNTERS
+
+
+def test_max_conflicts_counts_the_keys_it_spliced_in_place():
+    """One fast-path txn over five keys: every replica store's MaxConflicts
+    took the keys it owns twice (PreAccept, then Commit at the same
+    executeAt) into the SAME two lists, holding two boundaries a key."""
+    from accord_tpu.sim.cluster import Cluster
+    from accord_tpu.sim.kvstore import KVDataStore, kv_txn
+    from accord_tpu.sim.topology_factory import build_topology
+    cluster = Cluster(topology=build_topology(1, (1, 2, 3), 3, 4), seed=3,
+                      data_store_factory=KVDataStore)
+    stores = [s for n in cluster.nodes.values()
+              for s in n.command_stores.unsafe_all_stores()]
+    lists = [(s.max_conflicts._b, s.max_conflicts._v) for s in stores]
+    assert all(s.max_conflicts.n_adds == 0 for s in stores)
+    keys = [10, 30, 250_000, 600_000, 900_000]
+    done = []
+    cluster.nodes[1].coordinate(kv_txn(keys, {10: ("a",)})).begin(
+        lambda r, f: done.append(f))
+    cluster.run_until_quiescent()
+    assert done == [None] and cluster.failures == []
+    for store, (b, v) in zip(stores, lists):
+        mc = store.max_conflicts
+        owned = [k for k in keys if store.owned_current().contains_token(k)]
+        assert owned
+        assert mc.n_adds == 2 * len(owned)
+        assert mc.n_boundaries() == len(mc._b) == 2 * len(owned)
+        assert mc._b == sorted(t for k in owned for t in (k, k + 1))
+        assert mc._b is b and mc._v is v

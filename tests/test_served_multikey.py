@@ -142,7 +142,8 @@ def test_five_served_nodes_multikey_zipf_replay_and_coordination(served_run):
     assert all(set(c) == {"fast", "slow", "recoveries", "range_txns",
                           "key_txns", "scan_rows", "deps_txns",
                           "deps_entries", "patience_deferrals",
-                          "patience_micros"} for c in after)
+                          "patience_micros", "n_max_conflicts_adds",
+                          "max_conflicts_boundaries"} for c in after)
     # the home patience is never shorter than two scans of 0.5 s
     assert all(c["patience_micros"] >= 1_000_000
                and c["patience_deferrals"] >= 0 for c in after)
@@ -150,6 +151,10 @@ def test_five_served_nodes_multikey_zipf_replay_and_coordination(served_run):
     # on hot Zipf keys some of them named a dependency
     assert all(c["deps_txns"] > 0 for c in after)
     assert sum(c["deps_entries"] for c in after) > 0
+    # every node's stores spliced the run's keys into their MaxConflicts
+    assert all(a["n_max_conflicts_adds"] > b["n_max_conflicts_adds"]
+               and a["max_conflicts_boundaries"] > 0
+               for a, b in zip(after, before))
     # every decision was a key-domain txn's: nothing here scans
     assert all(c["key_txns"] == c["fast"] + c["slow"]
                and c["range_txns"] == c["scan_rows"] == 0 for c in after)

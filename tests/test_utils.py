@@ -171,6 +171,153 @@ def test_range_map_splice_add_matches_merge_add():
             assert m_new.get(t) == m_old.get(t)
 
 
+def _max_conflicts_history(shape, rng):
+    """(keys_or_ranges, ts) updates as the stores issue them: a txn's
+    Keys at PreAccept / Accept / Commit (the same or a later ts), a range
+    txn's Ranges, GetMaxConflict's RoutingKeys, and install_history's one
+    update over the store's whole owned Ranges."""
+    from accord_tpu.primitives.keys import IntKey, Keys, RoutingKeys
+    from accord_tpu.primitives.timestamp import (Domain, Timestamp, TxnId,
+                                                 TxnKind)
+
+    def ts():
+        hlc, node = rng.randint(1, 40), rng.randint(1, 3)
+        if rng.random() < 0.3:       # a TxnId equal to a Timestamp
+            return TxnId.create(1, hlc, TxnKind.Write, Domain.Key, node)
+        return Timestamp.from_values(1, hlc, node)
+
+    def ranges():
+        pts = sorted(rng.sample(range(0, 260), 2 * rng.randint(1, 3)))
+        return Ranges([Range(pts[2 * i], pts[2 * i + 1])
+                       for i in range(len(pts) // 2)])
+
+    owned = Ranges.of(Range(20, 90), Range(150, 240))
+    out = []
+    for _ in range(rng.randint(20, 80)):
+        kind = shape if shape != "mixed" else rng.choice(
+            ["points", "ranges", "owned"])
+        if kind == "points":
+            toks = [rng.randint(0, 255) for _ in range(rng.randint(1, 8))]
+            keys = (Keys([IntKey(t) for t in toks]) if rng.random() < 0.8
+                    else RoutingKeys(toks))
+            t = ts()
+            out.append((keys, t))
+            if rng.random() < 0.5:   # its Commit, at the decided executeAt
+                out.append((keys, max(t, ts())))
+        elif kind == "ranges":
+            out.append((ranges(), ts()))
+        else:
+            out.append((owned, ts()) if rng.random() < 0.2
+                       else (Keys([IntKey(rng.randint(0, 255))]), ts()))
+    return out
+
+
+def _assert_max_conflicts_matches(updates, rng):
+    """Feed ``updates`` through ``MaxConflicts`` and, as its map did before
+    the splice moved in place, through ``ReducingRangeMap.add`` with the
+    max reducer: the same boundaries and values after every update, the
+    same lists throughout, and the same ``get_max`` for Keys and Ranges."""
+    from accord_tpu.local.redundant import MaxConflicts
+    from accord_tpu.primitives.keys import IntKey, Keys
+    from accord_tpu.primitives.timestamp import Timestamp, max_timestamp
+    fmax = lambda a, b: a if a >= b else b   # noqa: E731
+
+    def as_ranges(q):
+        if isinstance(q, Ranges):
+            return q
+        return Ranges([Range(t, t + 1) for t in q.tokens()])
+
+    mc, ref = MaxConflicts(), ReducingRangeMap.empty()
+    b, v = mc._b, mc._v
+    for q, ts in updates:
+        mc.update(q, ts)
+        ref = ref.add(as_ranges(q), ts, fmax)
+        assert mc._b == list(ref.boundaries), (q, ts, mc._b, ref)
+        assert mc._v == list(ref.values), (q, ts, mc._v, ref)
+    assert mc._b is b and mc._v is v
+    assert mc.n_adds == sum(len(q) for q, _ts in updates)
+    hi = max(ref.boundaries, default=0) + 3
+    for _ in range(60):
+        if rng.random() < 0.5:
+            q = Keys([IntKey(rng.randint(-2, hi))
+                      for _ in range(rng.randint(1, 6))])
+        else:
+            pts = sorted(rng.sample(range(-2, hi + 1), 2))
+            q = Ranges.of(Range(pts[0], pts[1]))
+        want = ref.fold_over_ranges(
+            as_ranges(q), lambda x, acc: max_timestamp(acc, x), None)
+        assert mc.get_max(q) == (want if want is not None
+                                 else Timestamp.NONE), q
+    return mc
+
+
+def _journal_order_histories(monkeypatch):
+    """A three-node sim cluster's contended multi-key traffic, then node 2
+    restarted from its journal: every store's updates as the protocol
+    issued them live, and the restored stores' as the journal replay
+    issued them, each with the store whose MaxConflicts took them."""
+    from accord_tpu.local.redundant import MaxConflicts
+    from accord_tpu.sim.cluster import Cluster
+    from accord_tpu.sim.kvstore import KVDataStore, kv_txn
+    from accord_tpu.sim.topology_factory import build_topology
+    import random
+    logs = {}
+    live_update = MaxConflicts.update
+
+    def recording(self, keys_or_ranges, ts):
+        logs.setdefault(id(self), []).append((keys_or_ranges, ts))
+        live_update(self, keys_or_ranges, ts)
+
+    monkeypatch.setattr(MaxConflicts, "update", recording)
+    cluster = Cluster(topology=build_topology(1, (1, 2, 3), 3, 4), seed=7,
+                      data_store_factory=KVDataStore)
+    rng = random.Random(7)
+    done = []
+    for i in range(24):
+        keys = rng.sample([10, 20, 30, 250_000, 500_010, 740_000], 3)
+        cluster.nodes[1 + i % 3].coordinate(
+            kv_txn(keys, {keys[0]: (f"v{i}",)})).begin(
+            lambda r, f: done.append(f))
+        if i % 4 == 3:               # four at a time: they contend
+            cluster.run_until_quiescent()
+    cluster.run_until_quiescent()
+    assert done == [None] * 24 and cluster.failures == []
+    stores = [s for n in cluster.nodes.values()
+              for s in n.command_stores.unsafe_all_stores()]
+    live = [(s.max_conflicts, logs[id(s.max_conflicts)]) for s in stores]
+    restored = cluster.restart_node(2)
+    replayed = [(s.max_conflicts, logs[id(s.max_conflicts)])
+                for s in restored.command_stores.unsafe_all_stores()]
+    return live + replayed
+
+
+@pytest.mark.parametrize("shape", ["points", "ranges", "owned", "mixed",
+                                   "journal-order"])
+def test_max_conflicts_splice_in_place_matches_the_immutable_map(
+        shape, monkeypatch):
+    """``MaxConflicts`` splices each witnessed range into its own two lists
+    in place; ``ReducingRangeMap.add`` (which stays immutable, and which
+    it used before) is the reference: identical canonical form, boundary
+    for boundary, after every update of random histories of point keys,
+    ranges and whole-owned-range updates, and identical ``get_max``.  The
+    ``journal-order`` case replays each store's own updates, live and as
+    the journal's restore issued them: a fresh structure fed them in that
+    order ends with the store's lists, and answers as the reference does."""
+    import random
+    rng = random.Random(43)
+    if shape != "journal-order":
+        for _trial in range(60):
+            _assert_max_conflicts_matches(
+                _max_conflicts_history(shape, rng), rng)
+        return
+    histories = _journal_order_histories(monkeypatch)
+    assert all(updates for _mc, updates in histories)
+    monkeypatch.undo()
+    for store_mc, updates in histories:
+        mc = _assert_max_conflicts_matches(updates, rng)
+        assert (mc._b, mc._v) == (store_mc._b, store_mc._v)
+
+
 def test_range_map_splice_add_edges():
     """Splice edge shapes: exact-boundary hits, containment, adjacency,
     empty map, full overwrite."""
